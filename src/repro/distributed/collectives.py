@@ -13,7 +13,6 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 
 def compressed_psum_grads(grads, mesh: Mesh, axis: str | tuple, key,
@@ -58,8 +57,8 @@ def compressed_psum_grads(grads, mesh: Mesh, axis: str | tuple, key,
         lambda x: P(axes if len(axes) > 1 else axes[0],
                     *([None] * (x.ndim - 1))), grads)
     out_spec = jax.tree_util.tree_map(lambda _: P(), grads)
-    return shard_map(local_reduce, mesh=mesh, in_specs=(in_spec,),
-                     out_specs=out_spec, check_rep=False)(grads)
+    return jax.shard_map(local_reduce, mesh=mesh, in_specs=(in_spec,),
+                         out_specs=out_spec, check_vma=False)(grads)
 
 
 def sp_decode_attention(q, k_cache, v_cache, bias, mesh: Mesh, *,
@@ -86,11 +85,11 @@ def sp_decode_attention(q, k_cache, v_cache, bias, mesh: Mesh, *,
         o = jax.lax.psum(o_i * corr[..., None], seq_axis)
         return (o / jnp.maximum(l, 1e-30)[..., None]).astype(q_l.dtype)
 
-    return shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(), P(None, None, seq_axis, None),
                   P(None, None, seq_axis, None), P(None, seq_axis)),
-        out_specs=P(), check_rep=False)(q, k_cache, v_cache, bias)
+        out_specs=P(), check_vma=False)(q, k_cache, v_cache, bias)
 
 
 NEG_INF = -1e30
@@ -176,9 +175,9 @@ def sp_decode_attention_int8(q, k_q, k_s, v_q, v_s, write, write_at,
     sc_spec = P(None, None, seq_axis)
     mask = lengths if have_lengths else bias
     mask_spec = P() if have_lengths else P(None, seq_axis)
-    return shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(), kv_spec, sc_spec, kv_spec, sc_spec,
                   P(), P(), P(), P(), P(), mask_spec),
         out_specs=(P(), kv_spec, sc_spec, kv_spec, sc_spec),
-        check_rep=False)(q, k_q, k_s, v_q, v_s, *write, write_at, mask)
+        check_vma=False)(q, k_q, k_s, v_q, v_s, *write, write_at, mask)

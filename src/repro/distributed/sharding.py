@@ -15,6 +15,7 @@ divisible, otherwise the *sequence* dim over model (long-context serving).
 """
 from __future__ import annotations
 
+import functools
 from typing import Any
 
 import jax
@@ -244,3 +245,16 @@ def to_shardings(mesh: Mesh, spec_tree):
     return jax.tree_util.tree_map(
         lambda s: NamedSharding(mesh, s), spec_tree,
         is_leaf=lambda x: isinstance(x, P))
+
+
+def init_sharded_params(cfg: ModelConfig, key, mesh: Mesh | None = None):
+    """``transformer.init_params`` compiled with ``param_specs`` as its
+    output shardings: every device draws only its own shard, so no chip
+    ever holds the whole model (without a mesh: a plain jitted init).
+    The values equal an unsharded init's (threefry is partitionable)."""
+    from repro.models import transformer
+    init = functools.partial(transformer.init_params, cfg)
+    if mesh is None:
+        return jax.jit(init)(key)
+    specs = param_specs(cfg, jax.eval_shape(init, key), mesh=mesh)
+    return jax.jit(init, out_shardings=to_shardings(mesh, specs))(key)

@@ -122,7 +122,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_out_ref, l_out_ref, *refs,
         l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
         acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
         if count:
-            cnt_acc[...] = jnp.zeros(cnt_acc.shape, jnp.int32)
+            cnt_acc[0] = 0
 
     def _step():
         q = q_ref[...][0].astype(jnp.float32)                  # (BQ, D)
@@ -144,7 +144,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_out_ref, l_out_ref, *refs,
         l_ref[...] = l_ref[...] * alpha + p.sum(axis=-1)
         m_ref[...] = m_new
         if count:
-            cnt_acc[...] += 1
+            cnt_acc[0] += 1
 
     _when(visited, _step)
 
@@ -152,10 +152,10 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_out_ref, l_out_ref, *refs,
     def _done():
         denom = jnp.maximum(l_ref[...], 1e-30)
         o_ref[...] = (acc_ref[...] / denom[:, None])[None].astype(o_ref.dtype)
-        m_out_ref[...] = m_ref[...][None]
-        l_out_ref[...] = l_ref[...][None]
+        m_out_ref[...] = m_ref[...].reshape(m_out_ref.shape)
+        l_out_ref[...] = l_ref[...].reshape(l_out_ref.shape)
         if count:
-            cnt_ref[...] = cnt_acc[...].reshape(cnt_ref.shape)
+            cnt_ref[...] = jnp.full(cnt_ref.shape, cnt_acc[0], jnp.int32)
 
 
 def _kv_wedge_index(group, bounds_kw):
@@ -207,19 +207,19 @@ def flash_attention_fwd_pallas(q, k, v, *, causal: bool = True,
 
     out_specs = [
         pl.BlockSpec((1, bq, d), lambda h, i, j: (h, i, 0)),
-        pl.BlockSpec((1, bq), lambda h, i, j: (h, i)),
-        pl.BlockSpec((1, bq), lambda h, i, j: (h, i)),
+        pl.BlockSpec((1, 1, bq), lambda h, i, j: (h, 0, i)),
+        pl.BlockSpec((1, 1, bq), lambda h, i, j: (h, 0, i)),
     ]
     out_shape = [
         jax.ShapeDtypeStruct((bh, s_len, d), q.dtype),
-        jax.ShapeDtypeStruct((bh, s_len), jnp.float32),
-        jax.ShapeDtypeStruct((bh, s_len), jnp.float32),
+        jax.ShapeDtypeStruct((bh, 1, s_len), jnp.float32),
+        jax.ShapeDtypeStruct((bh, 1, s_len), jnp.float32),
     ]
     if debug_counts:
-        out_specs.append(pl.BlockSpec((1, 1), lambda h, i, j: (h, i)))
-        out_shape.append(jax.ShapeDtypeStruct((bh, n_q), jnp.int32))
+        out_specs.append(pl.BlockSpec((1, 1, 1, 1), lambda h, i, j: (h, i, 0, 0)))
+        out_shape.append(jax.ShapeDtypeStruct((bh, n_q, 1, 1), jnp.int32))
 
-    return pl.pallas_call(
+    o, m, l, *cnt = pl.pallas_call(
         functools.partial(_flash_kernel, sm_scale=scale, s_len=s_len,
                           count=debug_counts, **bounds_kw),
         grid=(bh, n_q, kv_steps),
@@ -237,6 +237,8 @@ def flash_attention_fwd_pallas(q, k, v, *, causal: bool = True,
         ] + ([pltpu.SMEM((1,), jnp.int32)] if debug_counts else []),
         interpret=interpret,
     )(q, k, v)
+    m, l = m.reshape(bh, s_len), l.reshape(bh, s_len)
+    return (o, m, l, cnt[0].reshape(bh, n_q)) if debug_counts else (o, m, l)
 
 
 # ---------------------------------------------------------------------------
@@ -246,7 +248,7 @@ def _bwd_delta_kernel(o_ref, do_ref, delta_ref):
     """D = rowsum(dO * O): the softmax-backward correction, (BQ,) f32."""
     o = o_ref[...][0].astype(jnp.float32)
     do = do_ref[...][0].astype(jnp.float32)
-    delta_ref[...] = (o * do).sum(axis=-1)[None]
+    delta_ref[...] = (o * do).sum(axis=-1).reshape(delta_ref.shape)
 
 
 def _recompute_probs(q, k, m, l, ok, *, sm_scale):
@@ -278,16 +280,16 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, m_ref, l_ref, delta_ref,
     def _init():
         acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
         if count:
-            cnt_acc[...] = jnp.zeros(cnt_acc.shape, jnp.int32)
+            cnt_acc[0] = 0
 
     def _step():
         q = q_ref[...][0].astype(jnp.float32)                  # (BQ, D)
         k = k_ref[...][0].astype(jnp.float32)                  # (BK, D)
         v = v_ref[...][0].astype(jnp.float32)
         do = do_ref[...][0].astype(jnp.float32)
-        m = m_ref[...][0]
-        l = l_ref[...][0]
-        delta = delta_ref[...][0]
+        m = m_ref[...][0, 0]
+        l = l_ref[...][0, 0]
+        delta = delta_ref[...][0, 0]
 
         ok = _position_mask(qi, ki, bq=bq, bk=bk, causal=causal,
                             window=window, kv_len=kv_len, s_len=s_len)
@@ -296,7 +298,7 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, m_ref, l_ref, delta_ref,
         ds = p * (dp - delta[:, None])
         acc_ref[...] += jnp.dot(ds, k, preferred_element_type=jnp.float32)
         if count:
-            cnt_acc[...] += 1
+            cnt_acc[0] += 1
 
     _when(visited, _step)
 
@@ -304,7 +306,7 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, m_ref, l_ref, delta_ref,
     def _done():
         dq_ref[...] = (acc_ref[...] * sm_scale)[None].astype(dq_ref.dtype)
         if count:
-            cnt_ref[...] = cnt_acc[...].reshape(cnt_ref.shape)
+            cnt_ref[...] = jnp.full(cnt_ref.shape, cnt_acc[0], jnp.int32)
 
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, m_ref, l_ref, delta_ref,
@@ -338,16 +340,16 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, m_ref, l_ref, delta_ref,
         dk_acc[...] = jnp.zeros(dk_acc.shape, jnp.float32)
         dv_acc[...] = jnp.zeros(dv_acc.shape, jnp.float32)
         if count:
-            cnt_acc[...] = jnp.zeros(cnt_acc.shape, jnp.int32)
+            cnt_acc[0] = 0
 
     def _step():
         q = q_ref[...][0].astype(jnp.float32)                  # (BQ, D)
         k = k_ref[...][0].astype(jnp.float32)                  # (BK, D)
         v = v_ref[...][0].astype(jnp.float32)
         do = do_ref[...][0].astype(jnp.float32)
-        m = m_ref[...][0]
-        l = l_ref[...][0]
-        delta = delta_ref[...][0]
+        m = m_ref[...][0, 0]
+        l = l_ref[...][0, 0]
+        delta = delta_ref[...][0, 0]
 
         ok = _position_mask(qi, ki, bq=bq, bk=bk, causal=causal,
                             window=window, kv_len=kv_len, s_len=s_len)
@@ -357,7 +359,7 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, m_ref, l_ref, delta_ref,
         ds = p * (dp - delta[:, None])
         dk_acc[...] += jnp.dot(ds.T, q, preferred_element_type=jnp.float32)
         if count:
-            cnt_acc[...] += 1
+            cnt_acc[0] += 1
 
     _when(visited, _step)
 
@@ -366,7 +368,7 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, m_ref, l_ref, delta_ref,
         dk_ref[...] = (dk_acc[...] * sm_scale)[None].astype(dk_ref.dtype)
         dv_ref[...] = dv_acc[...][None].astype(dv_ref.dtype)
         if count:
-            cnt_ref[...] = cnt_acc[...].reshape(cnt_ref.shape)
+            cnt_ref[...] = jnp.full(cnt_ref.shape, cnt_acc[0], jnp.int32)
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -420,17 +422,20 @@ def flash_attention_bwd_pallas(q, k, v, o, m, l, do, *, causal: bool = True,
         grid=(bh, n_q),
         in_specs=[pl.BlockSpec((1, bq, d), lambda h, i: (h, i, 0)),
                   pl.BlockSpec((1, bq, d), lambda h, i: (h, i, 0))],
-        out_specs=pl.BlockSpec((1, bq), lambda h, i: (h, i)),
-        out_shape=jax.ShapeDtypeStruct((bh, s_len), jnp.float32),
+        out_specs=pl.BlockSpec((1, 1, bq), lambda h, i: (h, 0, i)),
+        out_shape=jax.ShapeDtypeStruct((bh, 1, s_len), jnp.float32),
         interpret=interpret,
     )(o, do)
 
     dq_out_specs = [pl.BlockSpec((1, bq, d), lambda h, i, j: (h, i, 0))]
     dq_out_shape = [jax.ShapeDtypeStruct((bh, s_len, d), dq_dt)]
     if debug_counts:
-        dq_out_specs.append(pl.BlockSpec((1, 1), lambda h, i, j: (h, i)))
-        dq_out_shape.append(jax.ShapeDtypeStruct((bh, n_q), jnp.int32))
+        dq_out_specs.append(pl.BlockSpec((1, 1, 1, 1),
+                                         lambda h, i, j: (h, i, 0, 0)))
+        dq_out_shape.append(jax.ShapeDtypeStruct((bh, n_q, 1, 1), jnp.int32))
 
+    m, l = m.reshape(bh, 1, s_len), l.reshape(bh, 1, s_len)
+    row_spec = pl.BlockSpec((1, 1, bq), lambda h, i, j: (h, 0, i))
     dq_out = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, sm_scale=scale, s_len=s_len,
                           count=debug_counts, **bounds_kw),
@@ -440,9 +445,7 @@ def flash_attention_bwd_pallas(q, k, v, o, m, l, do, *, causal: bool = True,
             pl.BlockSpec((1, bk, d), _kv_wedge_index(group, bounds_kw)),
             pl.BlockSpec((1, bk, d), _kv_wedge_index(group, bounds_kw)),
             pl.BlockSpec((1, bq, d), lambda h, i, j: (h, i, 0)),
-            pl.BlockSpec((1, bq), lambda h, i, j: (h, i)),
-            pl.BlockSpec((1, bq), lambda h, i, j: (h, i)),
-            pl.BlockSpec((1, bq), lambda h, i, j: (h, i)),
+            row_spec, row_spec, row_spec,
         ],
         out_specs=dq_out_specs,
         out_shape=dq_out_shape,
@@ -471,9 +474,13 @@ def flash_attention_bwd_pallas(q, k, v, o, m, l, do, *, causal: bool = True,
         jax.ShapeDtypeStruct((bhkv, s_len, d), dv_dt),
     ]
     if debug_counts:
-        dkv_out_specs.append(pl.BlockSpec((1, 1),
-                                          lambda hk, j, gi, i: (hk, j)))
-        dkv_out_shape.append(jax.ShapeDtypeStruct((bhkv, n_k), jnp.int32))
+        dkv_out_specs.append(pl.BlockSpec(
+            (1, 1, 1, 1), lambda hk, j, gi, i: (hk, j, 0, 0)))
+        dkv_out_shape.append(jax.ShapeDtypeStruct((bhkv, n_k, 1, 1),
+                                                  jnp.int32))
+    q_row_spec = pl.BlockSpec(
+        (1, 1, bq), lambda hk, j, gi, i: (_q_head(hk, j, gi, i), 0,
+                                          _q_tile(hk, j, gi, i)))
 
     dkv_out = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, sm_scale=scale, n_q=n_q,
@@ -489,15 +496,7 @@ def flash_attention_bwd_pallas(q, k, v, o, m, l, do, *, causal: bool = True,
             pl.BlockSpec((1, bq, d),
                          lambda hk, j, gi, i: (_q_head(hk, j, gi, i),
                                                _q_tile(hk, j, gi, i), 0)),
-            pl.BlockSpec((1, bq),
-                         lambda hk, j, gi, i: (_q_head(hk, j, gi, i),
-                                               _q_tile(hk, j, gi, i))),
-            pl.BlockSpec((1, bq),
-                         lambda hk, j, gi, i: (_q_head(hk, j, gi, i),
-                                               _q_tile(hk, j, gi, i))),
-            pl.BlockSpec((1, bq),
-                         lambda hk, j, gi, i: (_q_head(hk, j, gi, i),
-                                               _q_tile(hk, j, gi, i))),
+            q_row_spec, q_row_spec, q_row_spec,
         ],
         out_specs=dkv_out_specs,
         out_shape=dkv_out_shape,
@@ -508,6 +507,7 @@ def flash_attention_bwd_pallas(q, k, v, o, m, l, do, *, causal: bool = True,
     )(q, k, v, do, m, l, delta)
     if debug_counts:
         dk, dv, dkv_counts = dkv_out
-        return dq, dk, dv, dq_out[1], dkv_counts
+        return (dq, dk, dv, dq_out[1].reshape(bh, n_q),
+                dkv_counts.reshape(bhkv, n_k))
     dk, dv = dkv_out
     return dq, dk, dv

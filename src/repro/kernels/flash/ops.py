@@ -15,9 +15,10 @@ O(S^2) probability matrix — and the backward runs the recompute-based
 Pallas kernels (``kernel.flash_attention_bwd_pallas``), so
 ``jax.grad`` through ``attn_backend="pallas"`` is legal and memory-cheap.
 
-Shapes the compiled Mosaic pipeline cannot lower (head_dim not in
-{64, 128}, sequences shorter than one 128-lane block) fall back to the
-``ref`` path with a one-time warning instead of crashing.
+``pallas`` never substitutes another backend: a shape Mosaic cannot
+lower raises Mosaic's own error.  (Small head_dims and sub-128
+sequences lower too, because their blocks span the whole array
+dimension; ``tests/test_tpu_compile.py`` compiles both.)
 
 ``resid_dtype`` applies a mixed-precision policy to the SAVED residual
 tuple: (q, k, v, o) are stored between forward and backward in that dtype
@@ -30,16 +31,12 @@ recomputation.
 from __future__ import annotations
 
 import functools
-import warnings
 from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
 
 from repro.kernels.flash import kernel, ref
-
-SUPPORTED_HEAD_DIMS = (64, 128)
-_WARNED_FALLBACKS: set[str] = set()
 
 
 class _FlashSpec(NamedTuple):
@@ -67,39 +64,6 @@ def padded_seq_len(s: int) -> int:
     benchmarks size tile grids with this so their visited-tile counts
     match what the kernels actually execute."""
     return s + ((-s) % 128 if s > 128 else (-s) % 8)
-
-
-def unsupported_reason(q, k, v, *, backend: str) -> Optional[str]:
-    """Why the *compiled* Mosaic kernel can't run this shape (None = fine).
-
-    Only ``backend="pallas"`` is constrained: the interpreter executes any
-    shape, and ``ref`` is pure jnp.  (Indivisible GQA head counts are an
-    invalid *input* on every backend — ``flash_attention`` raises rather
-    than falls back.)  Padding in ``flash_attention`` already rounds S up
-    to a multiple of the 128 block for S >= 128, so the sequence-length
-    guard only rejects sub-block sequences (which would lower to
-    non-lane-aligned tiles Mosaic refuses).
-    """
-    if backend != "pallas":
-        return None
-    d = q.shape[-1]
-    s = q.shape[2]
-    if d not in SUPPORTED_HEAD_DIMS:
-        return (f"head_dim={d} is not MXU lane-aligned (supported: "
-                f"{SUPPORTED_HEAD_DIMS}) for q{tuple(q.shape)}")
-    if s < kernel.DEFAULT_BQ and s % kernel.DEFAULT_BQ:
-        return (f"sequence length {s} of q{tuple(q.shape)} is not a "
-                f"multiple of the flash block size {kernel.DEFAULT_BQ}; "
-                f"sub-block tiles are not lane-aligned")
-    return None
-
-
-def _warn_fallback_once(reason: str) -> None:
-    if reason not in _WARNED_FALLBACKS:
-        _WARNED_FALLBACKS.add(reason)
-        warnings.warn(
-            f"flash_attention: falling back to backend='ref' — {reason}",
-            stacklevel=3)
 
 
 # ---------------------------------------------------------------------------
@@ -168,11 +132,6 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
             f"multiple of n_kv={k.shape[1]} (GQA) for q{tuple(q.shape)}, "
             f"k{tuple(k.shape)} — every backend groups query heads over "
             f"KV heads")
-    if backend != "ref":
-        reason = unsupported_reason(q, k, v, backend=backend)
-        if reason is not None:
-            _warn_fallback_once(reason)
-            backend = "ref"
     if backend == "ref":
         return ref.flash_ref(q, k, v, causal=causal, window=window,
                              sm_scale=sm_scale)

@@ -89,7 +89,7 @@ def _flash_decode_kernel(*refs, sm_scale, bs, ns, spt, has_bias,
         l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
         acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
         if count:
-            cnt_acc[...] = jnp.zeros(cnt_acc.shape, jnp.int32)
+            cnt_acc[0] = 0
 
     # early-out: structural padding tiles of the last split, and (with
     # lengths) every tile fully beyond this batch row's valid prefix
@@ -99,11 +99,11 @@ def _flash_decode_kernel(*refs, sm_scale, bs, ns, spt, has_bias,
 
     def _step():
         q = q_ref[...][0, 0].astype(jnp.float32)                 # (G, D)
-        k = kq_ref[...][0, 0].astype(jnp.float32) * ks_ref[...][0, 0][:, None]
-        v = vq_ref[...][0, 0].astype(jnp.float32) * vs_ref[...][0, 0][:, None]
+        k = kq_ref[...][0, 0].astype(jnp.float32) * ks_ref[...][0, 0, 0][:, None]
+        v = vq_ref[...][0, 0].astype(jnp.float32) * vs_ref[...][0, 0, 0][:, None]
         logits = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * sm_scale
         if has_bias:
-            logits = logits + bias_ref[...][0][None, :]           # (G, BS)
+            logits = logits + bias_ref[...][0]                    # (G, BS)
         if has_lengths:
             # straddling tile: mask the tail with a per-tile iota compare —
             # never a materialized (B, S) bias tensor
@@ -120,7 +120,7 @@ def _flash_decode_kernel(*refs, sm_scale, bs, ns, spt, has_bias,
         l_ref[...] = l_ref[...] * alpha + p.sum(axis=-1)
         m_ref[...] = m_new
         if count:
-            cnt_acc[...] += 1
+            cnt_acc[0] += 1
 
     pl.when(live)(_step)
 
@@ -137,10 +137,10 @@ def _flash_decode_kernel(*refs, sm_scale, bs, ns, spt, has_bias,
             # A split with zero executed steps writes its init state
             # (acc=0, l=0, m=NEG_INF) and contributes nothing to the merge.
             o_ref[...] = acc_ref[...][None, None, None]
-            m_out_ref[...] = m_ref[...][None, None, None]
-            l_out_ref[...] = l_ref[...][None, None, None]
+            m_out_ref[...] = m_ref[...].reshape(m_out_ref.shape)
+            l_out_ref[...] = l_ref[...].reshape(l_out_ref.shape)
         if count:
-            cnt_ref[...] = cnt_acc[...].reshape(cnt_ref.shape)
+            cnt_ref[...] = jnp.full(cnt_ref.shape, cnt_acc[0], jnp.int32)
 
 
 def combine_splits(o_p, m_p, l_p, dtype):
@@ -191,27 +191,26 @@ def flash_decode_pallas(q, k_q, k_s, v_q, v_s, bias=None, lengths=None, *,
     if has_lengths:
         q_map = lambda i, j, k, st, lr: (i, j, 0, 0)
         kv_map = lambda i, j, k, st, lr: (i, j, _tile(i, k, st, lr), 0)
-        sc_map = lambda i, j, k, st, lr: (i, j, _tile(i, k, st, lr))
+        sc_map = lambda i, j, k, st, lr: (i, j, 0, _tile(i, k, st, lr))
         o_map = lambda i, j, k, st, lr: (i, j, k, 0, 0)
-        ml_map = lambda i, j, k, st, lr: (i, j, k, 0)
-        cnt_map = lambda i, j, k, st, lr: (i, j, k)
     else:
         q_map = lambda i, j, k, st: (i, j, 0, 0)
         kv_map = lambda i, j, k, st: (i, j, _tile(i, k, st), 0)
-        sc_map = lambda i, j, k, st: (i, j, _tile(i, k, st))
+        sc_map = lambda i, j, k, st: (i, j, 0, _tile(i, k, st))
         o_map = lambda i, j, k, st: (i, j, k, 0, 0)
-        ml_map = lambda i, j, k, st: (i, j, k, 0)
-        cnt_map = lambda i, j, k, st: (i, j, k)
 
+    # Per-token scales (and the bias) get a unit axis so the block's last
+    # two dims are (1, bs) against an array dim of 1, a layout Mosaic
+    # accepts; likewise the (1, g) stat rows and (1, 1) counters below.
     kv_spec = pl.BlockSpec((1, 1, bs, d), kv_map)
-    sc_spec = pl.BlockSpec((1, 1, bs), sc_map)
+    sc_spec = pl.BlockSpec((1, 1, 1, bs), sc_map)
     in_specs = [pl.BlockSpec((1, 1, g, d), q_map),
                 kv_spec, sc_spec, kv_spec, sc_spec]
-    args = [q, k_q, k_s, v_q, v_s]
+    args = [q, k_q, k_s.reshape(b, hkv, 1, s), v_q, v_s.reshape(b, hkv, 1, s)]
     if has_bias:
-        bias_map = (lambda i, j, k, st: (i, _tile(i, k, st)))
-        in_specs.append(pl.BlockSpec((1, bs), bias_map))
-        args.append(bias)
+        bias_map = (lambda i, j, k, st: (i, 0, _tile(i, k, st)))
+        in_specs.append(pl.BlockSpec((1, 1, bs), bias_map))
+        args.append(bias.reshape(b, 1, s))
 
     fused = n_sp == 1            # single split: finalize in-kernel, no
     if fused:                    # partial HBM round-trip or jnp combine
@@ -220,17 +219,18 @@ def flash_decode_pallas(q, k_q, k_s, v_q, v_s, bias=None, lengths=None, *,
     else:
         out_specs = [
             pl.BlockSpec((1, 1, 1, g, d), o_map),
-            pl.BlockSpec((1, 1, 1, g), ml_map),
-            pl.BlockSpec((1, 1, 1, g), ml_map),
+            pl.BlockSpec((1, 1, 1, 1, g), o_map),
+            pl.BlockSpec((1, 1, 1, 1, g), o_map),
         ]
         out_shape = [
             jax.ShapeDtypeStruct((b, hkv, n_sp, g, d), jnp.float32),
-            jax.ShapeDtypeStruct((b, hkv, n_sp, g), jnp.float32),
-            jax.ShapeDtypeStruct((b, hkv, n_sp, g), jnp.float32),
+            jax.ShapeDtypeStruct((b, hkv, n_sp, 1, g), jnp.float32),
+            jax.ShapeDtypeStruct((b, hkv, n_sp, 1, g), jnp.float32),
         ]
     if debug_counts:
-        out_specs.append(pl.BlockSpec((1, 1, 1), cnt_map))
-        out_shape.append(jax.ShapeDtypeStruct((b, hkv, n_sp), jnp.int32))
+        out_specs.append(pl.BlockSpec((1, 1, 1, 1, 1), o_map))
+        out_shape.append(jax.ShapeDtypeStruct((b, hkv, n_sp, 1, 1),
+                                              jnp.int32))
 
     scratch_shapes = [
         pltpu.VMEM((g,), jnp.float32),                               # m
@@ -245,7 +245,7 @@ def flash_decode_pallas(q, k_q, k_s, v_q, v_s, bias=None, lengths=None, *,
     # the split-K point: (batch, kv-head, split) are PARALLEL — Mosaic may
     # run the splits concurrently (this is where O(S) -> O(S/splits) comes
     # from on hardware); only the per-split KV sweep is sequential
-    params = pltpu.TPUCompilerParams(
+    params = pltpu.CompilerParams(
         dimension_semantics=("parallel", "parallel", "parallel",
                              "arbitrary"))
     if has_lengths:
@@ -265,5 +265,6 @@ def flash_decode_pallas(q, k_q, k_s, v_q, v_s, bias=None, lengths=None, *,
     if fused:
         o = out[0]
     else:
-        o = combine_splits(*out[:3], q.dtype)
-    return (o, out[-1]) if debug_counts else o
+        o_p, m_p, l_p = out[:3]
+        o = combine_splits(o_p, m_p[..., 0, :], l_p[..., 0, :], q.dtype)
+    return (o, out[-1].reshape(b, hkv, n_sp)) if debug_counts else o

@@ -27,9 +27,12 @@ DEFAULT_BC = 512
 
 
 def _decode_kernel(packed_ref, out_ref, *, scale: float, shift: float):
-    x = packed_ref[...]  # (BR, BC) uint32
+    # Mosaic has no uint32 -> float32 cast: reinterpret the bits as int32
+    # (the 0xFF mask drops the sign extension of the arithmetic shift) and
+    # convert from there.
+    x = jax.lax.bitcast_convert_type(packed_ref[...], jnp.int32)  # (BR, BC)
     for i in range(LANES):  # unrolled VPU shifts/masks
-        lane = ((x >> jnp.uint32(8 * i)) & jnp.uint32(0xFF)).astype(jnp.float32)
+        lane = ((x >> (8 * i)) & 0xFF).astype(jnp.float32)
         out_ref[i, :, :] = lane * scale + shift
 
 

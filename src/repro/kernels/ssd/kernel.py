@@ -23,18 +23,22 @@ def _ssd_chunk_kernel(c_ref, b_ref, x_ref, da_ref, y_ref, st_ref):
     c = c_ref[...][0, 0]        # (Q, N)
     b = b_ref[...][0, 0]        # (Q, N)
     x = x_ref[...][0, 0]        # (Q, P)
-    da = da_ref[...][0, 0]      # (Q,) inclusive cumulative log-decay
+    da = da_ref[...][0, 0]      # (1, Q) inclusive cumulative log-decay
     q = c.shape[0]
 
+    # da arrives as a lane row; the column copy comes from transposing its
+    # (Q, Q) broadcast, since Mosaic relayouts whole tiles, not vectors.
+    da_row = jnp.broadcast_to(da, (q, q))                 # [i, j] = da[j]
+    da_col = da_row.T                                     # [i, j] = da[i]
     scores = jnp.dot(c, b.T, preferred_element_type=jnp.float32)     # (Q, Q)
-    decay = jnp.exp(da[:, None] - da[None, :])
+    decay = jnp.exp(da_col - da_row)
     rows = jax.lax.broadcasted_iota(jnp.int32, (q, q), 0)
     cols = jax.lax.broadcasted_iota(jnp.int32, (q, q), 1)
     g = jnp.where(rows >= cols, scores * decay, 0.0)
     y_ref[...] = jnp.dot(g, x, preferred_element_type=jnp.float32)[None, None]
 
-    w = jnp.exp(da[q - 1] - da)                                       # (Q,)
-    st_ref[...] = jnp.dot(b.T, x * w[:, None],
+    w = jnp.exp(da_row[:, q - 1:] - da_col[:, :1])                   # (Q, 1)
+    st_ref[...] = jnp.dot(b.T, x * w,
                           preferred_element_type=jnp.float32)[None, None]
 
 
@@ -50,10 +54,10 @@ def ssd_chunk_pallas(c, b, xbar, acum, *, interpret: bool = False):
         _ssd_chunk_kernel,
         grid=grid,
         in_specs=[spec_qn, spec_qn, spec_qp,
-                  pl.BlockSpec((1, 1, q), lambda i, j: (i, j, 0))],
+                  pl.BlockSpec((1, 1, 1, q), lambda i, j: (i, j, 0, 0))],
         out_specs=[spec_qp,
                    pl.BlockSpec((1, 1, n, p), lambda i, j: (i, j, 0, 0))],
         out_shape=[jax.ShapeDtypeStruct((g_sz, t, q, p), jnp.float32),
                    jax.ShapeDtypeStruct((g_sz, t, n, p), jnp.float32)],
         interpret=interpret,
-    )(c, b, xbar, acum)
+    )(c, b, xbar, acum.reshape(g_sz, t, 1, q))

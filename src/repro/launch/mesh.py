@@ -8,43 +8,22 @@ jax device state).  Production target: TPU v5e, 256 chips/pod, 16x16
 devices survive, it picks the largest (data, model) grid with model <= 16,
 and checkpoint restore reshards into it (see repro.checkpointing).
 
-``make_mesh`` / ``abstract_mesh`` are version-compat shims: jax moved the
-mesh-construction API between releases (``axis_types=`` kwarg +
-``jax.sharding.AxisType`` appeared after 0.4.x; ``AbstractMesh`` changed
-from a ``((name, size), ...)`` shape-tuple to ``(sizes, names)``).  All
-repo code and tests construct meshes through these two helpers so the same
-tree runs on either side of the drift.
+``make_mesh`` is ``jax.make_mesh`` with ``Auto`` axis types: the sharding
+rules in ``repro.distributed`` annotate inputs and outputs and leave the
+rest to XLA's propagation, while ``jax.make_mesh`` defaults to
+``Explicit`` axes.
 """
 from __future__ import annotations
 
 import math
 
 import jax
-
-
-def _axis_type_kwargs(n: int) -> dict:
-    """``axis_types=(Auto,) * n`` on jax versions that have it, else {}."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n}
+from jax.sharding import AxisType
 
 
 def make_mesh(shape, axes, **kwargs):
-    """``jax.make_mesh`` across the axis_types API drift."""
-    try:
-        return jax.make_mesh(shape, axes, **_axis_type_kwargs(len(axes)),
-                             **kwargs)
-    except TypeError:
-        return jax.make_mesh(shape, axes, **kwargs)
-
-
-def abstract_mesh(shape, axes):
-    """``jax.sharding.AbstractMesh`` across its signature drift."""
-    try:
-        return jax.sharding.AbstractMesh(tuple(shape), tuple(axes))
-    except TypeError:
-        return jax.sharding.AbstractMesh(tuple(zip(axes, shape)))
+    """``jax.make_mesh`` with every axis ``AxisType.Auto``."""
+    return jax.make_mesh(shape, axes, (AxisType.Auto,) * len(axes), **kwargs)
 
 
 def make_production_mesh(*, multi_pod: bool = False):

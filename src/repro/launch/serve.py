@@ -47,26 +47,28 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import configs
+from repro.distributed.sharding import init_sharded_params
+from repro.launch import compile_cache
 from repro.launch.mesh import describe, make_mesh_for
 from repro.launch.train import Watchdog
-from repro.models import transformer
 from repro.obs import MemStat, Tracer
 from repro.serve import sampling
 from repro.train.serve_step import build_decode_step, build_prefill_step
 
 
-def _kv_banner(cfg, args, s_total: int):
+def _kv_banner(cfg, args, s_total: int, backend: str):
     """Honest banner: name what decode will ACTUALLY run — the int8 kvq
     kernel only dispatches on a quantized GQA cache (MLA latents and SSM
     states take their own decode paths), and the split count is clamped
-    to the KV tile count of the preallocated cache."""
+    to the KV tile count of the preallocated cache.  ``backend`` is the
+    resolved kvq backend."""
     quant = not args.no_quantize
     kvq_eligible = cfg.mixer in ("attn", "hybrid") and cfg.mla is None
     if not kvq_eligible:
         kv_backend, kv_splits = "n/a (no kvq-layout attention cache)", 1
     elif quant:
         from repro.kernels.kvq import ops as kvq_ops
-        kv_backend = args.kv_backend
+        kv_backend = backend
         kv_splits = kvq_ops.resolve_splits(s_total, args.kv_splits)
     else:
         kv_backend, kv_splits = "jnp (cache not quantized)", 1
@@ -172,13 +174,25 @@ def _install_obs_hook(obj, sink, memstat, every: int, snapshot_fn) -> None:
 
 def run_fleet(args, cfg, params, mesh=None) -> int:
     """N engine replicas behind the health-routing Router, optionally
-    under the seeded chaos harness."""
+    under the seeded chaos harness.  With ``--workers`` this process never
+    touches JAX (``params`` is None): each worker builds its own weights.
+    On a TPU host one worker holds the chips: libtpu lets one process at a
+    time use them, so a second worker could not start."""
     from repro.serve import (BreakerConfig, FleetFaultInjector, Router,
                              chaos_plan, supports)
     if not supports(cfg):
         print(f"fleet: {cfg.arch_id} is not engine-eligible")
         return 2
-    _kv_banner(cfg, args, args.max_len)
+    if args.workers and args.replicas > 1:
+        from repro.serve.worker import host_chips
+        chips = host_chips()
+        if chips is not None:
+            print(f"fleet: --workers on a TPU host runs one worker process: "
+                  f"libtpu lets one process at a time hold this host's "
+                  f"{chips} chip(s), so {args.replicas} workers would "
+                  f"contend for them (use --replicas 1, or drop --workers "
+                  f"to run the replicas in one process)")
+            return 2
     sink = _open_sink(args)
     journal = None
     if args.journal:
@@ -189,11 +203,14 @@ def run_fleet(args, cfg, params, mesh=None) -> int:
     t0 = time.time()
     if args.workers:
         from repro.serve import spawn_workers
+        _kv_banner(cfg, args, args.max_len,
+                   f"{args.kv_backend} (resolved in each worker)")
         engines = spawn_workers(args.replicas, kwargs=_worker_kwargs(args))
         for i, w in enumerate(engines):
             w.metrics.replica = i
         print(f"fleet: {args.replicas} subprocess workers "
-              f"(pids {[w.pid for w in engines]}) warmed in "
+              f"(pids {[w.pid for w in engines]}, devices "
+              f"{[w.devices for w in engines]}) warmed in "
               f"{time.time()-t0:.1f}s "
               f"({engines[0].pool.max_slots} slots each)")
     else:
@@ -205,6 +222,7 @@ def run_fleet(args, cfg, params, mesh=None) -> int:
             e.metrics.replica = i
             e.warmup()
             engines.append(e)
+        _kv_banner(cfg, args, args.max_len, engines[0].kv_backend)
         print(f"fleet: {args.replicas} replicas warmed in "
               f"{time.time()-t0:.1f}s "
               f"({engines[0].pool.max_slots} slots each)")
@@ -245,7 +263,10 @@ def run_fleet(args, cfg, params, mesh=None) -> int:
         print(f"chaos: seed {args.chaos_seed} -> "
               f"{dict(plan.counts())}")
     memstat = None
-    if args.metrics_every:
+    if args.metrics_every and args.workers:
+        print("[warn] --metrics-every samples this process's arrays; with "
+              "--workers they live in the workers — memory sampling off")
+    elif args.metrics_every:
         memstat = MemStat(sink=sink,
                           plan_bytes=(int(args.mem_budget_mb * 2**20)
                                       or None))
@@ -303,11 +324,11 @@ def run_engine(args, cfg, params, mesh=None) -> int:
               f"uniform-window GQA attention cache — MLA/SSM/encoder/"
               f"global-layer archs serve through the lockstep driver)")
         return 2
-    _kv_banner(cfg, args, args.max_len)
     sink = _open_sink(args)
     budget = (int(args.mem_budget_mb * 2**20)
               if args.mem_budget_mb else None)
     engine = _build_engine(args, cfg, params, mesh, sink=sink)
+    _kv_banner(cfg, args, args.max_len, engine.kv_backend)
     # one source of truth for capacity: the engine's own clamp/accounting
     if mesh is not None:
         from repro.distributed import sharding as shd
@@ -389,7 +410,11 @@ def run_lockstep(args, cfg, params) -> int:
         rng.integers(0, cfg.vocab, (args.batch, args.prompt_len)), jnp.int32)
 
     s_total = args.prompt_len + args.gen
-    _kv_banner(cfg, args, s_total)
+    kv_backend = args.kv_backend
+    if kv_backend == "auto":
+        from repro.kernels import platform_backend
+        kv_backend = platform_backend()
+    _kv_banner(cfg, args, s_total, kv_backend)
 
     # the decode cache is preallocated at prompt_len + gen INSIDE the
     # compiled prefill (grow_cache) — no post-hoc host-side pad
@@ -397,7 +422,7 @@ def run_lockstep(args, cfg, params) -> int:
                                          quantized=quant, s_max=s_total))
     decode = jax.jit(build_decode_step(cfg, policy_name=args.policy,
                                        quantized=quant,
-                                       kvq_backend=args.kv_backend,
+                                       kvq_backend=kv_backend,
                                        kvq_splits=args.kv_splits))
     sampler = sampling.make_sampler(temperature=args.temperature,
                                     top_k=args.top_k)
@@ -440,21 +465,27 @@ def run_lockstep(args, cfg, params) -> int:
 
 
 def run(args):
-    mesh = make_mesh_for(max_model=args.max_model)
-    print(f"mesh: {describe(mesh)} ({mesh.size} devices)")
     cfg = configs.smoke_config(args.arch) if args.smoke \
         else configs.get_config(args.arch)
-    params = transformer.init_params(cfg, jax.random.PRNGKey(args.seed))
+    if args.engine and args.workers:
+        # this parent stays off JAX, so the chips are free for the
+        # workers, which build their own weights
+        return run_fleet(args, cfg, None)
+    compile_cache.enable()
+    mesh = make_mesh_for(max_model=args.max_model)
+    print(f"mesh: {describe(mesh)} ({mesh.size} devices)")
+    key = jax.random.PRNGKey(args.seed)
     if args.engine:
         # single-device mesh adds nothing but sharding plumbing — keep the
         # engine on the exact unsharded path there
         eng_mesh = mesh if mesh.size > 1 else None
-        if args.replicas > 1 or args.workers or args.journal:
-            # journal/worker modes always go through the router — a
-            # single replica is just a fleet of one
+        params = init_sharded_params(cfg, key, eng_mesh)
+        if args.replicas > 1 or args.journal:
+            # journal mode always goes through the router — a single
+            # replica is just a fleet of one
             return run_fleet(args, cfg, params, mesh=eng_mesh)
         return run_engine(args, cfg, params, mesh=eng_mesh)
-    return run_lockstep(args, cfg, params)
+    return run_lockstep(args, cfg, init_sharded_params(cfg, key))
 
 
 def main():
@@ -465,9 +496,11 @@ def main():
     ap.add_argument("--prompt-len", type=int, default=64)
     ap.add_argument("--gen", type=int, default=32)
     ap.add_argument("--policy", default="bf16")
-    ap.add_argument("--kv-backend", default="ref",
-                    choices=["ref", "interpret", "pallas"],
-                    help="int8 KV decode-attention backend (kernels/kvq)")
+    ap.add_argument("--kv-backend", default="auto",
+                    choices=["auto", "ref", "interpret", "pallas"],
+                    help="int8 KV decode-attention backend (kernels/kvq); "
+                         "auto = the compiled kernel on a TPU, ref "
+                         "elsewhere")
     ap.add_argument("--kv-splits", type=int, default=1,
                     help="split-K fan-out of the decode grid (clamped to "
                          "the cache's KV tile count)")
@@ -552,7 +585,8 @@ def main():
                          "replays it")
     ap.add_argument("--workers", action="store_true",
                     help="fleet: run each replica as a real subprocess "
-                         "behind the pipe RPC (repro.serve.worker)")
+                         "behind the pipe RPC (repro.serve.worker); on a "
+                         "TPU host, one worker (--replicas 1)")
     ap.add_argument("--recover", action="store_true",
                     help="fleet: rebuild in-flight requests from the "
                          "--journal before serving the trace "

@@ -40,8 +40,8 @@ from repro.core.checkpoint import CheckpointConfig
 from repro.core.mixed_precision import LossScale
 from repro.data.synthetic import token_stream
 from repro.events import EventSink
+from repro.launch import compile_cache
 from repro.launch.mesh import describe, make_mesh_for
-from repro.models import transformer
 from repro.obs import MemStat, MetricsRegistry, Tracer, maybe_span
 from repro.optim import adamw
 from repro.train.guards import GuardConfig, TrainGuard
@@ -126,7 +126,7 @@ def synthetic_lm_batches(cfg, batch: int, seq: int, *, seed=0, state=None):
         i += 1
 
 
-def _auto_remat(cfg, args, mesh, batch_sds) -> CheckpointConfig:
+def auto_remat(cfg, args, mesh, batch_sds) -> tuple[CheckpointConfig, int]:
     """Planner-driven remat: budget-constrained when ``--mem-budget-mb``
     is given (delegating to ``train_step.resolve_remat`` — the same path
     ``TrainConfig.mem_budget_mb`` takes programmatically), else Chen-style
@@ -157,7 +157,16 @@ def _auto_remat(cfg, args, mesh, batch_sds) -> CheckpointConfig:
     return remat, int(rep["peak_bytes"])
 
 
+def init_state(cfg, seed: int, mesh, shards):
+    """Fresh (params, AdamW state), each born in its shardings: no
+    device ever materializes the whole model first."""
+    from repro.distributed.sharding import init_sharded_params
+    params = init_sharded_params(cfg, jax.random.PRNGKey(seed), mesh)
+    return params, jax.jit(adamw.init, out_shardings=shards["opt"])(params)
+
+
 def run(args):
+    compile_cache.enable()
     mesh = make_mesh_for(max_model=args.max_model)
     print(f"mesh: {describe(mesh)} ({mesh.size} devices)")
     if args.mem_budget_mb > 0:
@@ -169,13 +178,13 @@ def run(args):
         else configs.get_config(args.arch)
     if args.attn_backend is not None:
         cfg = dataclasses.replace(cfg, attn_backend=args.attn_backend)
-    if cfg.attn_backend == "jnp":
+    if cfg.attn_impl() == "jnp":
         print("attn backend: jnp")
     else:
         from repro.plan import flash_attn_flop_report, \
             flash_training_eligible
-        eligible = flash_training_eligible(cfg, args.seq)
-        print(f"attn backend: {cfg.attn_backend}"
+        eligible = flash_training_eligible(cfg)
+        print(f"attn backend: {cfg.attn_impl()}"
               + (" (flash custom_vjp: O(S*D) attention residuals)"
                  if eligible else
                  " — flash INELIGIBLE for this arch/shape, jnp path "
@@ -197,7 +206,7 @@ def run(args):
     plan_bytes = None                     # activation budget (MemStat score)
     if remat_mode == "auto" or (remat_mode == "on" and args.mem_budget_mb > 0):
         # a budget implies the planner even without an explicit --remat auto
-        remat, plan_bytes = _auto_remat(cfg, args, mesh, batch_sds)
+        remat, plan_bytes = auto_remat(cfg, args, mesh, batch_sds)
     else:
         remat = CheckpointConfig(enabled=remat_mode != "off",
                                  policy=args.remat_policy)
@@ -217,8 +226,7 @@ def run(args):
         import os
         os.makedirs(args.ckpt_dir, exist_ok=True)
         tc.remat.plan.save(os.path.join(args.ckpt_dir, "remat_plan.json"))
-    params = transformer.init_params(cfg, jax.random.PRNGKey(args.seed))
-    opt = adamw.init(params)
+    params, opt = init_state(cfg, args.seed, mesh, shards)
     ls = LossScale.init() if tc.use_loss_scale else LossScale.noop()
     start_step, data_state = 0, 0
 
@@ -235,9 +243,9 @@ def run(args):
         if tc.use_loss_scale and "loss_scale" in extra:
             ls = dataclasses.replace(ls, scale=jnp.float32(extra["loss_scale"]))
         print(f"resumed from step {start_step} (data batch {data_state})")
-    else:
-        params = jax.device_put(params, shards["params"])
-        opt = jax.device_put(opt, shards["opt"])
+    # the step's outputs live on the mesh: a loss scale that does not
+    # would key a second compile of the step
+    ls = jax.device_put(ls, shards["loss_scale"])
 
     stop = {"now": False}
 
@@ -310,11 +318,7 @@ def run(args):
                 if latest is None:
                     print("[guard] rollback with no checkpoint on disk — "
                           "restarting from init")
-                    params = jax.device_put(
-                        transformer.init_params(
-                            cfg, jax.random.PRNGKey(args.seed)),
-                        shards["params"])
-                    opt = jax.device_put(adamw.init(params), shards["opt"])
+                    params, opt = init_state(cfg, args.seed, mesh, shards)
                     step, data_state = 0, 0
                 else:
                     with maybe_span(tracer, "checkpoint", step=latest,
@@ -328,8 +332,9 @@ def run(args):
                     step = extra.get("step", latest)
                     data_state = extra.get("data_state", 0)
                     if tc.use_loss_scale and "loss_scale" in extra:
-                        ls = dataclasses.replace(
-                            ls, scale=jnp.float32(extra["loss_scale"]))
+                        ls = jax.device_put(dataclasses.replace(
+                            ls, scale=jnp.float32(extra["loss_scale"])),
+                            shards["loss_scale"])
                 guard.reset_history()
                 data = synthetic_lm_batches(cfg, args.batch, args.seq,
                                             seed=args.seed,
@@ -398,8 +403,9 @@ def main():
     ap.add_argument("--attn-backend", default=None,
                     choices=["jnp", "interpret", "pallas"],
                     help="attention kernel override (default: the arch "
-                         "config's backend): jnp (O(S^2) residuals), or "
-                         "the flash kernel via the Pallas interpreter / "
+                         "config's, compiled flash on a TPU, jnp "
+                         "elsewhere): jnp (O(S^2) residuals), or the "
+                         "flash kernel via the Pallas interpreter / "
                          "compiled Mosaic (trainable custom_vjp, O(S*D) "
                          "residuals)")
     ap.add_argument("--remat", default="on", choices=["on", "off", "auto"],

@@ -8,6 +8,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from repro.kernels.kvq import ops as kvq_ops
 from repro.models.layers import apply_rope, rms_norm
@@ -120,17 +121,17 @@ def attn_block(p, x, cfg, *, positions, window: int = 0, layer_window=None,
                    cfg.mrope_sections)
     pos1d = positions[0] if positions.ndim == 3 else positions
     w = window if layer_window is None else layer_window
-    if (cfg.attn_backend != "jnp" and causal and isinstance(w, int)
+    impl = cfg.attn_impl()
+    if (impl != "jnp" and causal and isinstance(w, int)
             and positions.ndim < 3):
         # Pallas flash kernel (prefill/training hot path) — differentiable
         # via its custom_vjp with O(S*D) residuals, so this branch is legal
-        # under jax.grad.  Falls back to the jnp paths for traced per-layer
-        # windows (hybrid scan) and M-RoPE; unsupported shapes fall back to
-        # ref inside the op (one-time warning).
+        # under jax.grad.  Traced per-layer windows (hybrid scan) and
+        # M-RoPE take the jnp paths below.
         from repro.kernels.flash import ops as flash_ops
         fa = functools.partial(
             flash_ops.flash_attention, causal=True, window=w,
-            backend=cfg.attn_backend, resid_dtype=flash_resid_dtype)
+            backend=impl, resid_dtype=flash_resid_dtype)
         if mesh is not None:
             # shard_map over (data, model): batch rows and whole GQA groups
             # stay shard-local, so each device runs the UNCHANGED kernel on
@@ -141,9 +142,9 @@ def attn_block(p, x, cfg, *, positions, window: int = 0, layer_window=None,
             from repro.distributed import sharding as shd
             spec = shd.flash_shard_specs(mesh, b, h, hkv)
             if spec is not None:
-                from jax.experimental.shard_map import shard_map
-                fa = shard_map(fa, mesh=mesh, in_specs=(spec, spec, spec),
-                               out_specs=spec, check_rep=False)
+                fa = jax.shard_map(fa, mesh=mesh,
+                                   in_specs=(spec, spec, spec),
+                                   out_specs=spec, check_vma=False)
         out = fa(jnp.swapaxes(q, 1, 2), jnp.swapaxes(k, 1, 2),
                  jnp.swapaxes(v, 1, 2))
         out = jnp.swapaxes(out, 1, 2)
@@ -298,8 +299,10 @@ def attn_decode(p, x_t, cfg, cache_k, cache_s_k, cache_v, cache_s_v, pos,
     selects the kernel's split-K fan-out.
 
     ``kv_shard`` (from ``sharding.serve_kv_shard``) names how the cache is
-    laid out under ``mesh``: "heads" needs no code change here — XLA keeps
-    the per-kv-head einsums and token write shard-local — while "seq"
+    laid out under ``mesh``: under "heads" XLA keeps the per-kv-head
+    einsums and token write shard-local, and a kernel backend runs under
+    ``shard_map`` over the kv heads (Mosaic calls are not partitionable) —
+    while "seq"
     routes through ``collectives.sp_decode_attention_int8`` so the token
     write and softmax run per-shard with one flash-combine, instead of XLA
     re-sharding the cache around a dynamic_update_slice on its sharded
@@ -366,9 +369,22 @@ def attn_decode(p, x_t, cfg, cache_k, cache_s_k, cache_v, cache_s_v, pos,
             cv = _write_token(cache_v, vq_new, write_at)
             csk = _write_token(cache_s_k, ks_new, write_at)
             csv = _write_token(cache_s_v, vs_new, write_at)
-            out = kvq_ops.decode_attention(q, ck, csk, cv, csv,
-                                           lengths=lengths, bias=bias,
-                                           backend=backend, splits=splits)
+            mask_kw = "lengths" if lengths is not None else "bias"
+
+            def decode(q, ck, csk, cv, csv, mask):
+                return kvq_ops.decode_attention(
+                    q, ck, csk, cv, csv, backend=backend, splits=splits,
+                    **{mask_kw: mask})
+
+            if kv_shard == "heads" and backend != "ref":
+                # XLA cannot partition a Mosaic kernel: each device runs it
+                # on its own kv heads (whole GQA groups, never split)
+                heads = P(None, "model")
+                decode = jax.shard_map(
+                    decode, mesh=mesh, in_specs=(heads,) * 5 + (P(),),
+                    out_specs=heads, check_vma=False)
+            out = decode(q, ck, csk, cv, csv,
+                         lengths if lengths is not None else bias)
     else:
         ck = _write_token(cache_k, k_new.astype(cache_k.dtype), write_at)
         cv = _write_token(cache_v, v_new.astype(cache_v.dtype), write_at)

@@ -73,11 +73,12 @@ class ModelConfig:
     tie_embeddings: bool = False
     subquadratic: bool = False    # eligible for long_500k shapes
     norm_bf16_grad: bool = False  # perf: bf16 cotangent out of RMSNorm
-    # jnp | interpret | pallas — kernels/flash is fwd+bwd differentiable
-    # (custom_vjp with O(S*D) residuals), so "pallas" is legal for training
-    attn_backend: str = "jnp"
+    # auto | jnp | interpret | pallas — kernels/flash is fwd+bwd
+    # differentiable (custom_vjp with O(S*D) residuals), so "pallas" is
+    # legal for training; "auto" picks by platform (see attn_impl)
+    attn_backend: str = "auto"
 
-    ATTN_BACKENDS = ("jnp", "interpret", "pallas")
+    ATTN_BACKENDS = ("auto", "jnp", "interpret", "pallas")
 
     def __post_init__(self):
         if self.head_dim == 0 and self.n_heads:
@@ -86,6 +87,15 @@ class ModelConfig:
             raise ValueError(
                 f"attn_backend={self.attn_backend!r} not in "
                 f"{self.ATTN_BACKENDS}")
+
+    def attn_impl(self) -> str:
+        """The attention path that runs: ``attn_backend`` with "auto"
+        resolved by platform — the compiled flash kernel on a TPU, jnp
+        elsewhere (``interpret`` is only ever chosen explicitly)."""
+        if self.attn_backend != "auto":
+            return self.attn_backend
+        from repro.kernels import platform_backend
+        return "pallas" if platform_backend() == "pallas" else "jnp"
 
     @property
     def padded_vocab(self) -> int:

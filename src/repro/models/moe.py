@@ -126,7 +126,6 @@ def moe_ffn(p, x, cfg, mesh=None):
     if mesh is None or "model" not in mesh.axis_names:
         return _moe_local(p, x, cfg)
 
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     dp = ("pod", "data") if "pod" in mesh.axis_names else ("data",)
@@ -163,9 +162,9 @@ def moe_ffn(p, x, cfg, mesh=None):
         return P()
 
     p_specs = {k: w_spec(k, v) for k, v in p.items()}
-    return shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(b_ax, None, None), p_specs),
         out_specs=(P(b_ax, None, None), P()),
-        check_rep=False,
+        check_vma=False,
     )(x, p)

@@ -4,10 +4,11 @@ The planner (``repro.plan``) predicts peak bytes; nothing so far checked
 the prediction against what is actually resident.  ``MemStat.sample``
 sums ``jax.live_arrays()`` (host-visible handle bytes — the honest
 "what is still alive" number on every backend, including the CPU CI
-where ``device.memory_stats()`` is None), folds in allocator stats when
-the backend exposes them, and scores the result against the plan
-budget: ``mem_sample`` events carry ``frac_of_plan`` so a trace shows
-exactly when live bytes cross the planned peak.
+where ``device.memory_stats()`` is None), folds in the allocator's
+``peak_bytes_in_use`` (read on every sample on a TPU, where a failed read
+raises), and scores the result against the plan budget: ``mem_sample``
+events carry ``frac_of_plan`` so a trace shows exactly when live bytes
+cross the planned peak.
 """
 from __future__ import annotations
 
@@ -25,27 +26,21 @@ class MemStat:
     def sample(self, step: int) -> dict:
         import jax
 
-        live = n = 0
-        try:
-            for a in jax.live_arrays():
-                live += a.nbytes
-                n += 1
-        except Exception:            # backend without live_arrays support
-            live = n = -1
-        dev_peak = None
-        try:
-            stats = jax.devices()[0].memory_stats()
-            if stats:
-                dev_peak = int(stats.get("peak_bytes_in_use", 0))
-        except Exception:            # CPU backend: memory_stats is None
-            pass
+        arrays = jax.live_arrays()
+        live, n = sum(a.nbytes for a in arrays), len(arrays)
+        dev = jax.devices()[0]
+        stats = dev.memory_stats()   # None on the CPU backend
+        if dev.platform == "tpu" and "peak_bytes_in_use" not in (stats or {}):
+            raise RuntimeError(f"memory_stats() of {dev} has no "
+                               f"peak_bytes_in_use: {stats!r}")
+        dev_peak = (int(stats["peak_bytes_in_use"])
+                    if stats and "peak_bytes_in_use" in stats else None)
         rec = {"step": step, "live_bytes": live, "n_arrays": n}
         if dev_peak is not None:
             rec["device_peak_bytes"] = dev_peak
         if self.plan_bytes:
             rec["plan_bytes"] = int(self.plan_bytes)
-            rec["frac_of_plan"] = round(live / self.plan_bytes, 4) \
-                if live >= 0 else None
+            rec["frac_of_plan"] = round(live / self.plan_bytes, 4)
         if self.replica is not None:
             rec["replica"] = self.replica
         self.samples += 1

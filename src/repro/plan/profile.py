@@ -131,30 +131,21 @@ def profile_resnet(params, cfg, image_sds) -> ChainProfile:
     return profile_sequential(fns, image_sds, labels)
 
 
-def flash_training_eligible(cfg, s: int) -> bool:
+def flash_training_eligible(cfg) -> bool:
     """Would the training forward ACTUALLY dispatch to the flash kernel?
 
     Mirrors the dispatch gates end to end — ``transformer.forward`` (a
     uniform window schedule is required to pass a static window into the
     scan), ``attention.attn_block`` (non-MLA attention, 1-D rope
-    positions), and ``kernels.flash.ops`` (Mosaic-legal head_dim and
-    sequence length for the compiled backend).  The planner must budget
-    what the model will really do: a config that *asks* for flash but
-    falls back to the jnp/ref path still pays O(S^2) residuals.
+    positions) and ``ModelConfig.attn_impl`` ("auto" resolved by
+    platform).  The planner must budget what the model will really do: a
+    config whose attention runs the jnp path pays O(S^2) residuals.
     """
-    from repro.kernels.flash import kernel as flash_kernel, ops as flash_ops
     if cfg.mixer not in ("attn", "hybrid") or cfg.mla is not None:
         return False
-    if cfg.attn_backend == "jnp":
+    if cfg.attn_impl() == "jnp":
         return False
-    if cfg.global_layers or cfg.mrope_sections is not None:
-        return False
-    if cfg.attn_backend == "pallas":
-        if cfg.head_dim not in flash_ops.SUPPORTED_HEAD_DIMS:
-            return False
-        if s < flash_kernel.DEFAULT_BQ and s % flash_kernel.DEFAULT_BQ:
-            return False
-    return True
+    return not (cfg.global_layers or cfg.mrope_sections is not None)
 
 
 def attn_resid_bytes(cfg, b: int, s: int, ctx: int,
@@ -191,7 +182,7 @@ def attn_resid_bytes(cfg, b: int, s: int, ctx: int,
     ms = model_shards if (model_shards > 1
                           and cfg.n_heads % model_shards == 0
                           and cfg.n_kv % model_shards == 0) else 1
-    if not flash_training_eligible(cfg, s):
+    if not flash_training_eligible(cfg):
         qo_kv = (2 * cfg.n_heads + 2 * cfg.n_kv) * b * s * cfg.head_dim \
             * dtype_bytes
         return (qo_kv + 4 * b * cfg.n_heads * s * ctx) // ms   # f32 probs
@@ -229,7 +220,7 @@ def flash_bwd_recompute_flops(cfg, b: int, s: int) -> tuple[float, ...]:
     (:func:`flash_training_eligible`) — e.g. ``attn_backend="jnp"``
     (scores are stored, not recomputed) or non-attention layers.
     """
-    if not flash_training_eligible(cfg, s):
+    if not flash_training_eligible(cfg):
         return tuple(0.0 for _ in range(cfg.n_layers))
     bh = b * cfg.n_heads * cfg.head_dim
     return tuple(2.0 * bh * c["bq"] * c["bk"] * (c["dq"] + c["dkv"])
@@ -246,7 +237,7 @@ def flash_attn_flop_report(cfg, b: int, s: int) -> dict:
     what dryrun train cells, the trainer banner and BENCH_flash.json
     report as the sparse-grid FLOP claw-back.
     """
-    if not flash_training_eligible(cfg, s):
+    if not flash_training_eligible(cfg):
         return {"eligible": False, "dense_flops": 0.0, "visited_flops": 0.0,
                 "skip_frac": 0.0, "visited_tile_steps": 0,
                 "dense_tile_steps": 0}
@@ -456,7 +447,7 @@ def profile_transformer(cfg, batch_sds, *, dtype_bytes: int = 2,
     per_block_params = block_elems / cfg.n_layers
 
     windows = [int(w) for w in transformer.layer_windows(cfg)]
-    flash = flash_training_eligible(cfg, s)
+    flash = flash_training_eligible(cfg)
     tile_counts = _flash_tile_counts(cfg, s) if flash else None
     act, flops, labels, resid = [], [], [], []
     for i, w in enumerate(windows):

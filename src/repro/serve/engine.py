@@ -92,7 +92,7 @@ class ServeEngine:
     def __init__(self, params, cfg: ModelConfig, *, max_slots: int,
                  max_len: int, prompt_buckets: Sequence[int] | None = None,
                  policy_name: str = "bf16", quantized: bool = True,
-                 kv_backend: str = "ref", kv_splits: int = 1,
+                 kv_backend: str = "auto", kv_splits: int = 1,
                  temperature: float = 0.0, top_k: int = 0, seed: int = 0,
                  eos_id: Optional[int] = None,
                  max_prefill_per_step: int = 1,
@@ -121,6 +121,11 @@ class ServeEngine:
         # what makes fleet migration trajectory-preserving under
         # sampling (the router's mode).
         self.sampler_keys = sampler_keys
+        if kv_backend == "auto":          # compiled kvq kernel on a TPU
+            from repro.kernels import platform_backend
+            kv_backend = platform_backend()
+        #: the int8 decode-attention backend that runs (``auto`` resolved)
+        self.kv_backend = kv_backend
         self.cfg = cfg
         self.mesh = mesh
         self.max_len = max_len

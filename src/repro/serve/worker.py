@@ -65,6 +65,19 @@ class WorkerDied(RuntimeError):
     """The worker subprocess is gone (SIGKILL, EOF, or RPC timeout)."""
 
 
+def host_chips() -> Optional[int]:
+    """TPU chips on this host, or None off a TPU — asked of a short-lived
+    child so the caller never touches JAX (a process that has initialized
+    JAX holds the host's chips until it exits, and libtpu's host-wide
+    lock then keeps every later process off them)."""
+    out = subprocess.run(
+        [sys.executable, "-c",
+         "import jax; print(jax.default_backend(), jax.device_count())"],
+        capture_output=True, text=True, check=True, timeout=600)
+    platform, count = out.stdout.split()[-2:]
+    return int(count) if platform == "tpu" else None
+
+
 def engine_factory(arch: str = "llama3-8b", smoke: bool = True,
                    init_seed: int = 0, **engine_kwargs):
     """Default worker factory: build config + params + engine from
@@ -189,13 +202,19 @@ def main() -> int:
     inp = sys.stdin.buffer
     hello = _read_frame_blocking(inp)
     try:
+        from repro.launch import compile_cache
+        compile_cache.enable()
         mod, _, fn = hello["factory"].partition(":")
         factory = getattr(importlib.import_module(mod), fn)
         engine = factory(**hello.get("kwargs", {}))
         counts = engine.warmup() if hello.get("warmup", True) \
             else engine.compile_counts()
+        import jax
         _write_frame(out, {"ok": True, "result": {
             "pid": os.getpid(),
+            "devices": [f"{d.platform}:{d.id} "
+                        f"{getattr(d, 'coords', '')}".strip()
+                        for d in jax.devices()],
             "temperature": engine.temperature,
             "sampler_keys": engine.sampler_keys,
             "eos_id": engine.eos_id,
@@ -345,6 +364,8 @@ class WorkerProxy:
             raise WorkerDied(f"worker failed to start: {reply.get('msg')}")
         h = reply["result"]
         self.pid = h["pid"]
+        #: the devices the worker's JAX sees
+        self.devices = h["devices"]
         self.temperature = h["temperature"]
         self.sampler_keys = h["sampler_keys"]
         self.eos_id = h["eos_id"]

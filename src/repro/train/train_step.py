@@ -165,11 +165,14 @@ def make_train_step(cfg: ModelConfig, mesh, tc: TrainConfig,
     b_spec = shd.batch_specs(cfg, batch_sds, mesh)
     b_shard = shd.to_shardings(mesh, b_spec)
 
-    # loss-scale state is tiny and replicated: leave its sharding to jax
+    # loss-scale state is tiny: replicated, pinned on both sides so the
+    # state a step returns feeds the next step without a recompile
+    rep = NamedSharding(mesh, P())
     jitted = jax.jit(
         step,
-        in_shardings=(p_shard, opt_shard, None, b_shard),
-        out_shardings=(p_shard, opt_shard, None, None),
+        in_shardings=(p_shard, opt_shard, rep, b_shard),
+        out_shardings=(p_shard, opt_shard, rep, None),
         donate_argnums=(0, 1) if donate else (),
     )
-    return jitted, dict(params=p_shard, opt=opt_shard, batch=b_shard)
+    return jitted, dict(params=p_shard, opt=opt_shard, batch=b_shard,
+                        loss_scale=rep)

@@ -178,9 +178,15 @@ def _corrupt_shard(mgr, step):
     d = mgr._step_dir(step)
     [shard] = [n for n in os.listdir(d) if n.endswith(".npz")]
     path = os.path.join(d, shard)
+    # invert one byte in the middle of the archive (array payload): every
+    # bit flips, so the bytes the checksum covers always change (a fixed
+    # header pattern can equal what is already there, e.g. the ZIP64
+    # size placeholders numpy 2 writes as ff ff ff ff)
     with open(path, "r+b") as f:
-        f.seek(20)
-        f.write(b"\xff\xff\xff\xff")
+        f.seek(os.path.getsize(path) // 2)
+        byte = f.read(1)
+        f.seek(-1, os.SEEK_CUR)
+        f.write(bytes([byte[0] ^ 0xFF]))
     return path
 
 

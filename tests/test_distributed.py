@@ -127,8 +127,8 @@ class TestCacheSpecs:
         cfg = configs.get_config("llama3-8b")
         cache = jax.eval_shape(
             lambda: transformer.init_cache(cfg, 128, 1024, quantized=True))
-        from repro.launch.mesh import abstract_mesh
-        mesh = abstract_mesh((16, 16), ("data", "model"))
+        from jax.sharding import AbstractMesh
+        mesh = AbstractMesh((16, 16), ("data", "model"))
         specs = shd.cache_specs(cfg, cache, mesh)
         assert specs["k"][1] == "data"     # batch over DP
         # llama3 kv=8 heads don't divide model=16 -> sequence over model
@@ -138,7 +138,7 @@ class TestCacheSpecs:
         cfg = configs.get_config("hymba-1.5b")
         cache = jax.eval_shape(
             lambda: transformer.init_cache(cfg, 1, 2048, quantized=True))
-        from repro.launch.mesh import abstract_mesh
-        mesh = abstract_mesh((16, 16), ("data", "model"))
+        from jax.sharding import AbstractMesh
+        mesh = AbstractMesh((16, 16), ("data", "model"))
         specs = shd.cache_specs(cfg, cache, mesh)
         assert specs["k"][3] == ("data", "model")  # sequence sharded

@@ -87,38 +87,37 @@ class TestFlashGradParity:
 
 
 class TestDispatchGuards:
+    """``backend="pallas"`` runs the compiled kernel or raises: it never
+    hands back the ref result in its place."""
+
     def test_pallas_head_dim_falls_back_with_warning(self):
-        q, k, v = _qkv(1, 2, 2, 256, 32)       # head_dim 32: Mosaic-illegal
-        flash_ops._WARNED_FALLBACKS.clear()
+        # head_dim 32 lowers for the chip (tests/test_tpu_compile.py); on
+        # a host without one the compiled backend must fail loudly
+        # instead of quietly returning the ref output
+        q, k, v = _qkv(1, 2, 2, 256, 32)
         with warnings.catch_warnings(record=True) as w:
             warnings.simplefilter("always")
-            out = flash_ops.flash_attention(q, k, v, backend="pallas")
-            out2 = flash_ops.flash_attention(q, k, v, backend="pallas")
-        msgs = [str(x.message) for x in w
-                if "falling back" in str(x.message)]
-        assert len(msgs) == 1, msgs               # one-time warning
-        assert "head_dim=32" in msgs[0]           # names the offending shape
-        ref = flash_ref.flash_ref(q, k, v)
-        np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
-        np.testing.assert_array_equal(np.asarray(out2), np.asarray(ref))
+            with pytest.raises(ValueError, match="interpret"):
+                flash_ops.flash_attention(q, k, v, backend="pallas")
+        assert not [x for x in w if "falling back" in str(x.message)]
 
     def test_pallas_short_seq_falls_back_with_warning(self):
         q, k, v = _qkv(1, 2, 2, 40, 64)           # s=40 < one 128 block
-        flash_ops._WARNED_FALLBACKS.clear()
         with warnings.catch_warnings(record=True) as w:
             warnings.simplefilter("always")
-            out = flash_ops.flash_attention(q, k, v, backend="pallas")
-        msgs = [str(x.message) for x in w
-                if "falling back" in str(x.message)]
-        assert len(msgs) == 1 and "40" in msgs[0]
-        np.testing.assert_array_equal(
-            np.asarray(out), np.asarray(flash_ref.flash_ref(q, k, v)))
+            with pytest.raises(ValueError, match="interpret"):
+                flash_ops.flash_attention(q, k, v, backend="pallas")
+        assert not [x for x in w if "falling back" in str(x.message)]
 
     def test_interpret_not_restricted(self):
-        """The interpreter runs Mosaic-illegal shapes — no fallback."""
-        q, k, v = _qkv(1, 2, 2, 256, 32)
-        assert flash_ops.unsupported_reason(q, k, v,
-                                            backend="interpret") is None
+        """The interpreter runs the shapes the old guard refused (head_dim
+        32, a sub-block sequence) and agrees with ref."""
+        for s in (256, 40):
+            q, k, v = _qkv(1, 2, 2, s, 32)
+            out = flash_ops.flash_attention(q, k, v, backend="interpret")
+            np.testing.assert_allclose(
+                np.asarray(out), np.asarray(flash_ref.flash_ref(q, k, v)),
+                atol=2e-5)
 
     def test_gqa_indivisible_raises_on_every_backend(self):
         """n_heads % n_kv != 0 is an invalid GQA input everywhere (even
@@ -161,8 +160,8 @@ class TestEndToEnd:
         """Regression: jax.grad through attn_backend='pallas' must trace
         (the custom_vjp covers the backward; before ISSUE 2 this raised).
         eval_shape never lowers to Mosaic, so it runs on any host.
-        head_dim is pinned to a Mosaic-legal 64 (the smoke config's 16
-        would silently fall back to ref and make this test vacuous)."""
+        head_dim is pinned to 64, the width of the production heads' MXU
+        tiles."""
         cfg = dc.replace(configs.smoke_config("llama3-8b"),
                          attn_backend="pallas", n_layers=1, head_dim=64)
         params = transformer.init_params(cfg, jax.random.PRNGKey(0))
@@ -170,7 +169,6 @@ class TestEndToEnd:
             "tokens": jax.ShapeDtypeStruct((1, 128), jnp.int32),
             "labels": jax.ShapeDtypeStruct((1, 128), jnp.int32),
         }
-        flash_ops._WARNED_FALLBACKS.clear()
         with warnings.catch_warnings(record=True) as w:
             warnings.simplefilter("always")
             grads = jax.eval_shape(

@@ -31,11 +31,55 @@ def test_collective_parser_counts_and_widening():
                                not k.endswith("_widened"))
 
 
+def test_compile_cache_dir(monkeypatch, tmp_path):
+    """JAX's own JAX_COMPILATION_CACHE_DIR wins untouched; without it the
+    cache goes to .jax_cache/ at the repository root."""
+    import jax
+    from repro.launch import compile_cache
+    before = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert compile_cache.enable() == str(tmp_path)
+    assert jax.config.jax_compilation_cache_dir == before
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    try:
+        path = compile_cache.enable()
+        assert jax.config.jax_compilation_cache_dir == path
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert path == os.path.join(root, ".jax_cache")
+
+
+def _refused(monkeypatch, capsys, chips: int, replicas: int) -> bool:
+    import argparse
+    from repro import configs
+    from repro.launch import serve
+    from repro.serve import worker
+    monkeypatch.setattr(worker, "host_chips", lambda: chips)
+    args = argparse.Namespace(workers=True, replicas=replicas)
+    rc = serve.run_fleet(args, configs.smoke_config("llama3-8b"), None)
+    return rc == 2 and "runs one worker process" in capsys.readouterr().out
+
+
+def test_workers_refuse_fewer_chips_than_workers(monkeypatch, capsys):
+    """On a TPU host with fewer chips than workers the fleet refuses
+    before spawning any worker."""
+    from repro.serve import worker
+    assert worker.host_chips() is None         # this host has no TPU
+    assert _refused(monkeypatch, capsys, chips=1, replicas=2)
+
+
+def test_workers_refuse_many_on_tpu(monkeypatch, capsys):
+    """libtpu lets one process at a time hold a host's chips: with a chip
+    for each worker, more than one worker is refused all the same."""
+    assert _refused(monkeypatch, capsys, chips=4, replicas=2)
+
+
 def test_memory_floor_positive_all_cells():
     from repro import configs
     from repro.launch.dryrun import _memory_floor_bytes
-    from repro.launch.mesh import abstract_mesh
-    mesh = abstract_mesh((16, 16), ("data", "model"))
+    from jax.sharding import AbstractMesh
+    mesh = AbstractMesh((16, 16), ("data", "model"))
     for arch in configs.list_archs():
         cfg = configs.get_config(arch)
         for shape in configs.applicable_shapes(cfg):

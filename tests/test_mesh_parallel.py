@@ -12,11 +12,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.sharding import PartitionSpec as P
+from jax.sharding import AbstractMesh, PartitionSpec as P
 
 from repro import configs
 from repro.distributed import sharding as shd
-from repro.launch.mesh import abstract_mesh, make_mesh
+from repro.launch.mesh import make_mesh
 from repro.models import transformer
 
 multidevice = pytest.mark.skipif(
@@ -39,7 +39,7 @@ class TestParamSpecsOnMesh:
         """The divisibility fallback makes the production rule table legal
         on ANY mesh: every 'model'-sharded dim divides the model axis."""
         cfg = configs.get_config(arch)
-        mesh = abstract_mesh(mesh_shape, ("data", "model"))
+        mesh = AbstractMesh(mesh_shape, ("data", "model"))
         sds = jax.eval_shape(
             lambda: transformer.init_params(cfg, jax.random.PRNGKey(0)))
         specs = shd.param_specs(cfg, sds, mesh=mesh)
@@ -63,7 +63,7 @@ class TestParamSpecsOnMesh:
         raw = shd.param_specs(cfg, sds)
         # production rules shard wk's last dim
         assert raw["blocks"]["attn"]["wk"][-1] == "model"
-        odd = abstract_mesh((1, 3), ("data", "model"))
+        odd = AbstractMesh((1, 3), ("data", "model"))
         fitted = shd.param_specs(cfg, sds, mesh=odd)
         wk_dim = sds["blocks"]["attn"]["wk"].shape[-1]
         expect = "model" if wk_dim % 3 == 0 else None
@@ -72,7 +72,7 @@ class TestParamSpecsOnMesh:
     @pytest.mark.parametrize("mesh_shape", MESH_SHAPES)
     def test_ssm_replicated_on_every_mesh(self, mesh_shape):
         cfg = configs.get_config("mamba2-130m")
-        mesh = abstract_mesh(mesh_shape, ("data", "model"))
+        mesh = AbstractMesh(mesh_shape, ("data", "model"))
         sds = jax.eval_shape(
             lambda: transformer.init_params(cfg, jax.random.PRNGKey(0)))
         specs = shd.param_specs(cfg, sds, mesh=mesh)
@@ -84,33 +84,33 @@ class TestParamSpecsOnMesh:
 class TestFlashShardSpecs:
     def test_trivial_mesh_opts_out(self):
         assert shd.flash_shard_specs(None, 8, 8, 8) is None
-        mesh = abstract_mesh((1, 1), ("data", "model"))
+        mesh = AbstractMesh((1, 1), ("data", "model"))
         assert shd.flash_shard_specs(mesh, 8, 8, 8) is None
 
     def test_heads_and_batch_shard_when_divisible(self):
-        mesh = abstract_mesh((2, 4), ("data", "model"))
+        mesh = AbstractMesh((2, 4), ("data", "model"))
         spec = shd.flash_shard_specs(mesh, batch=8, heads=8, kv_heads=4)
         assert spec == P("data", "model", None, None)
 
     def test_gqa_misaligned_heads_fall_back_to_batch(self):
         # kv_heads=2 doesn't divide model=4: head sharding would split a
         # GQA group across shards, so only the batch axis shards
-        mesh = abstract_mesh((2, 4), ("data", "model"))
+        mesh = AbstractMesh((2, 4), ("data", "model"))
         spec = shd.flash_shard_specs(mesh, batch=8, heads=8, kv_heads=2)
         assert spec == P("data", None, None, None)
 
     def test_nothing_divides_means_none(self):
-        mesh = abstract_mesh((2, 4), ("data", "model"))
+        mesh = AbstractMesh((2, 4), ("data", "model"))
         assert shd.flash_shard_specs(mesh, batch=3, heads=6, kv_heads=3) \
             is None
 
 
 class TestServeKvShard:
     def test_mode_table(self):
-        mesh = abstract_mesh((1, 8), ("data", "model"))
+        mesh = AbstractMesh((1, 8), ("data", "model"))
         assert shd.serve_kv_shard(None, 8, 64) == "none"
         assert shd.serve_kv_shard(
-            abstract_mesh((8, 1), ("data", "model")), 8, 64) == "none"
+            AbstractMesh((8, 1), ("data", "model")), 8, 64) == "none"
         assert shd.serve_kv_shard(mesh, 8, 64) == "heads"
         assert shd.serve_kv_shard(mesh, 2, 64) == "seq"   # hkv fallback
         assert shd.serve_kv_shard(mesh, 2, 63) == "none"  # nothing divides
@@ -121,7 +121,7 @@ class TestServeKvShard:
         """serve_cache_specs must agree with serve_kv_shard for every
         config, and the slot axis must never shard."""
         cfg = configs.get_config(arch)
-        mesh = abstract_mesh(mesh_shape, ("data", "model"))
+        mesh = AbstractMesh(mesh_shape, ("data", "model"))
         cache = jax.eval_shape(
             lambda: transformer.init_cache(cfg, 4, 128, quantized=True))
         specs = shd.serve_cache_specs(cfg, cache, mesh)
@@ -140,7 +140,7 @@ class TestServeKvShard:
                 assert spec == P(), (name, spec)
 
     def test_spec_shards_counts_devices(self):
-        mesh = abstract_mesh((2, 4), ("data", "model"))
+        mesh = AbstractMesh((2, 4), ("data", "model"))
         assert shd.spec_shards(mesh, P()) == 1
         assert shd.spec_shards(mesh, P(None, "model")) == 4
         assert shd.spec_shards(mesh, P("data", "model")) == 8
@@ -176,7 +176,7 @@ class TestPerDeviceBudgets:
         cfg = configs.get_config("llama3-8b")
         budget = 8 * 2 ** 30
         r1 = serve_capacity_report(cfg, 4096, budget)
-        mesh = abstract_mesh((1, 8), ("data", "model"))
+        mesh = AbstractMesh((1, 8), ("data", "model"))
         r8 = serve_capacity_report(cfg, 4096, budget, mesh=mesh)
         assert r8["kv_shard"] == "heads" and r8["model_shards"] == 8
         assert r8["bytes_per_slot_per_device"] * 8 >= r8["bytes_per_slot"]
@@ -189,7 +189,7 @@ class TestPerDeviceBudgets:
         cfg = configs.smoke_config("llama3-8b")
         sds = {"tokens": jax.ShapeDtypeStruct((8, 64), jnp.int32)}
         tc = TrainConfig(policy="full")
-        mesh = abstract_mesh((4, 2), ("data", "model"))
+        mesh = AbstractMesh((4, 2), ("data", "model"))
         p1 = plan_profile(cfg, tc, sds)
         p2 = plan_profile(cfg, tc, sds, mesh=mesh)
         # microbatch 8/4 dp + residuals /2 model: strictly smaller profile

@@ -142,11 +142,9 @@ class TestBackendAwareResiduals:
     def _profiles(self, s=512):
         import dataclasses
         from repro import configs
-        # head_dim pinned to a Mosaic-legal 64: the smoke config's 16
-        # would make the pallas backend INELIGIBLE (silent ref fallback)
-        # and the profiler must then budget S^2 — tested separately below
+        # head_dim pinned to 64, the production heads' MXU tile width
         cfg = dataclasses.replace(configs.smoke_config("llama3-8b"),
-                                  head_dim=64)
+                                  head_dim=64, attn_backend="jnp")
         batch = {"tokens": jax.ShapeDtypeStruct((2, s), jnp.int32)}
         p_jnp = profile_transformer(cfg, batch)
         p_fla = profile_transformer(
@@ -216,29 +214,30 @@ class TestBackendAwareResiduals:
         assert sum(flash_bwd_recompute_flops(cfg_jnp, 2, 512)) == 0.0
 
     def test_resid_follows_effective_dispatch_not_config_flag(self):
-        """Asking for a flash backend is not enough: shapes/archs where
-        the model silently falls back to the jnp/ref path must still be
-        budgeted at O(S^2), or budget plans OOM."""
+        """Asking for a flash backend is not enough: configs whose
+        attention runs the jnp path (``auto`` off the chip, traced
+        windows) must still be budgeted at O(S^2), or budget plans OOM."""
         import dataclasses
         from repro import configs
         from repro.plan import flash_training_eligible
         batch = {"tokens": jax.ShapeDtypeStruct((2, 512), jnp.int32)}
-        # smoke head_dim=16: pallas falls back to ref -> S^2 budget
-        tiny = dataclasses.replace(configs.smoke_config("llama3-8b"),
-                                   attn_backend="pallas")
-        assert not flash_training_eligible(tiny, 512)
+        # "auto" resolves to jnp on this CPU host -> S^2 budget
+        tiny = configs.smoke_config("llama3-8b")
+        assert tiny.attn_backend == "auto" and tiny.attn_impl() == "jnp"
+        assert not flash_training_eligible(tiny)
         assert profile_transformer(tiny, batch).resid_bytes == \
             profile_transformer(dataclasses.replace(
                 tiny, attn_backend="jnp"), batch).resid_bytes
-        # ...but the interpreter executes any head_dim -> O(S*D) budget
-        interp = dataclasses.replace(tiny, attn_backend="interpret")
-        assert flash_training_eligible(interp, 512)
-        assert profile_transformer(interp, batch).resid_bytes < \
-            profile_transformer(tiny, batch).resid_bytes
+        # the kernels lower every head_dim (smoke: 16) -> O(S*D) budget
+        for backend in ("interpret", "pallas"):
+            flash = dataclasses.replace(tiny, attn_backend=backend)
+            assert flash_training_eligible(flash)
+            assert profile_transformer(flash, batch).resid_bytes < \
+                profile_transformer(tiny, batch).resid_bytes
         # global_layers force traced windows -> jnp path on any backend
         hyb = dataclasses.replace(configs.smoke_config("hymba-1.5b"),
                                   attn_backend="interpret")
-        assert hyb.global_layers and not flash_training_eligible(hyb, 512)
+        assert hyb.global_layers and not flash_training_eligible(hyb)
 
 
 class TestPlannedExecution:
@@ -299,10 +298,10 @@ class TestPlannedExecution:
     def test_microbatch_specs_shard_and_dtype(self):
         """The planner budgets the PER-DEVICE microbatch in the policy's
         compute dtype (regression: global batch + hardcoded bf16)."""
-        from repro.launch.mesh import abstract_mesh
+        from jax.sharding import AbstractMesh
         from repro.train.train_step import microbatch_specs
         sds = {"tokens": jax.ShapeDtypeStruct((64, 32), jnp.int32)}
-        mesh = abstract_mesh((16, 1), ("data", "model"))
+        mesh = AbstractMesh((16, 1), ("data", "model"))
         assert microbatch_specs(sds, accum=2,
                                 mesh=mesh)["tokens"].shape == (2, 32)
         assert microbatch_specs(sds, accum=2)["tokens"].shape == (32, 32)

@@ -286,6 +286,17 @@ class TestEngineParity:
                                    for r in eng._requests_done)
         assert toks["ref"] == toks["interpret"]
 
+    def test_auto_backends_follow_platform(self, llama):
+        """By default the engine and the model pick kernels by platform:
+        compiled Pallas on a TPU, the jnp/ref paths elsewhere."""
+        cfg, params = llama
+        tpu = jax.default_backend() == "tpu"
+        assert cfg.attn_backend == "auto"
+        assert cfg.attn_impl() == ("pallas" if tpu else "jnp")
+        eng = ServeEngine(params, cfg, max_slots=2, max_len=48,
+                          prompt_buckets=(8, 16))
+        assert eng.kv_backend == ("pallas" if tpu else "ref")
+
 
 class TestEngineInvariants:
     def _engine(self, llama, **kw):
