@@ -33,12 +33,6 @@
                   per-device slot capacity (subprocess — the device grid
                   must be set before jax initializes; writes
                   BENCH_shard.json).
-  obs_overhead    tracing overhead contract (ISSUE 10): the same seeded
-                  trace traced vs untraced, interleaved best-of-5 —
-                  tokens/s ratio, frozen compile counts, and span
-                  reconciliation (every DONE request has exactly one
-                  complete submit->terminal span chain; writes
-                  BENCH_obs.json).
 
 Prints ``name,us_per_call,derived`` CSV rows (plus derived metrics).
 """
@@ -905,160 +899,6 @@ def serve_trace():
     print(f"# wrote {os.path.normpath(path)}", flush=True)
 
 
-def obs_overhead():
-    """Observability overhead + reconciliation (ISSUE 10 acceptance).
-
-    Runs the same seeded ragged trace twice per iteration — untraced,
-    then traced (span records + scheduler/engine instrumentation to a
-    JSONL sink) — interleaved best-of-5 like serve_trace, so machine
-    drift hits both sides equally.  The contract being ratcheted:
-
-      * tokens/s traced >= 0.95x untraced (all instrumentation is
-        host-side Python around the jit boundary);
-      * compile_counts() frozen — attaching a tracer must not introduce
-        a single new jit trace;
-      * every DONE request reconstructs to exactly ONE closed ``req``
-        root span whose children include >=1 queue, exactly 1 prefill
-        and >=1 decode, and whose segments sum to the root duration.
-
-    Writes BENCH_obs.json.
-    """
-    import dataclasses
-    import importlib.util
-    import json
-    import os
-    import tempfile
-
-    from repro import configs
-    from repro.events import EventSink
-    from repro.models import transformer
-    from repro.obs import Tracer
-    from repro.serve import ServeEngine, synthetic_trace
-
-    spec = importlib.util.spec_from_file_location(
-        "tracelens", os.path.join(os.path.dirname(__file__), "..",
-                                  "tools", "tracelens.py"))
-    tracelens = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(tracelens)
-
-    # the 2-layer/64-dim smoke step is ~0.5 ms on CPU — an order of
-    # magnitude below any real decode step, which would overstate the
-    # fixed ~30 us/step host-side span cost.  Widen to a step wall in
-    # the low-ms range so the measured ratio reflects the contract's
-    # regime (instrumentation cost amortized against model compute).
-    cfg = dataclasses.replace(configs.smoke_config("llama3-8b"),
-                              n_layers=4, d_model=128, d_ff=384)
-    params = transformer.init_params(cfg, jax.random.PRNGKey(0))
-    slots, max_len, bucket = 4, 128, 16
-    # a longer trace than serve_trace's: each timed wall is ~0.5 s, so
-    # scheduler jitter moves the ratio by well under the 5% contract
-    trace = synthetic_trace(24, seed=7, vocab=cfg.vocab, mean_prompt=10,
-                            max_prompt=bucket, mean_gen=32, max_gen=64,
-                            arrival_rate=1.0)
-    useful = sum(r.max_new_tokens for r in trace)
-
-    eng = ServeEngine(params, cfg, max_slots=slots, max_len=max_len,
-                      prompt_buckets=(bucket,), seed=0)
-    compiles = eng.warmup()
-
-    ev_dir = tempfile.mkdtemp(prefix="bench_obs_")
-    wall_u = wall_t = float("inf")
-    ratios = []
-    ev_path = None
-    for it in range(7):
-        # paired design: each iteration times one untraced and one traced
-        # pass back to back (order alternating — the second run of a pair
-        # sees warmer caches) and contributes ONE traced/untraced ratio.
-        # Per-pair ratios still swing ±10% with CPU scheduler noise (a
-        # profiled traced run has come out FASTER than untraced); the
-        # MEDIAN of 7 pairs is stable at the true ~1-3% overhead, where
-        # min-of-min walls from different pairs flake the 0.95 floor.
-        walls = {}
-        for side in (("untraced", "traced") if it % 2 == 0
-                     else ("traced", "untraced")):
-            eng.reset()
-            if side == "untraced":
-                eng.tracer = None
-                t0 = time.perf_counter()
-                usum = eng.run(trace)
-                walls[side] = time.perf_counter() - t0
-            else:
-                ev_path = os.path.join(ev_dir, f"trace_{it}.jsonl")
-                sink = EventSink(ev_path, flush_every=16)
-                eng.tracer = Tracer(sink, pid="r0")
-                t0 = time.perf_counter()
-                tsum = eng.run(trace)
-                walls[side] = time.perf_counter() - t0
-                eng.tracer = None
-                sink.close()
-        ratios.append(walls["untraced"] / walls["traced"])
-        wall_u = min(wall_u, walls["untraced"])
-        wall_t = min(wall_t, walls["traced"])
-    frozen = eng.compile_counts() == compiles
-    assert frozen, "attaching a tracer re-jitted the engine"
-    assert usum["n_done"] == tsum["n_done"] == len(trace)
-
-    ratios.sort()
-    ratio = ratios[len(ratios) // 2]
-    _rows("obs_traced", wall_t * 1e6, f"tok_s={useful/wall_t:.1f}")
-    _rows("obs_untraced", wall_u * 1e6, f"tok_s={useful/wall_u:.1f}")
-    _rows("obs_overhead_ratio", 0.0, f"{ratio:.3f}x,frozen={frozen}")
-    assert ratio >= 0.95, (
-        f"traced goodput {ratio:.3f}x untraced (pairs {ratios}) — "
-        f"overhead contract broken")
-
-    # ---- reconcile the last traced run's spans against its summary
-    closed, open_spans = tracelens.load_spans(ev_path)
-    groups = tracelens.by_trace(closed)
-    done_chains = 0
-    for rid, spans in groups.items():
-        names = [s["name"] for s in spans]
-        roots = [s for s in spans
-                 if s["name"] == "req" and s["parent"] is None]
-        if not (roots and roots[0]["attrs"].get("state") == "DONE"):
-            continue
-        assert len(roots) == 1, f"rid {rid}: {len(roots)} req roots"
-        assert names.count("prefill") == 1 and "queue" in names, \
-            f"rid {rid}: incomplete chain {names}"
-        # a request whose whole budget was the prefill token never enters
-        # decode residency — no decode span is the correct timeline
-        if roots[0]["attrs"].get("tokens", 0) > 1:
-            assert "decode" in names, f"rid {rid}: missing decode {names}"
-        segs = tracelens.segments(spans, roots[0])
-        assert abs(sum(s["dur"] for s in segs) - roots[0]["dur"]) \
-            <= 1e-9 * max(roots[0]["dur"], 1e-12), \
-            f"rid {rid}: segments do not sum to e2e"
-        done_chains += 1
-    reconciled = done_chains == tsum["n_done"]
-    assert reconciled, (done_chains, tsum["n_done"])
-    assert not open_spans, f"{len(open_spans)} spans left open"
-    _rows("obs_span_reconcile", 0.0,
-          f"done_chains={done_chains},open={len(open_spans)}")
-
-    out = {
-        "trace": {"requests": len(trace), "useful_tokens": useful,
-                  "slots": slots},
-        "overhead": {
-            "tokens_per_s_traced": round(useful / wall_t, 1),
-            "tokens_per_s_untraced": round(useful / wall_u, 1),
-            "tokens_per_s_ratio": round(ratio, 3),
-            "compile_counts_frozen": frozen,
-        },
-        "spans": {"closed": len(closed), "open": len(open_spans),
-                  "traces": len(groups)},
-        "reconcile": {
-            "n_done": tsum["n_done"],
-            "done_span_chains": done_chains,
-            "done_span_chains_complete": reconciled,
-            "segments_sum_to_e2e": True,
-        },
-    }
-    path = os.path.join(os.path.dirname(__file__), "..", "BENCH_obs.json")
-    with open(path, "w") as f:
-        json.dump(out, f, indent=1, sort_keys=True)
-    print(f"# wrote {os.path.normpath(path)}", flush=True)
-
-
 def tbl_codec():
     """Codec throughput + ratios (paper claims up-to 16x passage saving)."""
     from repro.core import encoding
@@ -1167,7 +1007,7 @@ def mesh_shard():
 
 BENCHES = [tbl_codec, tbl_pipeline, tbl_compression, fig8_memory,
            fig10_pipelines, plan_vs_uniform, flash_fwd_bwd, flash_decode,
-           serve_trace, mesh_shard, obs_overhead, fig9_time_acc]
+           serve_trace, mesh_shard, fig9_time_acc]
 
 
 def main() -> None:

@@ -78,6 +78,12 @@ from repro.kernels.tiling import (DEFAULT_BK, DEFAULT_BQ, NEG_INF,  # noqa: F401
                                   q_visits as _q_visits, tile_step_counts,
                                   when as _when)
 
+#: each kernel's name in compiled programs and device traces
+KERNEL_NAMES = {"fwd": "flash_attention_fwd_pallas",
+                "delta": "flash_attention_bwd_pallas_delta",
+                "dq": "flash_attention_bwd_pallas_dq",
+                "dkv": "flash_attention_bwd_pallas_dkv"}
+
 
 def _position_mask(qi, ki, *, bq, bk, causal, window, kv_len, s_len):
     """(BQ, BK) bool validity mask from grid indices, or None if trivial.
@@ -235,7 +241,7 @@ def flash_attention_fwd_pallas(q, k, v, *, causal: bool = True,
             pltpu.VMEM((bq,), jnp.float32),        # running denom
             pltpu.VMEM((bq, d), jnp.float32),      # output accumulator
         ] + ([pltpu.SMEM((1,), jnp.int32)] if debug_counts else []),
-        interpret=interpret,
+        interpret=interpret, name=KERNEL_NAMES["fwd"],
     )(q, k, v)
     m, l = m.reshape(bh, s_len), l.reshape(bh, s_len)
     return (o, m, l, cnt[0].reshape(bh, n_q)) if debug_counts else (o, m, l)
@@ -424,7 +430,7 @@ def flash_attention_bwd_pallas(q, k, v, o, m, l, do, *, causal: bool = True,
                   pl.BlockSpec((1, bq, d), lambda h, i: (h, i, 0))],
         out_specs=pl.BlockSpec((1, 1, bq), lambda h, i: (h, 0, i)),
         out_shape=jax.ShapeDtypeStruct((bh, 1, s_len), jnp.float32),
-        interpret=interpret,
+        interpret=interpret, name=KERNEL_NAMES["delta"],
     )(o, do)
 
     dq_out_specs = [pl.BlockSpec((1, bq, d), lambda h, i, j: (h, i, 0))]
@@ -451,7 +457,7 @@ def flash_attention_bwd_pallas(q, k, v, o, m, l, do, *, causal: bool = True,
         out_shape=dq_out_shape,
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)]
         + ([pltpu.SMEM((1,), jnp.int32)] if debug_counts else []),
-        interpret=interpret,
+        interpret=interpret, name=KERNEL_NAMES["dq"],
     )(q, k, v, do, m, l, delta)
     dq = dq_out[0]                 # out_shape is a list even without counts
 
@@ -503,7 +509,7 @@ def flash_attention_bwd_pallas(q, k, v, o, m, l, do, *, causal: bool = True,
         scratch_shapes=[pltpu.VMEM((bk, d), jnp.float32),
                         pltpu.VMEM((bk, d), jnp.float32)]
         + ([pltpu.SMEM((1,), jnp.int32)] if debug_counts else []),
-        interpret=interpret,
+        interpret=interpret, name=KERNEL_NAMES["dkv"],
     )(q, k, v, do, m, l, delta)
     if debug_counts:
         dk, dv, dkv_counts = dkv_out

@@ -56,6 +56,8 @@ from repro.kernels import tiling
 from repro.kernels.tiling import NEG_INF, imin as _imin
 
 DEFAULT_BS = tiling.DEFAULT_DECODE_BS
+#: the kernel's name in compiled programs and device traces
+KERNEL_NAME = "flash_decode_pallas"
 
 
 def _flash_decode_kernel(*refs, sm_scale, bs, ns, spt, has_bias,
@@ -253,14 +255,15 @@ def flash_decode_pallas(q, k_q, k_s, v_q, v_s, bias=None, lengths=None, *,
             num_scalar_prefetch=1, grid=grid, in_specs=in_specs,
             out_specs=out_specs, scratch_shapes=scratch_shapes)
         out = pl.pallas_call(kern, grid_spec=grid_spec, out_shape=out_shape,
-                             compiler_params=params, interpret=interpret)(
+                             compiler_params=params, interpret=interpret,
+                             name=KERNEL_NAME)(
             jnp.asarray(lengths, jnp.int32), *args)
     else:
         out = pl.pallas_call(kern, grid=grid, in_specs=in_specs,
                              out_specs=out_specs, out_shape=out_shape,
                              scratch_shapes=scratch_shapes,
                              compiler_params=params,
-                             interpret=interpret)(*args)
+                             interpret=interpret, name=KERNEL_NAME)(*args)
 
     if fused:
         o = out[0]

@@ -417,52 +417,54 @@ def loss_fn(params, cfg: ModelConfig, batch: dict, *,
             scan_unroll: int = 1, mesh=None, ce_chunk: int = 0):
     labels = batch["labels"]
     mask = batch.get("loss_mask", jnp.ones_like(labels, jnp.float32))
-    if ce_chunk > 0:
-        # Chunked CE (perf iteration): the LM head + softmax runs per
-        # sequence chunk under remat, so the (B, S, V) logits never
-        # materialize — peak is (B, chunk, V) + recompute in bwd.
-        hidden, aux = forward(params, cfg, batch, policy=policy, remat=remat,
-                              ssd_backend=ssd_backend,
-                              scan_unroll=scan_unroll, mesh=mesh,
-                              return_hidden=True)
+    hidden, aux = forward(params, cfg, batch, policy=policy, remat=remat,
+                          ssd_backend=ssd_backend, scan_unroll=scan_unroll,
+                          mesh=mesh, return_hidden=True)
+    with jax.named_scope("lm_head_loss"):
         head = (params["embed"].T if cfg.tie_embeddings
                 else params["lm_head"]).astype(policy.compute_dtype)
+        if ce_chunk > 0:
+            # Chunked CE (perf iteration): the LM head + softmax runs per
+            # sequence chunk under remat, so the (B, S, V) logits never
+            # materialize — peak is (B, chunk, V) + recompute in bwd.
+            @jax.checkpoint
+            def chunk_nll(x_c, lab_c, mask_c):
+                logits = _mask_padded_vocab(
+                    (x_c @ head).astype(jnp.float32), cfg)
+                m = jax.lax.stop_gradient(logits.max(-1, keepdims=True))
+                shifted = logits - m
+                lse = jnp.log(jnp.sum(jnp.exp(shifted), axis=-1))
+                vi = jax.lax.broadcasted_iota(jnp.int32, logits.shape, 2)
+                ll = jnp.sum(jnp.where(vi == lab_c[..., None], shifted,
+                                       0.0), -1)
+                return ((lse - ll) * mask_c).sum()
 
-        @jax.checkpoint
-        def chunk_nll(x_c, lab_c, mask_c):
+            s = hidden.shape[1]
+            n_chunks = -(-s // ce_chunk)
+            total = jnp.float32(0)
+            for c in range(n_chunks):
+                sl = slice(c * ce_chunk, (c + 1) * ce_chunk)
+                total += chunk_nll(hidden[:, sl], labels[:, sl],
+                                   mask[:, sl])
+            loss = total / jnp.maximum(mask.sum(), 1.0)
+        else:
             logits = _mask_padded_vocab(
-                (x_c @ head).astype(jnp.float32), cfg)
-            m = jax.lax.stop_gradient(logits.max(-1, keepdims=True))
-            shifted = logits - m
+                (hidden @ head).astype(policy.output_dtype), cfg)
+            # Sharding-friendly CE: never gathers the (model-sharded)
+            # vocab dim.  label logit via a masked sum (iota compare
+            # shards cleanly; a take_along_axis gather would force an
+            # all-gather of the logits).
+            logits32 = logits.astype(jnp.float32)
+            m = jax.lax.stop_gradient(logits32.max(-1, keepdims=True))
+            shifted = logits32 - m
             lse = jnp.log(jnp.sum(jnp.exp(shifted), axis=-1))
-            vi = jax.lax.broadcasted_iota(jnp.int32, logits.shape, 2)
-            ll = jnp.sum(jnp.where(vi == lab_c[..., None], shifted, 0.0), -1)
-            return ((lse - ll) * mask_c).sum()
-
-        s = hidden.shape[1]
-        n_chunks = -(-s // ce_chunk)
-        total = jnp.float32(0)
-        for c in range(n_chunks):
-            sl = slice(c * ce_chunk, (c + 1) * ce_chunk)
-            total += chunk_nll(hidden[:, sl], labels[:, sl], mask[:, sl])
-        loss = total / jnp.maximum(mask.sum(), 1.0)
-    else:
-        logits, aux = forward(params, cfg, batch, policy=policy, remat=remat,
-                              ssd_backend=ssd_backend,
-                              scan_unroll=scan_unroll, mesh=mesh)
-        # Sharding-friendly CE: never gathers the (model-sharded) vocab dim.
-        # label logit via a masked sum (iota compare shards cleanly; a
-        # take_along_axis gather would force an all-gather of the logits).
-        logits32 = logits.astype(jnp.float32)
-        m = jax.lax.stop_gradient(logits32.max(-1, keepdims=True))
-        shifted = logits32 - m
-        lse = jnp.log(jnp.sum(jnp.exp(shifted), axis=-1))
-        vocab_iota = jax.lax.broadcasted_iota(jnp.int32, logits.shape,
-                                              logits.ndim - 1)
-        label_logit = jnp.sum(
-            jnp.where(vocab_iota == labels[..., None], shifted, 0.0), axis=-1)
-        nll = lse - label_logit
-        loss = (nll * mask).sum() / jnp.maximum(mask.sum(), 1.0)
+            vocab_iota = jax.lax.broadcasted_iota(jnp.int32, logits.shape,
+                                                  logits.ndim - 1)
+            label_logit = jnp.sum(
+                jnp.where(vocab_iota == labels[..., None], shifted, 0.0),
+                axis=-1)
+            nll = lse - label_logit
+            loss = (nll * mask).sum() / jnp.maximum(mask.sum(), 1.0)
     if cfg.moe is not None:
         loss = loss + moe_aux_weight * aux["moe_aux"]
     return loss, {"nll": loss, **aux}
@@ -749,10 +751,12 @@ def decode_step(params, cfg: ModelConfig, cache: dict, tokens_t, *,
     xs = {"p": params["blocks"], "c": layer_caches}
     if static_window is None:
         xs["w"] = windows
-    x, new_caches = jax.lax.scan(body, x, xs, unroll=scan_unroll)
-    x = rms_norm(x[:, None], params["final_norm"], cfg.norm_eps, bf16_grad=cfg.norm_bf16_grad)[:, 0]
-    head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    logits = _mask_padded_vocab((x @ head).astype(policy.output_dtype), cfg)
+    with jax.named_scope("layers"):
+        x, new_caches = jax.lax.scan(body, x, xs, unroll=scan_unroll)
+    with jax.named_scope("lm_head"):
+        x = rms_norm(x[:, None], params["final_norm"], cfg.norm_eps, bf16_grad=cfg.norm_bf16_grad)[:, 0]
+        head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
+        logits = _mask_padded_vocab((x @ head).astype(policy.output_dtype), cfg)
     if active is not None:
         new_caches["pos"] = pos + active.astype(jnp.int32)
     else:

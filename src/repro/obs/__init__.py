@@ -6,6 +6,8 @@
 * :mod:`repro.obs.trace` — request-scoped span records on the event
   stream; ``tools/tracelens.py`` turns them into timelines and Perfetto
   ``trace.json``.
+* :mod:`repro.obs.compiles` — XLA compiles as ``compile`` spans on the
+  attached tracers.
 * :mod:`repro.obs.schema` — the closed-world registry of event kinds and
   span names (CI fails on undeclared kinds).
 * :mod:`repro.obs.memstat` — planner-vs-live memory reconciliation.

@@ -46,20 +46,43 @@ EVENT_KINDS = {
     "mem_sample": "live-bytes sample scored against the plan budget",
 }
 
-# span name -> one-line description.  Segment classification in
-# tools/tracelens.py keys off these names, so they are closed-world too.
-SPAN_NAMES = {
+# span name -> one-line description, in two groups.  Segment
+# classification in tools/tracelens.py keys off these names, so they are
+# closed-world too.
+
+#: spans that cross host calls (a request's lifetime and its stages):
+#: event records only
+LIFETIME_SPANS = {
     # engine / scheduler (trace = rid, or gid when key_id is set)
     "req": "whole request: submit -> terminal (root span)",
     "queue": "QUEUED: waiting for a slot (reason=submit|replay)",
-    "prefill": "prompt prefill + scatter + first token",
     "decode": "DECODE residency: first token -> retirement",
-    "step": "one engine step (admissions + fused decode + harvest)",
     # router (trace = gid)
     "fleet_req": "whole fleet request: fleet submit -> fleet terminal",
-    "place": "placement attempt on a replica",
     "migrate": "evacuation -> successful re-placement elsewhere",
     "recover": "journal recovery replay of one live request",
+}
+
+#: spans that begin and end inside one host call: the tracer also writes
+#: each into the profiler's own trace as ``repro.<name>``, so that it
+#: lies on the device trace's clock
+CALL_SPANS = {
+    # engine
+    "prefill": "prompt prefill + scatter + first token",
+    "step": "one engine step (admissions + fused decode + harvest); "
+            "ends with prefill_tokens and prefill_padded (bucket - length), "
+            "summed over the step's prefills",
+    "admit": "step phase: deadline shedding, pop_admissible, slot allocation",
+    "dispatch": "step phase: enqueue device work (what=prefill|scatter|"
+                "decode)",
+    "sync": "step phase: blocking host read of device results "
+            "(what=first_token|decode)",
+    "emit": "step phase: hand sampled tokens to their requests, "
+            "retirements, metrics.on_step",
+    "compile": "one XLA backend compile, or its load from the persistent "
+               "cache (source=backend|cache, fun=...)",
+    # router (trace = gid)
+    "place": "placement attempt on a replica",
     # infrastructure
     "rpc": "one worker RPC round-trip (op=...)",
     "journal_append": "one WAL append (+ group-commit fsync when due)",
@@ -70,6 +93,8 @@ SPAN_NAMES = {
     "guard": "guard verdict on the synced loss/grads",
     "checkpoint": "checkpoint save (or rollback restore)",
 }
+
+SPAN_NAMES = {**LIFETIME_SPANS, **CALL_SPANS}
 
 
 # literal emit callsites: EventSink.emit / the private wrappers every

@@ -16,9 +16,18 @@ lifetime stitches together across replicas.  Both halves are emitted
 visible in the stream — an unclosed ``decode`` span after kill -9 is
 the observation, not a bug.
 
+A span that begins and ends inside one host call (``CALL_SPANS`` in
+``repro.obs.schema``: the engine step and its phases, ``prefill``,
+``compile``, ``rpc``, the trainer's spans) also opens a
+``jax.profiler.TraceAnnotation`` named ``repro.<name>``, carrying the
+span's begin attributes, and closes it at ``end``.  While a profiler
+session runs, those host spans land in the profiler's own trace, on the
+device trace's clock; otherwise the annotation records nothing.
+Annotations may close out of order.  Request-lifetime spans
+(``LIFETIME_SPANS``) stay event-only.
+
 Every call site guards ``if tracer is not None`` so the traced-off path
-costs nothing; the overhead contract (tokens/s >= 0.95x untraced,
-compile_counts frozen) is ratcheted via BENCH_obs.json.
+costs nothing.
 """
 from __future__ import annotations
 
@@ -27,7 +36,9 @@ import os
 import time
 from contextlib import contextmanager, nullcontext
 
-from repro.obs.schema import SPAN_NAMES
+from jax.profiler import TraceAnnotation
+
+from repro.obs.schema import CALL_SPANS, SPAN_NAMES
 
 #: per-process tracer instance counter: two tracers with the same pid
 #: label (e.g. a restarted "router" appending to the same event file)
@@ -51,6 +62,7 @@ class Tracer:
         self.clock = clock
         self._ns = f"{os.getpid()}.{next(_INSTANCES)}"
         self._n = 0
+        self._annotations: dict[str, TraceAnnotation] = {}   # sid -> open
 
     def begin(self, name: str, *, trace=None, parent=None, **attrs) -> str:
         if name not in SPAN_NAMES:
@@ -61,12 +73,24 @@ class Tracer:
         self.sink.emit("span_begin", name=name, sid=sid, trace=trace,
                        parent=parent, pid=self.pid, ts=self.clock(),
                        **attrs)
+        if name in CALL_SPANS:
+            ann = self._annotations[sid] = TraceAnnotation("repro." + name,
+                                                           **attrs)
+            ann.__enter__()
         return sid
 
     def end(self, sid, **attrs) -> None:
         if sid is None:          # begin was skipped (tracer attached late)
             return
         self.sink.emit("span_end", sid=sid, ts=self.clock(), **attrs)
+        ann = self._annotations.pop(sid, None)
+        if ann is not None:
+            ann.__exit__(None, None, None)
+
+    def switch(self, sid, name: str, **kw) -> str:
+        """End ``sid`` and begin the next span: consecutive phases."""
+        self.end(sid)
+        return self.begin(name, **kw)
 
     @contextmanager
     def span(self, name: str, *, trace=None, parent=None, **attrs):

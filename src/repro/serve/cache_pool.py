@@ -26,6 +26,7 @@ from repro.models import transformer
 from repro.models.config import ModelConfig
 
 
+@jax.named_scope("scatter")
 def scatter_request(pool_cache: dict, req_cache: dict, slot, length) -> dict:
     """Write a prefilled request cache (leading batch dim 1, sequence axis
     already grown to the pool's ``max_len``) into ``slot``.

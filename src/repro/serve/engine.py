@@ -60,6 +60,7 @@ import numpy as np
 from repro.core.mixed_precision import get_policy
 from repro.models import transformer
 from repro.models.config import ModelConfig
+from repro.obs import compiles
 from repro.serve import sampling
 from repro.serve.cache_pool import SlotPool, scatter_request
 from repro.serve.metrics import ServeMetrics
@@ -190,6 +191,7 @@ class ServeEngine:
                 kvq_splits=kv_splits, active=active, mesh=mesh)
             return pos_before, logits, cache
 
+        @jax.named_scope("sentinel")
         def _verdict(pos_before, logits, sampled, active, tokens):
             # health sentinel, fused into the same program: a live slot is
             # healthy iff its logits are all finite (the padded-vocab mask
@@ -212,8 +214,10 @@ class ServeEngine:
         def _decode(params, cache, tokens, active, key):
             pos_before, logits, cache = _decode_logits(params, cache,
                                                        tokens, active)
-            sampled = sampling.sample_tokens(
-                logits, key, temperature=self.temperature, top_k=self.top_k)
+            with jax.named_scope("sample"):
+                sampled = sampling.sample_tokens(
+                    logits, key, temperature=self.temperature,
+                    top_k=self.top_k)
             return _verdict(pos_before, logits, sampled, active,
                             tokens), cache
 
@@ -226,15 +230,17 @@ class ServeEngine:
             # program, so per-request keys add no host traffic
             pos_before, logits, cache = _decode_logits(params, cache,
                                                        tokens, active)
-            keys = jax.vmap(sampling.fold_request_key,
-                            in_axes=(None, 0, 0))(base_key, kids, draws)
-            sampled = sampling.sample_tokens_per_row(
-                logits, keys, temperature=self.temperature,
-                top_k=self.top_k)
+            with jax.named_scope("sample"):
+                keys = jax.vmap(sampling.fold_request_key,
+                                in_axes=(None, 0, 0))(base_key, kids, draws)
+                sampled = sampling.sample_tokens_per_row(
+                    logits, keys, temperature=self.temperature,
+                    top_k=self.top_k)
             new_draws = jnp.where(active, draws + 1, draws)
             return _verdict(pos_before, logits, sampled, active,
                             tokens), cache, new_draws
 
+        @jax.named_scope("prefill")
         def _prefill(bucket, params, tokens, true_len):
             # mesh: _kv_entry pins each cache entry's sharding as it is
             # built, so the prefill scan carries the pool's layout from the
@@ -362,8 +368,12 @@ class ServeEngine:
 
     @tracer.setter
     def tracer(self, t) -> None:
+        if self._tracer is not None:
+            compiles.detach(self._tracer)
         self._tracer = t
         self.scheduler.tracer = t         # queue-wait spans live there
+        if t is not None:
+            compiles.attach(t)            # compiles from now on are spans
 
     def _end_req_span(self, req: Request, state: str) -> None:
         """Close a request's open decode + root spans at terminal time."""
@@ -687,38 +697,58 @@ class ServeEngine:
 
     def step(self) -> None:
         """Deadline shedding + admissions (bounded prefills) + one decode
-        round with the fused health sentinel."""
+        round with the fused health sentinel.
+
+        Traced, the ``step`` span holds consecutive phase spans: ``admit``,
+        then per admission ``dispatch`` (prefill, scatter), ``sync`` (its
+        first token) and ``emit``, then ``dispatch`` (decode), ``sync``
+        (decode) and ``emit``."""
         hook = self.hooks.get("pre_step")
         if hook is not None:
             hook(self)
-        step_sid = None if self._tracer is None else \
-            self._tracer.begin("step", step=self._step_no)
+        tr = self._tracer
+        if tr is not None:
+            step_sid = tr.begin("step", step=self._step_no)
+            phase = tr.begin("admit", parent=step_sid)
         for req in self.scheduler.shed_expired(self._step_no):
             self.metrics.on_terminal(req.rid, req.state)
             self._end_req_span(req, req.state)
 
         admitted = [] if self._draining else \
             self.scheduler.pop_admissible(self.pool.free_slots, self._step_no)
+        slots = [self.pool.alloc() for _ in admitted]
+        assert None not in slots          # pop_admissible checked free_slots
+        prefill_tokens = prefill_padded = 0
         scatter_ok = self.hooks.get("scatter_filter")
-        for req in admitted:
-            if self._tracer is not None:
-                req.span_ids["prefill"] = self._tracer.begin(
+        for req, slot in zip(admitted, slots):
+            if tr is not None:
+                req.span_ids["prefill"] = tr.begin(
                     "prefill", trace=self._kid(req),
                     parent=req.span_ids.get("req"))
-            slot = self.pool.alloc()
-            assert slot is not None       # pop_admissible checked free_slots
+                phase = tr.switch(phase, "dispatch", parent=step_sid,
+                                  what="prefill")
             prompt = self._replay_prompt(req)   # == req.prompt first time
             plen = len(prompt)
             b = self._bucket_for(plen)
+            prefill_tokens += plen
+            prefill_padded += b - plen
             padded = np.zeros((1, b), np.int32)
             padded[0, :plen] = prompt
             logits, req_cache = self._prefill_fns[b](
                 self.params, jnp.asarray(padded), jnp.int32(plen))
+            if tr is not None:
+                phase = tr.switch(phase, "dispatch", parent=step_sid,
+                                  what="scatter")
             if scatter_ok is None or scatter_ok(self, req, slot):
                 self.pool.cache = self._scatter_fn(
                     self.pool.cache, req_cache, jnp.int32(slot),
                     jnp.int32(plen))
+            if tr is not None:
+                phase = tr.switch(phase, "sync", parent=step_sid,
+                                  what="first_token")
             tok = int(np.asarray(self._sampler(logits, self._first_key(req)))[0])
+            if tr is not None:
+                phase = tr.switch(phase, "emit", parent=step_sid)
             req.state = DECODE
             req.slot = slot
             self._slot_req[slot] = req
@@ -736,18 +766,21 @@ class ServeEngine:
                     self._tokens_dev, self._active_dev, jnp.int32(slot),
                     jnp.int32(tok))
             self._active_buf[slot] = True
-            if self._tracer is not None:
+            if tr is not None:
                 # prefill closes at the first sampled token (the TTFT
                 # edge); decode residency is its own span from here
-                self._tracer.end(req.span_ids.pop("prefill", None),
-                                 bucket=b, plen=plen, slot=int(slot))
+                tr.end(req.span_ids.pop("prefill", None),
+                       bucket=b, plen=plen, slot=int(slot))
             self._emit(req, tok)          # first token: the TTFT sample
-            if self._tracer is not None and req.state == DECODE:
-                req.span_ids["decode"] = self._tracer.begin(
+            if tr is not None and req.state == DECODE:
+                req.span_ids["decode"] = tr.begin(
                     "decode", trace=self._kid(req),
                     parent=req.span_ids.get("req"), slot=int(slot))
 
         if self._active_buf.any():
+            if tr is not None:
+                phase = tr.switch(phase, "dispatch", parent=step_sid,
+                                  what="decode")
             hook = self.hooks.get("pre_decode")
             if hook is not None:
                 hook(self)
@@ -761,21 +794,31 @@ class ServeEngine:
                 self._tokens_dev, self.pool.cache = self._decode_fn(
                     self.params, self.pool.cache, self._tokens_dev,
                     self._active_dev, self._next_key())
+            if tr is not None:
+                phase = tr.switch(phase, "sync", parent=step_sid,
+                                  what="decode")
             # one host sync, same as the fault-free path: the sentinel
             # verdict is encoded in the token sign (-1 = tripped)
             toks = np.asarray(self._tokens_dev)
+            if tr is not None:
+                phase = tr.switch(phase, "emit", parent=step_sid)
             for slot in live:
                 req = self._slot_req[int(slot)]
                 if toks[slot] >= 0:
                     self._emit(req, int(toks[slot]))
                 else:
                     self._fault(req)
+        elif tr is not None and not admitted:
+            phase = tr.switch(phase, "emit", parent=step_sid)
 
         self.metrics.on_step(self._step_no, self.scheduler.queue_depth,
                              self.pool.occupancy)
-        if self._tracer is not None:
-            self._tracer.end(step_sid, admitted=len(admitted),
-                             occupancy=self.pool.occupancy)
+        if tr is not None:
+            tr.end(phase)
+            tr.end(step_sid, admitted=len(admitted),
+                   occupancy=self.pool.occupancy,
+                   prefill_tokens=prefill_tokens,
+                   prefill_padded=prefill_padded)
         self._step_no += 1
 
     def request_states(self) -> dict:

@@ -142,8 +142,9 @@ def build_train_step(cfg: ModelConfig, tc: TrainConfig, mesh=None):
         ls = loss_scale if tc.use_loss_scale else None
         loss, grads, finite = compute_grads(params, ls, batch)
         skip = ~finite if (tc.use_loss_scale or tc.skip_nonfinite) else None
-        new_params, new_opt, metrics = adamw.update(
-            tc.opt, grads, opt_state, params, skip=skip)
+        with jax.named_scope("optimizer"):
+            new_params, new_opt, metrics = adamw.update(
+                tc.opt, grads, opt_state, params, skip=skip)
         new_ls = loss_scale.update(finite) if tc.use_loss_scale else loss_scale
         metrics = {"loss": loss, "grads_finite": finite, **metrics}
         return new_params, new_opt, new_ls, metrics

@@ -24,7 +24,8 @@ from repro.events import EventSink, read_events
 from repro.models import transformer
 from repro.obs import (EVENT_KINDS, SPAN_NAMES, Histogram, MemStat,
                        MetricsRegistry, Tracer, hist_quantile, maybe_span)
-from repro.obs.schema import undeclared_kinds_in_source, validate_events
+from repro.obs.schema import (CALL_SPANS, undeclared_kinds_in_source,
+                              validate_events)
 from repro.serve import (DONE, TERMINAL, RequestJournal, Router,
                          ServeEngine)
 
@@ -248,6 +249,14 @@ def _force_drain(engines):
 MAX_NEW = 8
 
 
+class _ListSink:
+    def __init__(self):
+        self.events = []
+
+    def emit(self, kind, **fields):
+        self.events.append((kind, fields))
+
+
 class TestEngineTrace:
     def test_traced_run_complete_chains_zero_recompiles(
             self, engines_mod, tmp_path):
@@ -280,6 +289,99 @@ class TestEngineTrace:
             segs = tracelens.segments(groups[rid], root)
             assert sum(s["dur"] for s in segs) == \
                 pytest.approx(root["dur"], rel=1e-9)
+
+    def test_step_phases_in_order(self, engines_mod):
+        """One step that admits and decodes: ``step`` holds its phases in
+        order, each parented on it, and ends with the prefill counters."""
+        eng = _reset(engines_mod)[0]
+        sink = _ListSink()
+        eng.tracer = Tracer(sink, pid="r0")
+        eng.submit(_prompts(1)[0], MAX_NEW)
+        eng.step()
+        eng.tracer = None
+        begins = [f for kind, f in sink.events if kind == "span_begin"]
+        ends = {f["sid"]: f for kind, f in sink.events if kind == "span_end"}
+        (step,) = [b for b in begins if b["name"] == "step"]
+        phases = [(b["name"], b.get("what")) for b in begins
+                  if b["parent"] == step["sid"]]
+        assert phases == [("admit", None), ("dispatch", "prefill"),
+                          ("dispatch", "scatter"), ("sync", "first_token"),
+                          ("emit", None), ("dispatch", "decode"),
+                          ("sync", "decode"), ("emit", None)]
+        assert all(b["sid"] in ends for b in begins
+                   if b["parent"] == step["sid"])
+        plen = len(_prompts(1)[0])
+        end = ends[step["sid"]]
+        assert end["prefill_tokens"] == plen
+        assert end["prefill_padded"] == 16 - plen
+        assert end["admitted"] == 1
+        # the phases tile the step, one after another
+        ts = [(b["ts"], ends[b["sid"]]["ts"]) for b in begins
+              if b["parent"] == step["sid"]]
+        assert all(a[1] <= b[0] for a, b in zip(ts, ts[1:]))
+        assert step["ts"] <= ts[0][0] and ts[-1][1] <= end["ts"]
+        _force_drain([eng])
+
+    def test_untraced_step_emits_and_annotates_nothing(self, engines_mod,
+                                                       monkeypatch):
+        from repro.obs import trace as trace_mod
+        made = []
+
+        class Spy:
+            def __init__(self, *a, **kw):
+                made.append(a)
+
+            def __enter__(self):
+                return self
+
+            def __exit__(self, *exc):
+                return None
+
+        monkeypatch.setattr(trace_mod, "TraceAnnotation", Spy)
+        eng = _reset(engines_mod)[0]
+        for pr in _prompts(2, seed=5):
+            eng.submit(pr, 3)
+        while eng.scheduler.has_work():
+            eng.step()
+        assert made == []
+        # the same steps traced open one annotation per call span
+        sink = _ListSink()
+        eng.tracer = Tracer(sink, pid="r0")
+        eng.submit(_prompts(1)[0], 2)
+        while eng.scheduler.has_work():
+            eng.step()
+        eng.tracer = None
+        calls = [f["name"] for kind, f in sink.events
+                 if kind == "span_begin" and f["name"] in CALL_SPANS]
+        assert sorted(a[0] for a in made) == \
+            sorted("repro." + n for n in calls)
+
+    def test_compiles_after_warmup_are_spans(self, engines_mod):
+        eng = _reset(engines_mod)[0]
+        compiles = eng.compile_counts()
+        sink = _ListSink()
+        eng.tracer = Tracer(sink, pid="r0")
+        for pr in _prompts(2, seed=7):
+            eng.submit(pr, MAX_NEW)
+        while eng.scheduler.has_work():
+            eng.step()
+        assert eng.compile_counts() == compiles
+        names = [f["name"] for kind, f in sink.events if kind == "span_begin"]
+        assert "compile" not in names      # steady state compiles nothing
+        # a shape the warm-up never saw compiles once, as one span
+        eng._sampler(np.zeros((3, eng.cfg.padded_vocab), np.float32), eng._key)
+        begins = [f for kind, f in sink.events
+                  if kind == "span_begin" and f["name"] == "compile"]
+        ends = [f for kind, f in sink.events
+                if kind == "span_end" and f["sid"] in
+                {b["sid"] for b in begins}]
+        assert len(begins) == len(ends) == 1
+        assert ends[0]["source"] == "backend" and ends[0]["seconds"] > 0
+        # detached, later compiles leave no span
+        eng.tracer = None
+        n = len(sink.events)
+        eng._sampler(np.zeros((5, eng.cfg.padded_vocab), np.float32), eng._key)
+        assert len(sink.events) == n
 
     def test_metrics_state_is_o_live(self, engines_mod):
         eng = _reset(engines_mod)[0]

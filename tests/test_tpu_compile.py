@@ -5,7 +5,10 @@ block-layout rules or the chip's memory limits.  These tests lower each
 kernel through its public op at production widths (Llama-3-8B heads:
 32 query / 8 KV heads of 128; Mamba2-130M's SSD) for a *described*
 ``v5e:2x2`` topology — the TPU compiler runs here with no chip attached —
-and check that the compiled program really contains the Mosaic kernel.
+and check that the compiled program really contains the Mosaic kernel,
+under its ``name=``.  The engine's decode program and the train step are
+compiled whole at a small width, and must carry their named scopes in
+the ops' metadata, where a device trace finds them.
 
 The topology is described inside a module-scoped fixture (never at import
 time): only one process may load the TPU runtime, and every test worker
@@ -13,14 +16,19 @@ imports every test file.  The persistent compilation cache is off around
 these compiles, since an entry written for a chip cannot be read back
 without one.
 """
+import dataclasses
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro import configs
+from repro.kernels.flash import kernel as flash_kernel
 from repro.kernels.flash import ops as flash_ops
+from repro.kernels.kvq import kernel as kvq_kernel
 from repro.kernels.kvq import ops as kvq_ops
 from repro.kernels.pack import ops as pack_ops
 from repro.kernels.ssd import ops as ssd_ops
@@ -60,6 +68,28 @@ def _kernels(text: str) -> int:
     return text.count("tpu_custom_call")
 
 
+def _scoped(text: str, scope: str) -> bool:
+    """Whether some op's metadata path holds ``scope`` as one part."""
+    rx = re.compile(r"(^|[/(])" + re.escape(scope) + r"([/)]|$)")
+    return any(rx.search(n) for n in re.findall(r'op_name="([^"]*)"', text))
+
+
+def _named_kernels(text: str) -> set:
+    """The ``name=`` of every Pallas call in the program."""
+    return set(re.findall(r'op_name="[^"]*/([^"/]+)/pallas_call"', text))
+
+
+def _on_chip(tree, sharding):
+    return jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding),
+        tree)
+
+
+#: a small model at real head width, for whole-program compiles
+SMALL = dict(n_layers=2, d_model=256, n_heads=4, n_kv=2, head_dim=D,
+             d_ff=512, vocab=1024)
+
+
 @pytest.mark.parametrize("window", [0, 1024])
 def test_flash_fwd_compiles(one_chip, window):
     text = _compile(
@@ -82,6 +112,7 @@ def test_flash_fwd_bwd_compiles(one_chip, window):
                     ((1, HKV, 2048, D), jnp.bfloat16),
                     ((1, HKV, 2048, D), jnp.bfloat16))
     assert _kernels(text) >= 4          # fwd, delta, dQ, dKV
+    assert _named_kernels(text) == set(flash_kernel.KERNEL_NAMES.values())
 
 
 @pytest.mark.parametrize("s,d", [(256, 32), (40, 64)])
@@ -112,6 +143,7 @@ def test_kvq_decode_compiles(one_chip, splits, with_lengths):
                     ((b, HKV, s, D), jnp.int8), ((b, HKV, s), jnp.float32),
                     ((b,), jnp.int32))
     assert _kernels(text) == 1
+    assert _named_kernels(text) == {kvq_kernel.KERNEL_NAME}
 
 
 def test_pack_encode_compiles(one_chip):
@@ -139,3 +171,48 @@ def test_ssd_fwd_compiles(one_chip):
                     ((b, L, SSD_N), jnp.float32), ((b, L, SSD_N), jnp.float32),
                     ((SSD_H,), jnp.float32))
     assert _kernels(text) == 1
+
+
+def test_decode_program_names_its_phases(one_chip):
+    """The engine's whole decode round: the named kvq kernel, and the
+    ``layers``, ``lm_head``, ``sample`` and ``sentinel`` scopes."""
+    from repro.models import transformer
+    from repro.serve import ServeEngine
+
+    cfg = dataclasses.replace(configs.smoke_config("llama3-8b"), **SMALL)
+    params = jax.eval_shape(
+        lambda: transformer.init_params(cfg, jax.random.PRNGKey(0)))
+    params = jax.tree_util.tree_map(
+        lambda x: jnp.zeros(x.shape, jnp.bfloat16), params)
+    eng = ServeEngine(params, cfg, max_slots=8, max_len=1024,
+                      prompt_buckets=(128,), kv_backend="pallas")
+    text = eng._decode_fn.lower(*_on_chip(
+        (eng.params, eng.pool.cache, eng._tokens_dev, eng._active_dev,
+         eng._key), one_chip)).compile().as_text()
+    assert _named_kernels(text) == {kvq_kernel.KERNEL_NAME}
+    for scope in ("layers", "lm_head", "sample", "sentinel"):
+        assert _scoped(text, scope), scope
+
+
+def test_train_step_names_its_phases(one_chip):
+    """The whole train step: the named flash kernels, the LM head with
+    its loss (forward and backward) and the optimizer."""
+    from repro.core.mixed_precision import LossScale
+    from repro.models import transformer
+    from repro.optim import adamw
+    from repro.train.train_step import TrainConfig, build_train_step
+
+    cfg = dataclasses.replace(configs.smoke_config("llama3-8b"), **SMALL,
+                              attn_backend="pallas")
+    params = jax.eval_shape(
+        lambda: transformer.init_params(cfg, jax.random.PRNGKey(0)))
+    opt = jax.eval_shape(adamw.init, params)
+    batch = {k: jax.ShapeDtypeStruct((2, 256), jnp.int32)
+             for k in ("tokens", "labels")}
+    step = build_train_step(cfg, TrainConfig())
+    text = jax.jit(step).lower(*_on_chip(
+        (params, opt, LossScale.noop(), batch), one_chip)).compile().as_text()
+    assert _named_kernels(text) == set(flash_kernel.KERNEL_NAMES.values())
+    assert _scoped(text, "lm_head_loss") and _scoped(text, "optimizer")
+    assert re.search(r'op_name="[^"]*transpose\(jvp\(lm_head_loss\)\)',
+                     text)
