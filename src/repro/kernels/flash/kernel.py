@@ -52,8 +52,19 @@ their matmuls — the measured visited-tile counts that tests, benchmarks
 and the memory planner's FLOP budgets are validated against
 (:func:`tile_step_counts` is the analytic twin).
 
-MXU shapes: every contraction is (128, D) x (D, 128) or (128, 128) x
-(128, D) with D in {64, 128} — lane-aligned (ops.py guards other shapes).
+Tiles: each grid takes the (bq, bk) it is given, and where none is
+given the one :func:`repro.kernels.tiling.flash_tiles` picks for that
+kernel from the sequence, the head dim and the window (up to 1024 a
+side, clamped to S), so the contractions are (BQ, D) x (D, BK) and
+(BQ, BK) x (BK, D).
+
+Operand dtypes: q·kᵀ and dO·vᵀ take their tiles as stored when both
+share a dtype (bf16 in training and serving), with f32 accumulation —
+exact, since a product of two bf16 values is exact in f32; a mixed pair
+(the ``resid_bf16`` policy's f32 dO against bf16-saved v) is widened to
+f32 first.  Values a kernel computes (P, dS) stay f32, and the stored
+operand they meet is widened exactly.  The running max, sum, exp and the
+(m, l) residuals are f32.
 
 Causal/window masking inside a visited tile still compares absolute
 positions built from the (remapped) grid indices; ``kv_len`` masks padded
@@ -72,7 +83,7 @@ from jax.experimental.pallas import tpu as pltpu
 # kvq split-K decode grids); re-exported here because this module is the
 # flash family's historical home for it.
 from repro.kernels.tiling import (DEFAULT_BK, DEFAULT_BQ, NEG_INF,  # noqa: F401
-                                  imax as _imax, imin as _imin,
+                                  flash_tiles, imax as _imax, imin as _imin,
                                   kv_tile_bounds, q_tile_bounds,
                                   kv_visits as _kv_visits,
                                   q_visits as _q_visits, tile_step_counts,
@@ -100,6 +111,24 @@ def _position_mask(qi, ki, *, bq, bk, causal, window, kv_len, s_len):
         if window > 0:
             ok &= (q_pos - k_pos) < window
     return ok
+
+
+def _tiles(bq, bk, s_len, d, *, window, kernel):
+    """A grid's (bq, bk): as given, else :func:`flash_tiles`'s choice for
+    ``kernel``; clamped to S."""
+    if bq is None or bk is None:
+        tq, tk = flash_tiles(s_len, d, window=window, kernel=kernel)
+        bq, bk = tq if bq is None else bq, tk if bk is None else bk
+    return min(bq, s_len), min(bk, s_len)
+
+
+def _dot_t(a, b):
+    """a b^T accumulated in f32: from the tiles as stored where both share
+    a dtype, else both widened to f32."""
+    if a.dtype != b.dtype:
+        a, b = a.astype(jnp.float32), b.astype(jnp.float32)
+    return jax.lax.dot_general(a, b, (((1,), (1,)), ((), ())),
+                               preferred_element_type=jnp.float32)
 
 
 # ---------------------------------------------------------------------------
@@ -131,11 +160,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_out_ref, l_out_ref, *refs,
             cnt_acc[0] = 0
 
     def _step():
-        q = q_ref[...][0].astype(jnp.float32)                  # (BQ, D)
-        k = k_ref[...][0].astype(jnp.float32)                  # (BK, D)
-        v = v_ref[...][0].astype(jnp.float32)
-        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * sm_scale
-
+        s = _dot_t(q_ref[...][0], k_ref[...][0]) * sm_scale   # (BQ, BK)
         ok = _position_mask(qi, ki, bq=bq, bk=bk, causal=causal,
                             window=window, kv_len=kv_len, s_len=s_len)
         if ok is not None:
@@ -145,6 +170,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_out_ref, l_out_ref, *refs,
         m_new = jnp.maximum(m_prev, s.max(axis=-1))
         alpha = jnp.exp(m_prev - m_new)
         p = jnp.exp(s - m_new[:, None])
+        v = v_ref[...][0].astype(jnp.float32)     # widened: P stays f32
         acc_ref[...] = acc_ref[...] * alpha[:, None] + jnp.dot(
             p, v, preferred_element_type=jnp.float32)
         l_ref[...] = l_ref[...] * alpha + p.sum(axis=-1)
@@ -181,7 +207,8 @@ def _kv_wedge_index(group, bounds_kw):
 def flash_attention_fwd_pallas(q, k, v, *, causal: bool = True,
                                window: int = 0,
                                sm_scale: float | None = None,
-                               bq: int = DEFAULT_BQ, bk: int = DEFAULT_BK,
+                               bq: int | None = None,
+                               bk: int | None = None,
                                kv_len: int | None = None,
                                interpret: bool = False,
                                debug_counts: bool = False):
@@ -195,14 +222,14 @@ def flash_attention_fwd_pallas(q, k, v, *, causal: bool = True,
     visit counts; compare against :func:`tile_step_counts`).
 
     Flat batch*head layout; the wrapper in ops.py folds (B, H) and GQA.
-    S % bq == 0 and S % bk == 0 (ops.py pads); ``kv_len`` (< S when ops.py
-    padded) masks the padded KV columns.
+    ``bq``/``bk`` default to ``tiling.flash_tiles``'s choice.  S % bq == 0
+    and S % bk == 0 (ops.py pads); ``kv_len`` (< S when ops.py padded)
+    masks the padded KV columns.
     """
     bh, s_len, d = q.shape
     bhkv = k.shape[0]
     group = bh // bhkv
-    bq = min(bq, s_len)
-    bk = min(bk, s_len)
+    bq, bk = _tiles(bq, bk, s_len, d, window=window, kernel="fwd")
     assert s_len % bq == 0 and s_len % bk == 0, (s_len, bq, bk)
     n_q = s_len // bq
     scale = sm_scale if sm_scale is not None else d ** -0.5
@@ -258,10 +285,10 @@ def _bwd_delta_kernel(o_ref, do_ref, delta_ref):
 
 
 def _recompute_probs(q, k, m, l, ok, *, sm_scale):
-    """P = exp(S - lse) from saved stats; masked entries exactly zero."""
-    s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * sm_scale
+    """P = exp(S - lse) from saved stats, in f32; masked entries exactly
+    zero."""
     lse = m + jnp.log(jnp.maximum(l, 1e-30))
-    p = jnp.exp(s - lse[:, None])
+    p = jnp.exp(_dot_t(q, k) * sm_scale - lse[:, None])
     if ok is not None:
         p = jnp.where(ok, p, 0.0)
     return p
@@ -289,20 +316,15 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, m_ref, l_ref, delta_ref,
             cnt_acc[0] = 0
 
     def _step():
-        q = q_ref[...][0].astype(jnp.float32)                  # (BQ, D)
-        k = k_ref[...][0].astype(jnp.float32)                  # (BK, D)
-        v = v_ref[...][0].astype(jnp.float32)
-        do = do_ref[...][0].astype(jnp.float32)
-        m = m_ref[...][0, 0]
-        l = l_ref[...][0, 0]
-        delta = delta_ref[...][0, 0]
-
+        k = k_ref[...][0]                                      # (BK, D)
         ok = _position_mask(qi, ki, bq=bq, bk=bk, causal=causal,
                             window=window, kv_len=kv_len, s_len=s_len)
-        p = _recompute_probs(q, k, m, l, ok, sm_scale=sm_scale)  # (BQ, BK)
-        dp = jnp.dot(do, v.T, preferred_element_type=jnp.float32)
-        ds = p * (dp - delta[:, None])
-        acc_ref[...] += jnp.dot(ds, k, preferred_element_type=jnp.float32)
+        p = _recompute_probs(q_ref[...][0], k, m_ref[...][0, 0],
+                             l_ref[...][0, 0], ok, sm_scale=sm_scale)
+        dp = _dot_t(do_ref[...][0], v_ref[...][0])             # dO V^T
+        ds = p * (dp - delta_ref[...][0, 0][:, None])
+        acc_ref[...] += jnp.dot(ds, k.astype(jnp.float32),
+                                preferred_element_type=jnp.float32)
         if count:
             cnt_acc[0] += 1
 
@@ -349,21 +371,18 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, m_ref, l_ref, delta_ref,
             cnt_acc[0] = 0
 
     def _step():
-        q = q_ref[...][0].astype(jnp.float32)                  # (BQ, D)
-        k = k_ref[...][0].astype(jnp.float32)                  # (BK, D)
-        v = v_ref[...][0].astype(jnp.float32)
-        do = do_ref[...][0].astype(jnp.float32)
-        m = m_ref[...][0, 0]
-        l = l_ref[...][0, 0]
-        delta = delta_ref[...][0, 0]
-
+        q = q_ref[...][0]                                      # (BQ, D)
+        do = do_ref[...][0]
         ok = _position_mask(qi, ki, bq=bq, bk=bk, causal=causal,
                             window=window, kv_len=kv_len, s_len=s_len)
-        p = _recompute_probs(q, k, m, l, ok, sm_scale=sm_scale)  # (BQ, BK)
-        dv_acc[...] += jnp.dot(p.T, do, preferred_element_type=jnp.float32)
-        dp = jnp.dot(do, v.T, preferred_element_type=jnp.float32)
-        ds = p * (dp - delta[:, None])
-        dk_acc[...] += jnp.dot(ds.T, q, preferred_element_type=jnp.float32)
+        p = _recompute_probs(q, k_ref[...][0], m_ref[...][0, 0],
+                             l_ref[...][0, 0], ok, sm_scale=sm_scale)
+        dv_acc[...] += jnp.dot(p.T, do.astype(jnp.float32),
+                               preferred_element_type=jnp.float32)
+        dp = _dot_t(do, v_ref[...][0])                         # dO V^T
+        ds = p * (dp - delta_ref[...][0, 0][:, None])
+        dk_acc[...] += jnp.dot(ds.T, q.astype(jnp.float32),
+                               preferred_element_type=jnp.float32)
         if count:
             cnt_acc[0] += 1
 
@@ -383,7 +402,8 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, m_ref, l_ref, delta_ref,
 def flash_attention_bwd_pallas(q, k, v, o, m, l, do, *, causal: bool = True,
                                window: int = 0,
                                sm_scale: float | None = None,
-                               bq: int = DEFAULT_BQ, bk: int = DEFAULT_BK,
+                               bq: int | None = None,
+                               bk: int | None = None,
                                kv_len: int | None = None,
                                interpret: bool = False,
                                debug_counts: bool = False,
@@ -398,30 +418,44 @@ def flash_attention_bwd_pallas(q, k, v, o, m, l, do, *, causal: bool = True,
     dkv_counts (BHkv, nK)) of executed inner steps (the dKV counter sums
     over the GQA group: group * visited q tiles when the KV tile is live).
 
+    ``bq``/``bk`` tile all three grids when given; by default the delta
+    and dQ grids take ``tiling.flash_tiles``'s dQ choice and the dKV grid
+    its dKV choice.
+
     ``grad_dtypes`` (dtype names for dq, dk, dv) overrides the output
     dtypes, which default to following q/k/v — under a residual policy
     the saved q/k/v are bf16 but the gradients should leave the f32 VMEM
     accumulators at the PRIMAL precision, not round-trip through bf16.
     """
     bh, s_len, d = q.shape
-    bhkv = k.shape[0]
-    group = bh // bhkv
-    bq = min(bq, s_len)
-    bk = min(bk, s_len)
-    assert s_len % bq == 0 and s_len % bk == 0, (s_len, bq, bk)
-    n_q, n_k = s_len // bq, s_len // bk
+    dq_dt, dk_dt, dv_dt = (q.dtype, k.dtype, v.dtype) if grad_dtypes is \
+        None else (jnp.dtype(t) for t in grad_dtypes)
+    kw = dict(causal=causal, window=window, kv_len=kv_len,
+              interpret=interpret, debug_counts=debug_counts)
     scale = sm_scale if sm_scale is not None else d ** -0.5
+    m, l = m.reshape(bh, 1, s_len), l.reshape(bh, 1, s_len)
+    delta, dq, *dq_cnt = _bwd_dq(q, k, v, o, m, l, do, sm_scale=scale,
+                                 bq=bq, bk=bk, dq_dt=dq_dt, **kw)
+    dk, dv, *dkv_cnt = _bwd_dkv(q, k, v, do, m, l, delta, sm_scale=scale,
+                                bq=bq, bk=bk, dk_dt=dk_dt,
+                                dv_dt=dv_dt, **kw)
+    if debug_counts:
+        return dq, dk, dv, dq_cnt[0], dkv_cnt[0]
+    return dq, dk, dv
+
+
+def _bwd_dq(q, k, v, o, m, l, do, *, causal, window, sm_scale, bq, bk,
+            kv_len, interpret, debug_counts, dq_dt):
+    """The delta and dQ kernels: (delta, dq[, dq_counts (BH, nQ)])."""
+    bh, s_len, d = q.shape
+    group = bh // k.shape[0]
+    bq, bk = _tiles(bq, bk, s_len, d, window=window, kernel="dq")
+    assert s_len % bq == 0 and s_len % bk == 0, (s_len, bq, bk)
+    n_q = s_len // bq
     kv_len = s_len if kv_len is None else kv_len
     bounds_kw = dict(bq=bq, bk=bk, causal=causal, window=window,
                      kv_len=kv_len)
-    mask_kw = dict(causal=causal, window=window, kv_len=kv_len, s_len=s_len)
     kv_steps = max(_kv_visits(s_len, **bounds_kw))
-    q_steps = max(hi - lo + 1 for lo, hi in
-                  (q_tile_bounds(j, bq=bq, bk=bk, causal=causal,
-                                 window=window, n_q=n_q, kv_len=kv_len)
-                   for j in range(n_k)))
-    dq_dt, dk_dt, dv_dt = (q.dtype, k.dtype, v.dtype) if grad_dtypes is \
-        None else (jnp.dtype(t) for t in grad_dtypes)
 
     delta = pl.pallas_call(
         _bwd_delta_kernel,
@@ -440,10 +474,9 @@ def flash_attention_bwd_pallas(q, k, v, o, m, l, do, *, causal: bool = True,
                                          lambda h, i, j: (h, i, 0, 0)))
         dq_out_shape.append(jax.ShapeDtypeStruct((bh, n_q, 1, 1), jnp.int32))
 
-    m, l = m.reshape(bh, 1, s_len), l.reshape(bh, 1, s_len)
     row_spec = pl.BlockSpec((1, 1, bq), lambda h, i, j: (h, 0, i))
     dq_out = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel, sm_scale=scale, s_len=s_len,
+        functools.partial(_bwd_dq_kernel, sm_scale=sm_scale, s_len=s_len,
                           count=debug_counts, **bounds_kw),
         grid=(bh, n_q, kv_steps),
         in_specs=[
@@ -459,7 +492,26 @@ def flash_attention_bwd_pallas(q, k, v, o, m, l, do, *, causal: bool = True,
         + ([pltpu.SMEM((1,), jnp.int32)] if debug_counts else []),
         interpret=interpret, name=KERNEL_NAMES["dq"],
     )(q, k, v, do, m, l, delta)
-    dq = dq_out[0]                 # out_shape is a list even without counts
+    if debug_counts:
+        return delta, dq_out[0], dq_out[1].reshape(bh, n_q)
+    return delta, dq_out[0]       # out_shape is a list even without counts
+
+
+def _bwd_dkv(q, k, v, do, m, l, delta, *, causal, window, sm_scale, bq, bk,
+             kv_len, interpret, debug_counts, dk_dt, dv_dt):
+    """The dKV kernel: (dk, dv[, dkv_counts (BHkv, nK)])."""
+    bh, s_len, d = q.shape
+    bhkv = k.shape[0]
+    group = bh // bhkv
+    bq, bk = _tiles(bq, bk, s_len, d, window=window, kernel="dkv")
+    assert s_len % bq == 0 and s_len % bk == 0, (s_len, bq, bk)
+    n_q, n_k = s_len // bq, s_len // bk
+    kv_len = s_len if kv_len is None else kv_len
+    mask_kw = dict(causal=causal, window=window, kv_len=kv_len, s_len=s_len)
+    q_steps = max(hi - lo + 1 for lo, hi in
+                  (q_tile_bounds(j, bq=bq, bk=bk, causal=causal,
+                                 window=window, n_q=n_q, kv_len=kv_len)
+                   for j in range(n_k)))
 
     def _q_head(hk, j, gi, i, g=group):
         del j, i
@@ -489,7 +541,7 @@ def flash_attention_bwd_pallas(q, k, v, o, m, l, do, *, causal: bool = True,
                                           _q_tile(hk, j, gi, i)))
 
     dkv_out = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel, sm_scale=scale, n_q=n_q,
+        functools.partial(_bwd_dkv_kernel, sm_scale=sm_scale, n_q=n_q,
                           group=group, count=debug_counts, bq=bq, bk=bk,
                           **mask_kw),
         grid=(bhkv, n_k, group, q_steps),
@@ -513,7 +565,5 @@ def flash_attention_bwd_pallas(q, k, v, o, m, l, do, *, causal: bool = True,
     )(q, k, v, do, m, l, delta)
     if debug_counts:
         dk, dv, dkv_counts = dkv_out
-        return (dq, dk, dv, dq_out[1].reshape(bh, n_q),
-                dkv_counts.reshape(bhkv, n_k))
-    dk, dv = dkv_out
-    return dq, dk, dv
+        return dk, dv, dkv_counts.reshape(bhkv, n_k)
+    return tuple(dkv_out)

@@ -15,6 +15,8 @@ silently.
 Flash (prefill/training) bounds: :func:`kv_tile_bounds`,
 :func:`q_tile_bounds`, :func:`tile_step_counts` — see
 ``kernels/flash/kernel.py`` for how the wedge grids consume them.
+:func:`flash_tiles` picks each flash kernel's (bq, bk) from the shape,
+and :func:`flash_vmem_bytes` is the VMEM estimate it checks them against.
 
 Decode (split-K) bounds: :func:`resolve_decode_grid` sizes the
 (splits, steps-per-split) axes, :func:`decode_last_live_tile` turns a
@@ -30,6 +32,16 @@ NEG_INF = -1e30
 DEFAULT_BQ = 128
 DEFAULT_BK = 128
 DEFAULT_DECODE_BS = 512
+
+#: the flash kernels whose tiles :func:`flash_tiles` chooses
+FLASH_KERNELS = ("fwd", "dq", "dkv")
+#: the largest flash tile side; past it a v5e gains under 10% a call
+FLASH_TILE_CAP = 1024
+#: VMEM a Mosaic kernel may use on a TPU v5e without asking for more
+V5E_SCOPED_VMEM = 16 * 2**20
+#: (bq, bk)-sized f32 values that each kernel body keeps in VMEM at once,
+#: calibrated from the least scoped limit Mosaic compiles each kernel under
+_FLASH_TILE_TEMPS = {"fwd": 2, "dq": 2, "dkv": 3}
 
 
 def imin(a, b):
@@ -146,6 +158,65 @@ def tile_step_counts(s_len, *, bq: int = DEFAULT_BQ, bk: int = DEFAULT_BK,
     return {"fwd": fwd, "dq": fwd, "dkv": dkv,
             "dense": (s_len // bq) * (s_len // bk),
             "bq": bq, "bk": bk}
+
+
+def flash_vmem_bytes(bq: int, bk: int, d: int, *, kernel: str) -> int:
+    """Estimated VMEM of one flash kernel at tiles (bq, bk), head dim d.
+
+    Pallas double-buffers every block it pipelines; scratch and the
+    body's live values are single.  Blocks are counted at 4 bytes an
+    element, which bounds both bf16 and f32 callers.  A (1, bq) f32 row
+    block (m, l, delta) fills 8 sublanes.
+    """
+    row = 8 * 4 * bq
+    if kernel == "fwd":      # q, k, v, o; m, l; scratch acc, m, l
+        blocks = (2 * bq + 2 * bk) * d * 4 + 2 * row
+        scratch = bq * d * 4 + 2 * row
+        wide = bk * d * 4                           # v widened for P.V
+    elif kernel == "dq":     # q, dO, dQ, k, v; m, l, delta; scratch acc
+        blocks = (3 * bq + 2 * bk) * d * 4 + 3 * row
+        scratch = bq * d * 4
+        wide = bk * d * 4                           # k widened for dS.K
+    elif kernel == "dkv":    # q, dO, k, v, dK, dV; m, l, delta; dK/dV acc
+        blocks = (2 * bq + 4 * bk) * d * 4 + 3 * row
+        scratch = 2 * bk * d * 4
+        wide = 2 * bq * d * 4                       # dO and q widened
+    else:
+        raise ValueError(f"flash_vmem_bytes: unknown kernel {kernel!r}; "
+                         f"expected one of {FLASH_KERNELS}")
+    temps = _FLASH_TILE_TEMPS[kernel] * bq * bk * 4
+    return 2 * blocks + scratch + wide + temps
+
+
+def flash_tiles(s_len: int, d: int, *, window: int = 0,
+                kernel: str = "fwd") -> tuple[int, int]:
+    """(bq, bk) for one flash kernel on a (padded) sequence of ``s_len``.
+
+    Every grid step costs a fixed fraction of a microsecond on top of its
+    matmuls, so each kernel takes the largest tiles that
+    :data:`FLASH_TILE_CAP`, the sequence and the v5e's default scoped VMEM
+    (:func:`flash_vmem_bytes`) allow.  Tiles are multiples of 128 that
+    divide ``s_len``; a sequence of one block or less is one tile.  On a
+    windowed layer neither side exceeds the window (rounded down to 128),
+    so the band is not swamped by masked area.  Among equal areas the
+    squarer pair wins, as it wastes less of the causal diagonal, then the
+    wider KV side.
+    """
+    if kernel not in FLASH_KERNELS:
+        raise ValueError(f"flash_tiles: unknown kernel {kernel!r}; "
+                         f"expected one of {FLASH_KERNELS}")
+    if s_len <= 128:
+        return s_len, s_len
+    if s_len % 128:
+        raise ValueError(f"flash_tiles: S={s_len} is not a multiple of 128 "
+                         "(flash/ops.padded_seq_len pads it)")
+    cap = FLASH_TILE_CAP
+    if window > 0:
+        cap = min(cap, max(128, window // 128 * 128))
+    sides = [t for t in range(128, min(cap, s_len) + 1, 128) if s_len % t == 0]
+    fits = [(bq, bk) for bq in sides for bk in sides
+            if flash_vmem_bytes(bq, bk, d, kernel=kernel) <= V5E_SCOPED_VMEM]
+    return max(fits, key=lambda t: (t[0] * t[1], -abs(t[0] - t[1]), t[1]))
 
 
 # ---------------------------------------------------------------------------

@@ -191,21 +191,35 @@ def attn_resid_bytes(cfg, b: int, s: int, ctx: int,
     return (qo_kv + 2 * 4 * b * cfg.n_heads * s) // ms     # f32 m, l rows
 
 
-def _flash_tile_counts(cfg, s: int) -> "list[dict]":
-    """Per-layer visited/dense tile-step counts of the sparse flash grids.
+def _flash_tile_counts(cfg, s: int, tiles=None) -> "list[dict]":
+    """Per layer, each flash kernel's visited/dense tile-step counts.
 
     Computed on the PADDED grid the kernels actually run (ops.py rounds S
-    up to the 128-lane block and masks the tail via ``kv_len``), from the
-    same :func:`repro.kernels.flash.kernel.tile_step_counts` bounds the
-    kernels build their wedge grids from — planner budgets and measured
-    ``debug_counts`` counters agree tile-for-tile by construction.
+    up to the 128-lane block and masks the tail via ``kv_len``), at the
+    tiles each kernel runs (:func:`repro.kernels.tiling.flash_tiles`), or
+    at ``tiles`` (one (bq, bk) for every grid) when given, from the same
+    :func:`repro.kernels.tiling.tile_step_counts` bounds the kernels build
+    their wedge grids from — planner budgets and measured ``debug_counts``
+    counters agree tile-for-tile by construction.  Each layer maps a
+    kernel name to ``{"steps", "area", "dense", "bq", "bk"}``: ``area`` is
+    the visited positions per head, steps times bq * bk.
     """
-    from repro.kernels.flash import kernel as flash_kernel, ops as flash_ops
+    from repro.kernels import tiling
+    from repro.kernels.flash import ops as flash_ops
     from repro.models import transformer
     s_pad = flash_ops.padded_seq_len(s)
-    return [flash_kernel.tile_step_counts(s_pad, causal=True, window=w,
-                                          kv_len=s)
-            for w in (int(x) for x in transformer.layer_windows(cfg))]
+    layers = []
+    for w in (int(x) for x in transformer.layer_windows(cfg)):
+        per = {}
+        for kn in tiling.FLASH_KERNELS:
+            bq, bk = tiles or tiling.flash_tiles(s_pad, cfg.head_dim,
+                                                 window=w, kernel=kn)
+            c = tiling.tile_step_counts(s_pad, bq=bq, bk=bk, causal=True,
+                                        window=w, kv_len=s)
+            per[kn] = {"steps": c[kn], "area": c[kn] * c["bq"] * c["bk"],
+                       "dense": c["dense"], "bq": c["bq"], "bk": c["bk"]}
+        layers.append(per)
+    return layers
 
 
 def flash_bwd_recompute_flops(cfg, b: int, s: int) -> tuple[float, ...]:
@@ -215,27 +229,30 @@ def flash_bwd_recompute_flops(cfg, b: int, s: int) -> tuple[float, ...]:
     saved stats instead of loading a stored probability matrix — but only
     on the tiles their sparse grids actually visit: ``2 * BQ * BK * D``
     FLOPs per visited tile-step per (batch x head), summed over the dQ
-    and dKV grids (causal visits ~1/2 of the dense rectangle, window
-    ~W/S).  Zero when the flash kernel would not actually dispatch
-    (:func:`flash_training_eligible`) — e.g. ``attn_backend="jnp"``
-    (scores are stored, not recomputed) or non-attention layers.
+    and dKV grids at their own tiles (causal visits ~1/2 of the dense
+    rectangle at small tiles, window ~W/S).  Zero when the flash kernel
+    would not actually dispatch (:func:`flash_training_eligible`) — e.g.
+    ``attn_backend="jnp"`` (scores are stored, not recomputed) or
+    non-attention layers.
     """
     if not flash_training_eligible(cfg):
         return tuple(0.0 for _ in range(cfg.n_layers))
     bh = b * cfg.n_heads * cfg.head_dim
-    return tuple(2.0 * bh * c["bq"] * c["bk"] * (c["dq"] + c["dkv"])
+    return tuple(2.0 * bh * (c["dq"]["area"] + c["dkv"]["area"])
                  for c in _flash_tile_counts(cfg, s))
 
 
-def flash_attn_flop_report(cfg, b: int, s: int) -> dict:
+def flash_attn_flop_report(cfg, b: int, s: int, *, tiles=None) -> dict:
     """Dense-vs-visited attention FLOPs across the three sparse grids.
 
     Counts every matmul each grid runs per visited tile-step — forward
     (QK^T, PV: 4·BQ·BK·D flops), dQ (score recompute, dP, dS·K: 6), dKV
     (score recompute, P^T·dO, dP, dS^T·Q: 8) — against the same matmuls
-    on the dense nQ x nK rectangle a mask-blind grid executes.  This is
-    what dryrun train cells, the trainer banner and BENCH_flash.json
-    report as the sparse-grid FLOP claw-back.
+    on the dense nQ x nK rectangle a mask-blind grid executes, each grid
+    at the tiles it runs.  This is what dryrun train cells, the trainer
+    banner and BENCH_flash.json report as the sparse-grid FLOP claw-back.
+    ``tiles`` counts every grid at one (bq, bk) instead, e.g. (128, 128)
+    for the claw-back on fine grids.
     """
     if not flash_training_eligible(cfg):
         return {"eligible": False, "dense_flops": 0.0, "visited_flops": 0.0,
@@ -244,12 +261,13 @@ def flash_attn_flop_report(cfg, b: int, s: int) -> dict:
     bh = b * cfg.n_heads * cfg.head_dim
     dense = visited = 0.0
     vis_steps = dense_steps = 0
-    for c in _flash_tile_counts(cfg, s):
-        tile = bh * c["bq"] * c["bk"]
-        visited += tile * (4.0 * c["fwd"] + 6.0 * c["dq"] + 8.0 * c["dkv"])
-        dense += tile * (4.0 + 6.0 + 8.0) * c["dense"]
-        vis_steps += c["fwd"] + c["dq"] + c["dkv"]
-        dense_steps += 3 * c["dense"]
+    for c in _flash_tile_counts(cfg, s, tiles):
+        for kn, per_pos in (("fwd", 4.0), ("dq", 6.0), ("dkv", 8.0)):
+            k = c[kn]
+            visited += bh * per_pos * k["area"]
+            dense += bh * per_pos * k["dense"] * k["bq"] * k["bk"]
+            vis_steps += k["steps"]
+            dense_steps += k["dense"]
     return {"eligible": True, "dense_flops": dense, "visited_flops": visited,
             "skip_frac": 1.0 - (vis_steps / dense_steps if dense_steps
                                 else 0.0),
@@ -455,9 +473,8 @@ def profile_transformer(cfg, batch_sds, *, dtype_bytes: int = 2,
         attn_flops = 0.0
         if cfg.mixer in ("attn", "hybrid"):
             if flash:
-                c = tile_counts[i]
                 attn_flops = 4.0 * b * cfg.n_heads * cfg.head_dim \
-                    * c["bq"] * c["bk"] * c["fwd"]
+                    * tile_counts[i]["fwd"]["area"]
             else:
                 attn_flops = 4.0 * b * s * ctx * cfg.n_heads * cfg.head_dim
         flops.append(2.0 * b * s * per_block_params + attn_flops)

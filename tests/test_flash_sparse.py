@@ -11,6 +11,7 @@ import numpy as np
 import pytest
 
 from repro import configs
+from repro.kernels import tiling as T
 from repro.kernels.flash import kernel as K, ops as O, ref as R
 from repro.models import transformer
 
@@ -57,6 +58,12 @@ SWEEP = [
     (64, 64, 256, 256, 1, True),          # degenerate window = 1
     (128, 128, 2048, 2048, 256, True),
     (8, 8, 40, 40, 0, True),              # sub-block path (ops pads to 8)
+    # the tiles flash_tiles chooses: seq4k fwd/dQ and dKV, a windowed
+    # layer, a ragged padded bucket
+    (1024, 1024, 4096, 4096, 0, True),
+    (512, 1024, 4096, 4096, 0, True),
+    (512, 512, 2048, 2048, 600, True),
+    (640, 640, 2560, 2500, 0, True),
 ]
 
 
@@ -143,39 +150,66 @@ class TestMeasuredCounters:
     """interpret-mode debug counters vs the analytic counts, and the
     ISSUE 3 acceptance ratios."""
 
-    def _measure(self, s, *, window, causal, kv_len=None, h=2, hkv=1, d=64):
+    def _measure(self, s, *, window, causal, kv_len=None, h=2, hkv=1, d=64,
+                 bq=128, bk=128):
         kvl = s if kv_len is None else kv_len
         q, k, v = _flat(h, hkv, s, d)
         o, m, l, cnt = K.flash_attention_fwd_pallas(
-            q, k, v, causal=causal, window=window, kv_len=kvl,
+            q, k, v, causal=causal, window=window, kv_len=kvl, bq=bq, bk=bk,
             interpret=True, debug_counts=True)
         do = jnp.ones_like(o)
         _, _, _, dqc, dkvc = K.flash_attention_bwd_pallas(
             q, k, v, o, m, l, do, causal=causal, window=window, kv_len=kvl,
-            interpret=True, debug_counts=True)
+            bq=bq, bk=bk, interpret=True, debug_counts=True)
         group = h // hkv
         return {"fwd": int(cnt[0].sum()), "dq": int(dqc[0].sum()),
                 "dkv": int(dkvc[0].sum()) // group}
 
-    @pytest.mark.parametrize("s,window,causal,kv_len", [
-        (512, 0, True, None),
-        (512, 128, True, None),
-        (512, 100, True, 400),
-        (256, 0, False, 200),
-        (256, 64, True, None),
+    @pytest.mark.parametrize("s,window,causal,kv_len,bq,bk", [
+        pytest.param(512, 0, True, None, 128, 128, id="512-0-True-None"),
+        pytest.param(512, 128, True, None, 128, 128, id="512-128-True-None"),
+        pytest.param(512, 100, True, 400, 128, 128, id="512-100-True-400"),
+        pytest.param(256, 0, False, 200, 128, 128, id="256-0-False-200"),
+        pytest.param(256, 64, True, None, 128, 128, id="256-64-True-None"),
+        # tiles flash_tiles chooses: fwd/dQ at 4096, dKV at 4096,
+        # windowed, a ragged bucket (1200 pads to 1280 -> 640)
+        (4096, 0, True, None, 1024, 1024),
+        (4096, 0, True, None, 512, 1024),
+        (2048, 600, True, None, 512, 512),
+        (1280, 0, True, 1200, 640, 640),
+        (1280, 0, False, 1200, 640, 640),
     ])
-    def test_counters_match_analytic(self, s, window, causal, kv_len):
+    def test_counters_match_analytic(self, s, window, causal, kv_len, bq,
+                                     bk):
         kvl = s if kv_len is None else kv_len
-        meas = self._measure(s, window=window, causal=causal, kv_len=kv_len)
-        c = K.tile_step_counts(s, causal=causal, window=window, kv_len=kvl)
+        meas = self._measure(s, window=window, causal=causal, kv_len=kv_len,
+                             bq=bq, bk=bk)
+        c = K.tile_step_counts(s, bq=bq, bk=bk, causal=causal,
+                               window=window, kv_len=kvl)
         assert meas == {k_: c[k_] for k_ in ("fwd", "dq", "dkv")}
 
+    @pytest.mark.parametrize("s,window", [(4096, 0), (2048, 0), (2048, 512),
+                                          (1280, 0)])
+    def test_counters_match_analytic_at_chosen_tiles(self, s, window):
+        """Given no tiles, each of the fwd, dQ and dKV grids runs the
+        tiles flash_tiles chooses for it (dKV its own)."""
+        d = 64
+        meas = self._measure(s, window=window, causal=True, bq=None,
+                             bk=None, d=d)
+        want = {}
+        for kn in T.FLASH_KERNELS:
+            bq, bk = T.flash_tiles(s, d, window=window, kernel=kn)
+            want[kn] = K.tile_step_counts(s, bq=bq, bk=bk, causal=True,
+                                          window=window)[kn]
+        assert meas == want
+
     def test_causal_s2048_skips_at_least_45pct(self):
-        """Acceptance: causal S=2048 must skip >= 45% of KV tile-steps on
-        all three grids (the dense rectangle is 16x16=256; the wedge
-        visits the 136-step lower triangle)."""
-        meas = self._measure(2048, window=0, causal=True)
-        dense = K.tile_step_counts(2048, causal=True, window=0)["dense"]
+        """Acceptance: causal S=2048 on 128 x 128 tiles must skip >= 45%
+        of KV tile-steps on all three grids (the dense rectangle is
+        16x16=256; the wedge visits the 136-step lower triangle)."""
+        meas = self._measure(2048, window=0, causal=True, bq=128, bk=128)
+        dense = K.tile_step_counts(2048, bq=128, bk=128, causal=True,
+                                   window=0)["dense"]
         for grid in ("fwd", "dq", "dkv"):
             skipped = 1 - meas[grid] / dense
             assert skipped >= 0.45, (grid, skipped)
@@ -185,8 +219,8 @@ class TestMeasuredCounters:
         eps = (BQ + BK)/S covers tile-granularity overhang (a band of
         width W can straddle at most W/BK + 1 tiles per q tile)."""
         s, w = 2048, 256
-        meas = self._measure(s, window=w, causal=True)
-        c = K.tile_step_counts(s, causal=True, window=w)
+        meas = self._measure(s, window=w, causal=True, bq=128, bk=128)
+        c = K.tile_step_counts(s, bq=128, bk=128, causal=True, window=w)
         eps = (c["bq"] + c["bk"]) / s
         for grid in ("fwd", "dq", "dkv"):
             skipped = 1 - meas[grid] / c["dense"]
@@ -197,9 +231,112 @@ class TestMeasuredCounters:
         last tile): S=300 pads to 384, kv_len=300 masks the tail."""
         s_pad = O.padded_seq_len(300)
         assert s_pad == 384
-        meas = self._measure(s_pad, window=0, causal=True, kv_len=300)
-        c = K.tile_step_counts(s_pad, causal=True, window=0, kv_len=300)
+        meas = self._measure(s_pad, window=0, causal=True, kv_len=300,
+                             bq=128, bk=128)
+        c = K.tile_step_counts(s_pad, bq=128, bk=128, causal=True, window=0,
+                               kv_len=300)
         assert meas == {k_: c[k_] for k_ in ("fwd", "dq", "dkv")}
+
+
+class TestFlashTiles:
+    """The tiles each flash kernel runs when its caller names none."""
+
+    @pytest.mark.parametrize("s,d,kernel,want", [
+        # the seq4k cell: (4, 32, 4096, 128), 2 KV heads
+        (4096, 128, "fwd", (1024, 1024)),
+        (4096, 128, "dq", (1024, 1024)),
+        (4096, 128, "dkv", (512, 1024)),
+        # the chat cell's prefill buckets, 128...2048
+        (128, 128, "fwd", (128, 128)),
+        (256, 128, "fwd", (256, 256)),
+        (512, 128, "fwd", (512, 512)),
+        (1024, 128, "fwd", (1024, 1024)),
+        (2048, 128, "fwd", (1024, 1024)),
+        (2048, 128, "dkv", (512, 1024)),
+        # below one block: one tile over the 8-padded sequence
+        (40, 64, "fwd", (40, 40)),
+    ])
+    def test_choice_for_the_cells_shapes(self, s, d, kernel, want):
+        assert T.flash_tiles(s, d, kernel=kernel) == want
+
+    @pytest.mark.parametrize("s,want", [(384, (384, 384)),
+                                        (2560, (640, 640)),
+                                        (1408, (128, 128))])
+    def test_padded_lengths_divide(self, s, want):
+        """Tiles are multiples of 128 that divide S: 384 is one tile,
+        2560 = 4 x 640, and 1408 = 11 x 128 leaves only 128."""
+        assert T.flash_tiles(s, 128, kernel="fwd") == want
+
+    @pytest.mark.parametrize("window,want", [(1, (128, 128)),
+                                             (100, (128, 128)),
+                                             (256, (256, 256)),
+                                             (600, (512, 512)),
+                                             (4096, (1024, 1024))])
+    def test_window_caps_both_sides(self, window, want):
+        assert T.flash_tiles(4096, 128, window=window, kernel="fwd") == want
+
+    @pytest.mark.parametrize("kernel", T.FLASH_KERNELS)
+    @pytest.mark.parametrize("d", [32, 64, 128, 256])
+    def test_every_choice_fits_v5e_scoped_vmem(self, kernel, d):
+        for s in (128, 256, 384, 512, 1024, 1280, 2048, 2560, 4096, 16384):
+            for window in (0, 256, 1000):
+                bq, bk = T.flash_tiles(s, d, window=window, kernel=kernel)
+                assert s % bq == 0 and s % bk == 0, (s, bq, bk)
+                assert bq % 128 == 0 and bk % 128 == 0, (s, bq, bk)
+                assert max(bq, bk) <= T.FLASH_TILE_CAP
+                if window:
+                    assert max(bq, bk) <= max(128, window // 128 * 128)
+                assert T.flash_vmem_bytes(bq, bk, d, kernel=kernel) \
+                    <= T.V5E_SCOPED_VMEM, (s, window, bq, bk)
+
+    def test_rejects_what_no_kernel_runs(self):
+        with pytest.raises(ValueError, match="multiple of 128"):
+            T.flash_tiles(1000, 128)
+        with pytest.raises(ValueError, match="unknown kernel"):
+            T.flash_tiles(4096, 128, kernel="delta")
+
+
+class TestStoredOperandDtype:
+    """bf16-stored q, k, v and dO meet the MXU as stored, a mixed pair is
+    widened, and nothing the kernels compute in f32 (P, dS) is rounded to
+    bf16 on its way into a matmul: against the f32 oracle on the same
+    values, only the order of accumulation differs, far inside bf16's
+    rounding of P or dS (~4e-3)."""
+
+    @pytest.mark.parametrize("do_dtype", [jnp.bfloat16, jnp.float32])
+    @pytest.mark.parametrize("window", [0, 512])
+    def test_bf16_operands_match_f32_oracle(self, window, do_dtype):
+        """An f32 dO against bf16 q, k, v is the ``resid_bf16`` policy's
+        backward: f32 compute with bf16-saved residuals."""
+        h, s, d = 4, 1024, 64
+        q, k, v = (jnp.asarray(RNG.normal(size=(n, s, d)), jnp.bfloat16)
+                   for n in (h, 1, 1))
+        do = jnp.asarray(RNG.normal(size=(h, s, d)), do_dtype)
+        q32, k32, v32, do32 = (x.astype(jnp.float32) for x in (q, k, v, do))
+        kw = dict(causal=True, window=window, interpret=True, bq=512,
+                  bk=1024)
+
+        def oracle(q, k, v):
+            return R.flash_ref(q[None], k[None], v[None], causal=True,
+                               window=window)[0]
+
+        o_ref, vjp = jax.vjp(oracle, q32, k32, v32)
+
+        def rel(a, b_):
+            return float(jnp.abs(a - b_).max() / jnp.abs(b_).max())
+
+        # QK^T from the bf16 tiles as stored: the softmax stats
+        _, m, l = K.flash_attention_fwd_pallas(q, k, v, **kw)
+        _, m32, l32 = K.flash_attention_fwd_pallas(q32, k32, v32, **kw)
+        assert rel(m, m32) < 1e-6 and rel(l, l32) < 1e-6
+        # P.V with bf16 v: an f32 q makes the output f32, so it shows
+        o, m, l = K.flash_attention_fwd_pallas(q32, k, v, **kw)
+        assert rel(o, o_ref) < 1e-4
+        # dP = dO V^T from bf16 tiles; P^T dO, dS K and dS^T Q keep P, dS
+        grads = K.flash_attention_bwd_pallas(
+            q, k, v, o, m, l, do, grad_dtypes=("float32",) * 3, **kw)
+        for name, a, b_ in zip(("dq", "dk", "dv"), grads, vjp(do32)):
+            assert rel(a, b_) < 1e-4, name
 
 
 class TestSparseGridGradParity:
@@ -215,6 +352,11 @@ class TestSparseGridGradParity:
         (1, 4, 4, 512, 64, 128, True),    # statically shrunk window grid
         (1, 2, 2, 200, 64, 0, False),     # non-causal padded KV
         (1, 2, 1, 384, 64, 96, True),     # MQA, window not tile-aligned
+        # GQA 16:1 at the chosen, non-128 tiles: 1536 -> 768 x 768;
+        # window 512 -> 512 x 512; 1200 pads to 1280 -> 640 x 640 ragged
+        (1, 16, 1, 1536, 64, 0, True),
+        (1, 16, 1, 1536, 64, 512, True),
+        (1, 16, 1, 1200, 64, 0, True),
     ])
     def test_grads_match_ref(self, b, h, hkv, s, d, window, causal):
         q, k, v = _qkv(b, h, hkv, s, d)
@@ -237,74 +379,114 @@ class TestSparseGridGradParity:
 
 class TestPlannerHonesty:
     """profile/flash_bwd_recompute_flops budgets == the measured visited
-    tiles, within one tile per layer (ISSUE 3 acceptance)."""
+    positions, exactly, at the tiles the kernels run, on grids of several
+    steps, where a short budget shows."""
+
+    D = 64
 
     def _cfg(self, **kw):
         return dc.replace(configs.smoke_config("llama3-8b"),
                           attn_backend="interpret", **kw)
 
-    def test_profile_budget_matches_measured_tiles(self):
-        b, s, d = 1, 256, 64
-        cfg = self._cfg(head_dim=d)
+    def _fwd(self, s, window):
+        """Layer 0's forward, per head: (budgeted, measured) positions."""
+        b, d = 1, self.D
+        cfg = self._cfg(head_dim=d, window=window)
         h, hkv = cfg.n_heads, cfg.n_kv
-        prof_flops = {}
         from repro.plan import profile_transformer
         batch = {"tokens": jax.ShapeDtypeStruct((b, s), jnp.int32)}
         prof = profile_transformer(cfg, batch)
 
-        # measured: one layer's forward on the padded flash grid
-        q, k, v = _flat(b * h, hkv, O.padded_seq_len(s), d)
-        w = int(cfg.window)
+        # measured: one layer's forward on the padded flash grid, at the
+        # tiles the kernel chooses
+        s_pad = O.padded_seq_len(s)
+        q, k, v = _flat(b * h, hkv, s_pad, d)
         *_, cnt = K.flash_attention_fwd_pallas(
-            q, k, v, causal=True, window=w, kv_len=s, interpret=True,
+            q, k, v, causal=True, window=window, kv_len=s, interpret=True,
             debug_counts=True)
-        measured_tiles = int(cnt.sum()) // (b * h)
+        bq, bk = T.flash_tiles(s_pad, d, window=window, kernel="fwd")
+        measured = int(cnt.sum()) // (b * h) * bq * bk
 
-        # budgeted: back out the per-head tile count from the profile's
+        # budgeted: back out the per-head positions from the profile's
         # attention term (total layer flops - matmul term)
         params_sds = jax.eval_shape(
             lambda: transformer.init_params(cfg, jax.random.PRNGKey(0)))
         block_elems = sum(x.size for x in jax.tree_util.tree_leaves(
             params_sds["blocks"]))
         matmul = 2.0 * b * s * (block_elems / cfg.n_layers)
-        c = K.tile_step_counts(O.padded_seq_len(s), causal=True, window=w,
-                               kv_len=s)
-        per_tile = 4.0 * b * h * d * c["bq"] * c["bk"]
-        budget_tiles = (prof.flops[0] - matmul) / per_tile * (b * h) \
-            / (b * h)
-        assert abs(budget_tiles - measured_tiles) <= 1, \
-            (budget_tiles, measured_tiles)
+        return (prof.flops[0] - matmul) / (4.0 * b * h * d), measured
 
-    def test_bwd_budget_matches_measured_tiles(self):
-        b, s, d = 1, 256, 64
-        cfg = self._cfg(head_dim=d)
+    def _bwd(self, s, window):
+        """Layer 0's dQ + dKV recompute, per head: (budgeted, measured)
+        positions and each grid's visited steps."""
+        b, d = 1, self.D
+        cfg = self._cfg(head_dim=d, window=window)
         h, hkv = cfg.n_heads, cfg.n_kv
         from repro.plan import flash_bwd_recompute_flops
         per_layer = flash_bwd_recompute_flops(cfg, b, s)
 
         s_pad = O.padded_seq_len(s)
         q, k, v = _flat(b * h, hkv, s_pad, d)
-        w = int(cfg.window)
-        o, m, l, _ = K.flash_attention_fwd_pallas(
-            q, k, v, causal=True, window=w, kv_len=s, interpret=True,
-            debug_counts=True)
+        o, m, l = K.flash_attention_fwd_pallas(
+            q, k, v, causal=True, window=window, kv_len=s, interpret=True)
         *_, dqc, dkvc = K.flash_attention_bwd_pallas(
-            q, k, v, o, m, l, jnp.ones_like(o), causal=True, window=w,
+            q, k, v, o, m, l, jnp.ones_like(o), causal=True, window=window,
             kv_len=s, interpret=True, debug_counts=True)
         group = h // hkv
-        measured = int(dqc.sum()) // (b * h) + int(dkvc.sum()) // (b * group
-                                                                   * hkv)
-        c = K.tile_step_counts(s_pad, causal=True, window=w, kv_len=s)
-        per_tile = 2.0 * b * h * d * c["bq"] * c["bk"]
-        assert abs(per_layer[0] / per_tile - measured) <= 1
+        steps = {"dq": int(dqc.sum()) // (b * h),
+                 "dkv": int(dkvc.sum()) // (b * group * hkv)}
+        measured = 0
+        for kn, n in steps.items():
+            bq, bk = T.flash_tiles(s_pad, d, window=window, kernel=kn)
+            measured += n * bq * bk
+        return per_layer[0] / (2.0 * b * h * d), measured, steps
+
+    def test_profile_budget_matches_measured_tiles(self):
+        # S=2000 pads to 2048: 1024^2 tiles, 3 of the 4 steps visited
+        budget, measured = self._fwd(2000, 0)
+        assert measured == 3 * 1024 * 1024
+        assert abs(budget - measured) < 0.5, (budget, measured)
+
+    def test_bwd_budget_matches_measured_tiles(self):
+        # dQ on 1024^2 tiles (3 of 4 steps), dKV on 512 x 1024 (6 of 8)
+        budget, measured, steps = self._bwd(2000, 0)
+        assert steps == {"dq": 3, "dkv": 6}
+        assert abs(budget - measured) < 0.5, (budget, measured)
+
+    def test_windowed_budgets_match_measured_tiles(self):
+        # window 256, S=1000 padded to 1024: every grid on 256^2 tiles,
+        # 7 of the 16 steps visited
+        budget, measured = self._fwd(1000, 256)
+        assert measured == 7 * 256 * 256
+        assert abs(budget - measured) < 0.5, (budget, measured)
+        budget, measured, steps = self._bwd(1000, 256)
+        assert steps == {"dq": 7, "dkv": 7}
+        assert abs(budget - measured) < 0.5, (budget, measured)
 
     def test_flop_report_claws_back_causal(self):
         from repro.plan import flash_attn_flop_report
         cfg = self._cfg(head_dim=64)
-        rep = flash_attn_flop_report(cfg, 1, 2048)
+        # on 128 x 128 grids the sparse grids claw back over 40%
+        rep = flash_attn_flop_report(cfg, 1, 2048, tiles=(128, 128))
         assert rep["eligible"]
         assert rep["visited_flops"] < 0.6 * rep["dense_flops"]
         assert 0.45 <= rep["skip_frac"] < 1.0
+        # by default the report counts each grid at the tiles it runs
+        rep = flash_attn_flop_report(cfg, 1, 2048)
+        visited = dense = 0.0
+        steps = {"visited": 0, "dense": 0}
+        for kn, per_pos in (("fwd", 4.0), ("dq", 6.0), ("dkv", 8.0)):
+            bq, bk = T.flash_tiles(2048, 64, kernel=kn)
+            c = K.tile_step_counts(2048, bq=bq, bk=bk, causal=True)
+            visited += per_pos * c[kn] * bq * bk
+            dense += per_pos * c["dense"] * bq * bk
+            steps["visited"] += c[kn]
+            steps["dense"] += c["dense"]
+        bhd = cfg.n_heads * cfg.head_dim * cfg.n_layers
+        assert rep["visited_flops"] == pytest.approx(bhd * visited)
+        assert rep["dense_flops"] == pytest.approx(bhd * dense)
+        assert rep["skip_frac"] == 1 - steps["visited"] / steps["dense"]
+        assert rep["visited_flops"] < rep["dense_flops"]
         # ineligible config reports zeros, not a phantom claw-back
         rep_jnp = flash_attn_flop_report(dc.replace(cfg, attn_backend="jnp"),
                                          1, 2048)

@@ -197,19 +197,30 @@ class TestBackendAwareResiduals:
         import dataclasses
         from repro import configs
         from repro.kernels.flash.kernel import tile_step_counts
+        from repro.kernels.tiling import flash_tiles
         from repro.plan import flash_bwd_recompute_flops
         cfg = dataclasses.replace(configs.smoke_config("llama3-8b"),
                                   attn_backend="pallas", head_dim=64)
         per_layer = flash_bwd_recompute_flops(cfg, 2, 512)
         assert len(per_layer) == cfg.n_layers
         # dQ and dKV each recompute scores, but only on the tiles their
-        # sparse grids visit — NOT the dense (S x S) rectangle
-        c = tile_step_counts(512, causal=True, window=0)
-        expect = 2.0 * 2 * cfg.n_heads * cfg.head_dim * c["bq"] * c["bk"] \
-            * (c["dq"] + c["dkv"])
+        # sparse grids visit, at the tiles each kernel runs
+        per_pos = 2.0 * 2 * cfg.n_heads * cfg.head_dim
+        expect = 0.0
+        for kn in ("dq", "dkv"):
+            bq, bk = flash_tiles(512, cfg.head_dim, kernel=kn)
+            c = tile_step_counts(512, bq=bq, bk=bk, causal=True, window=0)
+            expect += per_pos * bq * bk * c[kn]
         assert per_layer[0] == expect
-        dense = 4.0 * 2 * 512 * 512 * cfg.n_heads * cfg.head_dim
-        assert per_layer[0] < 0.7 * dense     # causal claws back ~2x
+
+        def dense(s):
+            return 4.0 * 2 * s * s * cfg.n_heads * cfg.head_dim
+        # on 128 tiles the sparse grids visit NOT the dense (S x S)
+        # rectangle: causal claws back ~2x
+        c = tile_step_counts(512, bq=128, bk=128, causal=True, window=0)
+        assert per_pos * 128 * 128 * (c["dq"] + c["dkv"]) < 0.7 * dense(512)
+        # at 512 the chosen tiles are one tile; at 4096 they claw back too
+        assert flash_bwd_recompute_flops(cfg, 2, 4096)[0] < 0.7 * dense(4096)
         cfg_jnp = dataclasses.replace(cfg, attn_backend="jnp")
         assert sum(flash_bwd_recompute_flops(cfg_jnp, 2, 512)) == 0.0
 

@@ -115,6 +115,51 @@ def test_flash_fwd_bwd_compiles(one_chip, window):
     assert _named_kernels(text) == set(flash_kernel.KERNEL_NAMES.values())
 
 
+@pytest.mark.parametrize("b,s", [(1, 2048), (4, 4096)])
+def test_flash_cells_compile_at_chosen_tiles(one_chip, b, s):
+    """GLM-4-9B attention (32 query heads on 2 KV heads of 128) at the
+    chat cell's longest prefill and the seq4k training step, forward and
+    backward, at the tiles ``tiling.flash_tiles`` picks (up to 1024 a
+    side): a Mosaic layout or VMEM refusal of those tiles shows here."""
+    def loss(q, k, v):
+        o = flash_ops.flash_attention(q, k, v, backend="pallas")
+        return jnp.sum(o.astype(jnp.float32) ** 2)
+
+    kv = ((b, 2, s, D), jnp.bfloat16)
+    text = _compile(jax.grad(loss, argnums=(0, 1, 2)), one_chip,
+                    ((b, 32, s, D), jnp.bfloat16), kv, kv)
+    assert _named_kernels(text) == set(flash_kernel.KERNEL_NAMES.values())
+
+
+def test_flash_resid_bf16_backward_compiles(one_chip):
+    """The ``resid_bf16`` policy at the seq4k cell's length: f32 compute
+    with q, k, v and o saved in bf16, so the backward kernels meet an f32
+    dO against bf16 tiles (widened to f32 for dO·vᵀ)."""
+    def loss(q, k, v):
+        o = flash_ops.flash_attention(q, k, v, backend="pallas",
+                                      resid_dtype="bfloat16")
+        return jnp.sum(o ** 2)
+
+    kv = ((1, 2, 4096, D), jnp.float32)
+    text = _compile(jax.grad(loss, argnums=(0, 1, 2)), one_chip,
+                    ((1, 32, 4096, D), jnp.float32), kv, kv)
+    assert _named_kernels(text) == set(flash_kernel.KERNEL_NAMES.values())
+
+
+@pytest.mark.parametrize("bq", [128, None])
+def test_flash_fp16_is_refused(one_chip, bq):
+    """Mosaic on a v5e refuses to load f16 tiles, on 128 x 128 tiles as
+    on the chosen ones, so the ``fp16`` policy cannot take the compiled
+    flash path on this chip; this pins that down."""
+    def fwd(q, k, v):
+        return flash_kernel.flash_attention_fwd_pallas(q, k, v, bq=bq,
+                                                      bk=bq)[0]
+
+    kv = ((2, 2048, D), jnp.float16)
+    with pytest.raises(Exception, match="Mosaic failed to compile"):
+        _compile(fwd, one_chip, ((32, 2048, D), jnp.float16), kv, kv)
+
+
 @pytest.mark.parametrize("s,d", [(256, 32), (40, 64)])
 def test_flash_small_shapes_compile(one_chip, s, d):
     """A head_dim under the 128-lane tile and a sequence under one block
