@@ -15,7 +15,7 @@ from repro.models.config import (EncoderConfig, MLAConfig, ModelConfig,
 ARCHS = [
     "deepseek_moe_16b", "granite_moe_3b_a800m", "stablelm_12b",
     "minicpm3_4b", "glm4_9b", "llama3_8b", "whisper_base", "hymba_1_5b",
-    "qwen2_vl_2b", "mamba2_130m",
+    "qwen2_vl_2b", "mamba2_130m", "glm47_flash",
 ]
 
 # canonical ids use dashes (CLI); module names use underscores
@@ -47,7 +47,8 @@ def smoke_config(arch_id: str) -> ModelConfig:
         kw["moe"] = dataclasses.replace(
             cfg.moe, num_experts=8, top_k=2, d_expert=32,
             d_shared=64 if cfg.moe.num_shared else 0)
-        kw["d_ff"] = 0
+        kw["d_ff"] = 128 if cfg.dense_layers else 0
+        kw["dense_layers"] = min(cfg.dense_layers, 1)
     if cfg.mla is not None:
         kw["mla"] = MLAConfig(q_lora_rank=32, kv_lora_rank=16, qk_nope_dim=8,
                               qk_rope_dim=8, v_head_dim=8)

@@ -231,8 +231,10 @@ def cache_specs(cfg: ModelConfig, cache_shape, mesh: Mesh) -> Any:
             if s % n_seq:
                 seq_ax = None
             return P(None, b_ax, None, seq_ax)
-        if name in ("mla_lat", "mla_rope"):          # (L, B, S, r)
+        if name in ("mla_lat", "mla_rope"):   # (L, B, S, r), (L, B, r, S)
             seq_ax = ("data", "model") if b_ax is None else "model"
+            if name == "mla_rope":
+                return P(None, b_ax, None, seq_ax)
             return P(None, b_ax, seq_ax, None)
         if name in ("ssm", "conv"):                  # small states: DP only
             return P(None, b_ax)

@@ -175,79 +175,140 @@ def cross_attn_block(p, x, enc_kv, cfg):
 # MLA (MiniCPM3 / DeepSeek-V2 style multi-head latent attention).
 # ---------------------------------------------------------------------------
 def mla_block(p, x, cfg, *, positions):
-    """Latent-compressed attention; returns (out, (kv_latent, k_rope))."""
+    """Latent-compressed attention; returns (out, (kv_latent, k_rope)).
+
+    Prefill and training: the latent is expanded to per-head keys
+    (``qk_nope + qk_rope`` wide, the rotary part shared by every head) and
+    values, and attention runs as multi-head attention through the
+    platform's path (``cfg.attn_impl()``): the flash kernel, or jnp."""
     m = cfg.mla
     b, s, _ = x.shape
     h = cfg.n_heads
     dn, dr, dv = m.qk_nope_dim, m.qk_rope_dim, m.v_head_dim
 
-    q_lat = rms_norm(x @ p["q_a"], p["q_a_norm"], cfg.norm_eps, bf16_grad=cfg.norm_bf16_grad)
-    q = (q_lat @ p["q_b"]).reshape(b, s, h, dn + dr)
-    q_nope, q_rope = q[..., :dn], q[..., dn:]
+    with jax.named_scope("mla_prefill"):
+        q_lat = rms_norm(x @ p["q_a"], p["q_a_norm"], cfg.norm_eps,
+                         bf16_grad=cfg.norm_bf16_grad)
+        q = (q_lat @ p["q_b"]).reshape(b, s, h, dn + dr)
+        q_nope, q_rope = q[..., :dn], q[..., dn:]
 
-    kv_all = x @ p["kv_a"]                               # (B,S,kv_lora+dr)
-    kv_lat = rms_norm(kv_all[..., : m.kv_lora_rank], p["kv_a_norm"], cfg.norm_eps, bf16_grad=cfg.norm_bf16_grad)
-    k_rope = kv_all[..., m.kv_lora_rank:].reshape(b, s, 1, dr)
+        kv_all = x @ p["kv_a"]                           # (B,S,kv_lora+dr)
+        kv_lat = rms_norm(kv_all[..., : m.kv_lora_rank], p["kv_a_norm"],
+                          cfg.norm_eps, bf16_grad=cfg.norm_bf16_grad)
+        k_rope = kv_all[..., m.kv_lora_rank:].reshape(b, s, 1, dr)
 
-    kv = (kv_lat @ p["kv_b"]).reshape(b, s, h, dn + dv)
-    k_nope, v = kv[..., :dn], kv[..., dn:]
+        kv = (kv_lat @ p["kv_b"]).reshape(b, s, h, dn + dv)
+        k_nope, v = kv[..., :dn], kv[..., dn:]
 
-    q_rope = apply_rope(q_rope, positions, cfg.rope_theta)
-    k_rope = apply_rope(k_rope, positions, cfg.rope_theta)
-    qf = jnp.concatenate([q_nope, q_rope], -1)
-    kf = jnp.concatenate([k_nope, jnp.broadcast_to(k_rope, (b, s, h, dr))], -1)
-    out = gqa_attention(qf, kf, v, q_pos=positions, k_pos=positions,
-                        sm_scale=(dn + dr) ** -0.5)
-    out = out.reshape(b, s, h * dv)
+        q_rope = apply_rope(q_rope, positions, cfg.rope_theta)
+        k_rope = apply_rope(k_rope, positions, cfg.rope_theta)
+        qf = jnp.concatenate([q_nope, q_rope], -1)
+        kf = jnp.concatenate([k_nope, jnp.broadcast_to(k_rope,
+                                                       (b, s, h, dr))], -1)
+        sm_scale = (dn + dr) ** -0.5
+        impl = cfg.attn_impl()
+        if impl != "jnp" and dn + dr == dv:
+            from repro.kernels.flash import ops as flash_ops
+            out = flash_ops.flash_attention(
+                jnp.swapaxes(qf, 1, 2), jnp.swapaxes(kf, 1, 2),
+                jnp.swapaxes(v, 1, 2), causal=True, sm_scale=sm_scale,
+                backend=impl)
+            out = jnp.swapaxes(out, 1, 2)
+        else:
+            out = gqa_attention(qf, kf, v, q_pos=positions, k_pos=positions,
+                                sm_scale=sm_scale)
+        out = out.reshape(b, s, h * dv)
     return out @ p["wo"], (kv_lat, k_rope)
 
 
+def _mla_decode_inputs(p, x_t, cfg, pos_arr):
+    """One token's absorbed decode inputs: (q_abs (B, H, kv_lora) f32,
+    q_rope (B, H, dr), the token's latent (B, kv_lora) and rotated rope
+    key (B, dr)) at positions ``pos_arr`` (B, 1)."""
+    m = cfg.mla
+    b = x_t.shape[0]
+    h = cfg.n_heads
+    dn, dr, dv = m.qk_nope_dim, m.qk_rope_dim, m.v_head_dim
+    q_lat = rms_norm(x_t @ p["q_a"], p["q_a_norm"], cfg.norm_eps,
+                     bf16_grad=cfg.norm_bf16_grad)
+    q = (q_lat @ p["q_b"]).reshape(b, h, dn + dr)
+    q_nope, q_rope = q[..., :dn], q[..., dn:]
+    q_rope = apply_rope(q_rope[:, None], pos_arr, cfg.rope_theta)[:, 0]
+
+    kv_all = x_t @ p["kv_a"]
+    lat_new = rms_norm(kv_all[..., : m.kv_lora_rank], p["kv_a_norm"],
+                       cfg.norm_eps, bf16_grad=cfg.norm_bf16_grad)
+    kr_new = apply_rope(kv_all[..., m.kv_lora_rank:][:, None, None],
+                        pos_arr, cfg.rope_theta)[:, 0, 0]
+    wk_b = p["kv_b"].reshape(m.kv_lora_rank, h, dn + dv)[..., :dn]
+    q_abs = jnp.einsum("bhd,lhd->bhl", q_nope.astype(jnp.float32),
+                       wk_b.astype(jnp.float32))
+    return q_abs, q_rope, lat_new, kr_new
+
+
+def _mla_decode_output(p, x_t, cfg, o_lat):
+    """Latent attention output (B, H, kv_lora) -> (B, D_model): the value
+    up-projection, then the output projection."""
+    m = cfg.mla
+    h = cfg.n_heads
+    wv_b = p["kv_b"].reshape(m.kv_lora_rank, h,
+                             m.qk_nope_dim + m.v_head_dim)[..., m.qk_nope_dim:]
+    out = jnp.einsum("bhl,lhd->bhd", o_lat, wv_b.astype(jnp.float32))
+    out = out.reshape(x_t.shape[0], h * m.v_head_dim).astype(x_t.dtype)
+    return out @ p["wo"]
+
+
 def mla_decode(p, x_t, cfg, cache_lat, cache_rope, pos):
-    """One-token MLA decode with weight absorption.
+    """One-token MLA decode with weight absorption (lockstep batch).
 
     The latent cache stores only (kv_lora + rope_dim) floats/token — MLA's
     whole point.  Scores and outputs are computed in latent space:
       score = (q_nope @ Wk_b) . kv_lat + q_rope . k_rope
       out   = (softmax . kv_lat) @ Wv_b
-    cache_lat: (B, S, kv_lora); cache_rope: (B, S, dr); pos scalar.
+    cache_lat: (B, S, kv_lora); cache_rope: (B, dr, S); pos scalar.
     """
+    from repro.kernels.mla import ref as mla_ref
     m = cfg.mla
     b = x_t.shape[0]
-    h = cfg.n_heads
-    dn, dr, dv = m.qk_nope_dim, m.qk_rope_dim, m.v_head_dim
-    s_max = cache_lat.shape[1]
-
-    q_lat = rms_norm(x_t @ p["q_a"], p["q_a_norm"], cfg.norm_eps, bf16_grad=cfg.norm_bf16_grad)
-    q = (q_lat @ p["q_b"]).reshape(b, h, dn + dr)
-    q_nope, q_rope = q[..., :dn], q[..., dn:]
     pos_arr = jnp.full((b, 1), pos, jnp.int32)
-    q_rope = apply_rope(q_rope[:, None], pos_arr, cfg.rope_theta)[:, 0]
-
-    kv_all = x_t @ p["kv_a"]
-    lat_new = rms_norm(kv_all[..., : m.kv_lora_rank], p["kv_a_norm"], cfg.norm_eps, bf16_grad=cfg.norm_bf16_grad)
-    kr_new = apply_rope(kv_all[..., m.kv_lora_rank:][:, None, None],
-                        pos_arr, cfg.rope_theta)[:, 0, 0]
-
+    q_abs, q_rope, lat_new, kr_new = _mla_decode_inputs(p, x_t, cfg, pos_arr)
     cl = jax.lax.dynamic_update_slice(
         cache_lat, lat_new[:, None].astype(cache_lat.dtype), (0, pos, 0))
     cr = jax.lax.dynamic_update_slice(
-        cache_rope, kr_new[:, None].astype(cache_rope.dtype), (0, pos, 0))
+        cache_rope, kr_new[:, :, None].astype(cache_rope.dtype), (0, 0, pos))
+    o_lat = mla_ref.mla_decode_ref(
+        q_abs, q_rope, cl[None], cr[None], jnp.broadcast_to(pos + 1, (b,)),
+        0, sm_scale=(m.qk_nope_dim + m.qk_rope_dim) ** -0.5)
+    return _mla_decode_output(p, x_t, cfg, o_lat), (cl, cr)
 
-    kv_b = p["kv_b"].reshape(m.kv_lora_rank, h, dn + dv)
-    wk_b, wv_b = kv_b[..., :dn], kv_b[..., dn:]
-    q_abs = jnp.einsum("bhd,lhd->bhl", q_nope.astype(jnp.float32),
-                       wk_b.astype(jnp.float32))
-    scores = jnp.einsum("bhl,bsl->bhs", q_abs, cl.astype(jnp.float32))
-    scores += jnp.einsum("bhd,bsd->bhs", q_rope.astype(jnp.float32),
-                         cr.astype(jnp.float32))
-    scores = scores * (dn + dr) ** -0.5
-    valid = jnp.arange(s_max)[None, :] <= pos
-    scores = jnp.where(valid[:, None], scores, NEG_INF)
-    pr = jax.nn.softmax(scores, -1)
-    o_lat = jnp.einsum("bhs,bsl->bhl", pr, cl.astype(jnp.float32))
-    out = jnp.einsum("bhl,lhd->bhd", o_lat, wv_b.astype(jnp.float32))
-    out = out.reshape(b, h * dv).astype(x_t.dtype)
-    return out @ p["wo"], (cl, cr)
+
+def mla_decode_slots(p, x_t, cfg, lat, rope, layer, pos, lengths, *,
+                     backend: str = "ref"):
+    """One-token MLA decode of a slot pool, each row at its own position.
+
+    ``lat`` (L, B, S, kv_lora) and ``rope`` (L, B, dr, S) are the whole
+    stacked cache: the row's new latent and rope key are written at
+    ``(layer, b, pos[b])`` in place, and the attention reads ``layer`` of
+    the cache where it lies (``kernels.mla``: the ``mla_decode_pallas``
+    kernel, or its jnp ``ref``), over each row's first ``lengths[b]``
+    positions.  Returns (out (B, D_model), lat, rope)."""
+    m = cfg.mla
+    b = x_t.shape[0]
+    with jax.named_scope("mla_decode"):
+        q_abs, q_rope, lat_new, kr_new = _mla_decode_inputs(
+            p, x_t, cfg, pos[:, None])
+        rows = jnp.arange(b)
+        lat = lat.at[layer, rows, pos].set(lat_new.astype(lat.dtype),
+                                           mode="drop")
+        rope = rope.at[layer, rows, :, pos].set(kr_new.astype(rope.dtype),
+                                                mode="drop")
+        from repro.kernels.mla import ops as mla_ops
+        o_lat = mla_ops.mla_decode_attention(
+            q_abs, q_rope, lat, rope, lengths, layer,
+            sm_scale=(m.qk_nope_dim + m.qk_rope_dim) ** -0.5,
+            backend=backend)
+        out = _mla_decode_output(p, x_t, cfg, o_lat)
+    return out, lat, rope
 
 
 # ---------------------------------------------------------------------------

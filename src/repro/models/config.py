@@ -15,6 +15,36 @@ class MoEConfig:
     router_dtype: str = "float32"
     expert_mode: str = "tp"       # 'tp' (shard d_expert) | 'ep' (shard experts)
     capacity_factor: float = 1.25  # 0 => dropless (sort + ragged_dot)
+    # 'softmax' | 'sigmoid' (DeepSeek-V3 / GLM-4.5 ``noaux_tc``: sigmoid
+    # scores, a per-expert bias that only chooses the top k, the chosen
+    # raw scores normalised)
+    scoring: str = "softmax"
+    routed_scale: float = 1.0     # routed_scaling_factor on the routed sum
+    # (first, count) of the routed experts this device holds; None => all.
+    # Routing still spans num_experts; absent experts contribute nothing.
+    held: Optional[Tuple[int, int]] = None
+
+    SCORINGS = ("softmax", "sigmoid")
+
+    def __post_init__(self):
+        if isinstance(self.held, list):           # from a JSON file
+            object.__setattr__(self, "held", tuple(self.held))
+        if self.scoring not in self.SCORINGS:
+            raise ValueError(f"MoEConfig.scoring={self.scoring!r} not in "
+                             f"{self.SCORINGS}")
+        if self.held is not None:
+            first, count = self.held
+            if not (0 <= first and count >= 1
+                    and first + count <= self.num_experts):
+                raise ValueError(f"MoEConfig.held={self.held} is not a range "
+                                 f"of the {self.num_experts} experts")
+            if self.capacity_factor > 0:
+                raise ValueError("MoEConfig.held needs the dropless "
+                                 "dispatch (capacity_factor=0)")
+
+    @property
+    def n_held(self) -> int:
+        return self.num_experts if self.held is None else self.held[1]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -55,7 +85,7 @@ class ModelConfig:
     d_model: int
     n_heads: int
     n_kv: int
-    d_ff: int
+    d_ff: int                     # MLP width (MoE archs: of the dense layers)
     vocab: int
     head_dim: int = 0             # 0 => d_model // n_heads
     mixer: str = "attn"           # attn | ssm | hybrid
@@ -67,6 +97,9 @@ class ModelConfig:
     window: int = 0               # 0 => full causal; else sliding window
     global_layers: Tuple[int, ...] = ()   # layers that override window -> full
     moe: Optional[MoEConfig] = None
+    # leading layers with a dense d_ff MLP before the MoE stack
+    # (``first_k_dense_replace``); the first ``dense_layers`` of n_layers
+    dense_layers: int = 0
     mla: Optional[MLAConfig] = None
     ssm: Optional[SSMConfig] = None
     encoder: Optional[EncoderConfig] = None
@@ -81,8 +114,18 @@ class ModelConfig:
     ATTN_BACKENDS = ("auto", "jnp", "interpret", "pallas")
 
     def __post_init__(self):
+        # nested configs may be given as dicts (a JSON file's overrides)
+        for name, kind in (("moe", MoEConfig), ("mla", MLAConfig)):
+            if isinstance(getattr(self, name), dict):
+                object.__setattr__(self, name, kind(**getattr(self, name)))
         if self.head_dim == 0 and self.n_heads:
             object.__setattr__(self, "head_dim", self.d_model // self.n_heads)
+        if self.dense_layers and (self.moe is None or not self.d_ff
+                                  or self.dense_layers >= self.n_layers
+                                  or self.global_layers):
+            raise ValueError("dense_layers needs a MoE arch with a dense "
+                             "d_ff, a uniform window and at least one MoE "
+                             "layer after them")
         if self.attn_backend not in self.ATTN_BACKENDS:
             raise ValueError(
                 f"attn_backend={self.attn_backend!r} not in "
@@ -138,14 +181,18 @@ class ModelConfig:
             per_layer += s.d_inner * d                                   # out_proj
         # FFN
         per_layer += d                             # ln2
+        mult = 3 if self.mlp_kind == "swiglu" else 2
         if self.moe is not None:
             m = self.moe
+            total += self.dense_layers * (per_layer + mult * d * self.d_ff)
+            l -= self.dense_layers
             per_layer += d * m.num_experts                               # router
-            per_layer += m.num_experts * 3 * d * m.d_expert              # experts
+            if m.scoring == "sigmoid":
+                per_layer += m.num_experts                               # bias
+            per_layer += m.n_held * 3 * d * m.d_expert                   # experts
             if m.num_shared:
                 per_layer += 3 * d * m.d_shared                          # shared
         elif self.d_ff:
-            mult = 3 if self.mlp_kind == "swiglu" else 2
             per_layer += mult * d * self.d_ff
         total += l * per_layer
         if self.encoder is not None:
@@ -163,6 +210,7 @@ class ModelConfig:
         if self.moe is None:
             return self.param_count()
         m = self.moe
-        dense_experts = self.n_layers * m.num_experts * 3 * self.d_model * m.d_expert
-        active_experts = self.n_layers * m.top_k * 3 * self.d_model * m.d_expert
+        moe_layers = self.n_layers - self.dense_layers
+        dense_experts = moe_layers * m.n_held * 3 * self.d_model * m.d_expert
+        active_experts = moe_layers * m.top_k * 3 * self.d_model * m.d_expert
         return self.param_count() - dense_experts + active_experts

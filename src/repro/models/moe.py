@@ -3,7 +3,20 @@
 Baseline sharding is TP-experts (expert hidden dim sharded over the model
 axis; every device holds a slice of every expert).  ``expert_mode='ep'``
 switches to expert parallelism via shard_map + all_to_all — a perf-iteration
-path (see EXPERIMENTS.md §Perf).
+path.
+
+Routers: ``softmax`` (top-k of the softmax, renormalised, with a
+Switch-style load-balance loss) and ``sigmoid`` (DeepSeek-V3 / GLM-4.5
+``noaux_tc`` with one group: sigmoid scores, a learned per-expert
+correction bias added only to choose the top k, the chosen raw scores
+normalised and scaled by ``routed_scale``; no auxiliary loss).
+
+A layer told which experts it holds (``MoEConfig.held``, a chip's share
+under expert parallelism) routes every token over all ``num_experts`` and
+computes only its own experts' part: rows routed to absent experts sort
+last, outside every ragged group, and add nothing.  The shared expert is
+added once.  Scopes ``moe/{router,dispatch,experts,shared}`` name the
+phases in compiled programs and device traces.
 """
 from __future__ import annotations
 
@@ -13,9 +26,16 @@ import jax.numpy as jnp
 from repro.models.layers import swiglu
 
 
-def router_topk(x, w_router, k: int):
+def router_topk(x, w_router, k: int, *, scoring: str = "softmax",
+                bias=None, scale: float = 1.0):
     """Returns (weights (T,k) f32, idx (T,k) i32, aux load-balance loss)."""
     logits = x.astype(jnp.float32) @ w_router.astype(jnp.float32)   # (T, E)
+    if scoring == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+        _, top_i = jax.lax.top_k(scores + bias.astype(jnp.float32), k)
+        top_s = jnp.take_along_axis(scores, top_i, axis=-1)
+        weights = top_s / (top_s.sum(-1, keepdims=True) + 1e-20) * scale
+        return weights, top_i, jnp.float32(0.0)
     probs = jax.nn.softmax(logits, axis=-1)
     top_p, top_i = jax.lax.top_k(probs, k)
     weights = top_p / jnp.maximum(top_p.sum(-1, keepdims=True), 1e-9)
@@ -42,7 +62,9 @@ def _moe_capacity_local(p, x, cfg, expert_offset=None):
     b, s, d = x.shape
     t = b * s
     xf = x.reshape(t, d)
-    weights, top_i, aux = router_topk(xf, p["router"], m.top_k)
+    weights, top_i, aux = router_topk(
+        xf, p["router"], m.top_k, scoring=m.scoring,
+        bias=p.get("router_bias"), scale=m.routed_scale)
 
     tk = t * m.top_k
     cap = max(8, int(tk / m.num_experts * m.capacity_factor) // 8 * 8)
@@ -85,6 +107,8 @@ def _moe_local(p, x, cfg):
     run grouped matmuls with lax.ragged_dot, un-sort, combine with router
     weights.  Shared experts (DeepSeek) run densely on the side.
     The expert FFN hidden shard may be a TP shard; the caller psums.
+    With ``held`` experts, the rows of absent experts sort past the last
+    group: ragged_dot leaves them out and their output is zeroed.
     """
     m = cfg.moe
     if m.capacity_factor > 0:
@@ -92,25 +116,41 @@ def _moe_local(p, x, cfg):
     b, s, d = x.shape
     t = b * s
     xf = x.reshape(t, d)
-    weights, top_i, aux = router_topk(xf, p["router"], m.top_k)
+    with jax.named_scope("router"):
+        weights, top_i, aux = router_topk(
+            xf, p["router"], m.top_k, scoring=m.scoring,
+            bias=p.get("router_bias"), scale=m.routed_scale)
 
-    flat_expert = top_i.reshape(-1)                         # (T*k,)
-    flat_token = jnp.repeat(jnp.arange(t), m.top_k)
-    order = jnp.argsort(flat_expert)
-    tok_sorted = flat_token[order]
-    exp_sorted = flat_expert[order]
-    group_sizes = jnp.zeros((m.num_experts,), jnp.int32).at[exp_sorted].add(1)
+    with jax.named_scope("dispatch"):
+        flat_expert = top_i.reshape(-1)                     # (T*k,)
+        if m.held is not None:
+            first, count = m.held
+            local = flat_expert - first
+            flat_expert = jnp.where((local >= 0) & (local < count), local,
+                                    count)                  # absent: last
+        flat_token = jnp.repeat(jnp.arange(t), m.top_k)
+        order = jnp.argsort(flat_expert)
+        tok_sorted = flat_token[order]
+        exp_sorted = flat_expert[order]
+        group_sizes = jnp.zeros((m.n_held,), jnp.int32).at[exp_sorted].add(
+            1, mode="drop")
+        xs = xf[tok_sorted]                                 # (T*k, D)
 
-    xs = xf[tok_sorted]                                     # (T*k, D)
-    h = jax.nn.silu(jax.lax.ragged_dot(xs, p["w_gate"], group_sizes)) * \
-        jax.lax.ragged_dot(xs, p["w_up"], group_sizes)
-    ys = jax.lax.ragged_dot(h, p["w_down"], group_sizes)    # (T*k, D)
+    with jax.named_scope("experts"):
+        h = jax.nn.silu(jax.lax.ragged_dot(xs, p["w_gate"], group_sizes)) * \
+            jax.lax.ragged_dot(xs, p["w_up"], group_sizes)
+        ys = jax.lax.ragged_dot(h, p["w_down"], group_sizes)  # (T*k, D)
+        if m.held is not None:
+            ys = jnp.where((exp_sorted < m.n_held)[:, None], ys, 0)
 
-    w_sorted = weights.reshape(-1)[order].astype(ys.dtype)
-    out = jnp.zeros((t, d), ys.dtype).at[tok_sorted].add(ys * w_sorted[:, None])
+        w_sorted = weights.reshape(-1)[order].astype(ys.dtype)
+        out = jnp.zeros((t, d), ys.dtype).at[tok_sorted].add(
+            ys * w_sorted[:, None])
 
     if m.num_shared:
-        out = out + swiglu(xf, p["shared_gate"], p["shared_up"], p["shared_down"])
+        with jax.named_scope("shared"):
+            out = out + swiglu(xf, p["shared_gate"], p["shared_up"],
+                               p["shared_down"])
     return out.reshape(b, s, d).astype(x.dtype), aux
 
 
@@ -124,7 +164,11 @@ def moe_ffn(p, x, cfg, mesh=None):
     over the model axis.
     """
     if mesh is None or "model" not in mesh.axis_names:
-        return _moe_local(p, x, cfg)
+        with jax.named_scope("moe"):
+            return _moe_local(p, x, cfg)
+    if cfg.moe.held is not None:
+        raise NotImplementedError("moe_ffn: held experts run on one device "
+                                  "(no mesh)")
 
     from jax.sharding import PartitionSpec as P
 

@@ -3,7 +3,10 @@ family (dense/GQA, MLA, MoE, SSM, hybrid, encoder-decoder, VLM).
 
 Params are pure pytrees; per-layer params are *stacked* along a leading
 layer axis and executed with ``repro.core.checkpoint.remat_scan`` so depth
-never inflates the HLO and OpTorch's S-C applies per segment.
+never inflates the HLO and OpTorch's S-C applies per segment.  A MoE
+stack's leading dense layers (``cfg.dense_layers``) are stacked apart,
+under ``dense_blocks``, and run first; the cache stays one stack of all
+``n_layers``.
 
 Public entry points:
   init_params(cfg, key)                -> params
@@ -82,10 +85,12 @@ def _init_ffn(cfg: ModelConfig, key) -> dict:
         m = cfg.moe
         p = {
             "router": dense_init(ks[0], (d, m.num_experts)),
-            "w_gate": dense_init(ks[1], (m.num_experts, d, m.d_expert), in_axis=1),
-            "w_up": dense_init(ks[2], (m.num_experts, d, m.d_expert), in_axis=1),
-            "w_down": dense_init(ks[3], (m.num_experts, m.d_expert, d), in_axis=1),
+            "w_gate": dense_init(ks[1], (m.n_held, d, m.d_expert), in_axis=1),
+            "w_up": dense_init(ks[2], (m.n_held, d, m.d_expert), in_axis=1),
+            "w_down": dense_init(ks[3], (m.n_held, m.d_expert, d), in_axis=1),
         }
+        if m.scoring == "sigmoid":
+            p["router_bias"] = jnp.zeros((m.num_experts,))
         if m.num_shared:
             p.update(
                 shared_gate=dense_init(ks[4], (d, m.d_shared)),
@@ -178,19 +183,27 @@ def _init_enc_block(cfg: ModelConfig, key) -> dict:
 
 
 def dataclass_no_moe(cfg):
+    """The config of a dense-MLP layer of ``cfg`` (the encoder's, or a
+    leading dense layer of a MoE stack)."""
     import dataclasses
-    return dataclasses.replace(cfg, moe=None) if cfg.moe is not None else cfg
+    return dataclasses.replace(cfg, moe=None, dense_layers=0) \
+        if cfg.moe is not None else cfg
 
 
 def init_params(cfg: ModelConfig, key) -> dict:
     k_embed, k_blocks, k_head, k_enc = jax.random.split(key, 4)
+    n_dense = cfg.dense_layers
     blocks = jax.vmap(lambda k: _init_block(cfg, k))(
-        jax.random.split(k_blocks, cfg.n_layers))
+        jax.random.split(k_blocks, cfg.n_layers - n_dense))
     params = {
         "embed": embed_init(k_embed, (cfg.padded_vocab, cfg.d_model)),
         "blocks": blocks,
         "final_norm": jnp.ones((cfg.d_model,)),
     }
+    if n_dense:
+        params["dense_blocks"] = jax.vmap(
+            lambda k: _init_block(dataclass_no_moe(cfg), k))(
+            jax.random.split(jax.random.fold_in(k_blocks, 1), n_dense))
     if not cfg.tie_embeddings:
         params["lm_head"] = dense_init(k_head, (cfg.d_model, cfg.padded_vocab))
     if cfg.encoder is not None:
@@ -244,7 +257,8 @@ def _block_apply(p, x, cfg, *, positions, window, ssd_backend="ref",
             mix, (lat, kr) = attn.mla_block(p["attn"], h, cfg,
                                             positions=positions)
             if collect_cache:
-                cache_entry = {"mla_lat": lat, "mla_rope": kr[:, :, 0]}
+                cache_entry = {"mla_lat": lat,
+                               "mla_rope": jnp.swapaxes(kr[:, :, 0], 1, 2)}
         else:
             mix, (k, v) = attn.attn_block(p["attn"], h, cfg,
                                           positions=positions,
@@ -355,30 +369,40 @@ def forward(params, cfg: ModelConfig, batch: dict, *,
     static_window = int(cfg.window) if not cfg.global_layers else None
     windows = None if static_window is not None else layer_windows(cfg)
 
-    def body(carry, xs):
-        if static_window is None:
-            p_layer, win = xs
-        else:
-            p_layer, win = xs, static_window
-        ekv = None
-        if enc_kv is not None:
-            hkv, hd = cfg.n_kv, cfg.head_dim
-            bb, se, _ = enc_kv.shape
-            k = (enc_kv @ p_layer["xattn"]["wk"]).reshape(bb, se, hkv, hd)
-            v = (enc_kv @ p_layer["xattn"]["wv"]).reshape(bb, se, hkv, hd)
-            ekv = (k, v)
-        out, aux, entry = _block_apply(
-            p_layer, carry, cfg, positions=positions, window=win,
-            ssd_backend=ssd_backend, enc_kv=ekv, collect_cache=build_cache,
-            mesh=mesh, cache_quantized=cache_quantized,
-            flash_resid_dtype=policy.flash_resid_dtype)
-        return out, (aux, entry)
+    def make_body(bcfg):
+        def body(carry, xs):
+            if static_window is None:
+                p_layer, win = xs
+            else:
+                p_layer, win = xs, static_window
+            ekv = None
+            if enc_kv is not None:
+                hkv, hd = cfg.n_kv, cfg.head_dim
+                bb, se, _ = enc_kv.shape
+                k = (enc_kv @ p_layer["xattn"]["wk"]).reshape(bb, se, hkv, hd)
+                v = (enc_kv @ p_layer["xattn"]["wv"]).reshape(bb, se, hkv, hd)
+                ekv = (k, v)
+            out, aux, entry = _block_apply(
+                p_layer, carry, bcfg, positions=positions, window=win,
+                ssd_backend=ssd_backend, enc_kv=ekv,
+                collect_cache=build_cache, mesh=mesh,
+                cache_quantized=cache_quantized,
+                flash_resid_dtype=policy.flash_resid_dtype)
+            return out, (aux, entry)
+        return body
 
+    if cfg.dense_layers:
+        # the leading dense layers: their own stack, before the MoE scan
+        x, (_, dense_entries) = jax.lax.scan(
+            make_body(dataclass_no_moe(cfg)), x, params["dense_blocks"])
     x, (auxes, entries) = remat_scan(
-        body, x,
+        make_body(cfg), x,
         params["blocks"] if static_window is not None
         else (params["blocks"], windows),
         config=remat, unroll=scan_unroll)
+    if cfg.dense_layers and build_cache:
+        entries = {k: jnp.concatenate([dense_entries[k], v])
+                   for k, v in entries.items()}
     x = rms_norm(x, params["final_norm"], cfg.norm_eps, bf16_grad=cfg.norm_bf16_grad)
     aux_out = {"moe_aux": jnp.mean(auxes) if cfg.moe is not None else 0.0}
     if build_cache:
@@ -390,6 +414,16 @@ def forward(params, cfg: ModelConfig, batch: dict, *,
     logits = (x @ head).astype(policy.output_dtype)
     logits = _mask_padded_vocab(logits, cfg)
     return logits, aux_out
+
+
+def head_logits(params, cfg: ModelConfig, x, *,
+                policy: Policy = Policy.full()):
+    """LM-head logits of final hidden rows ``x`` (``forward(...,
+    return_hidden=True)``'s output, or rows of it)."""
+    head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
+    logits = (x @ head.astype(policy.compute_dtype)).astype(
+        policy.output_dtype)
+    return _mask_padded_vocab(logits, cfg)
 
 
 def _assemble_cache(cfg: ModelConfig, entries: dict, s: int, *,
@@ -607,8 +641,11 @@ def init_cache(cfg: ModelConfig, batch: int, s_max: int, *,
     if cfg.mixer in ("attn", "hybrid"):
         if cfg.mla is not None:
             m = cfg.mla
+            # the rope keys lie sequence-minor, (L, B, dr, S): a 64-wide
+            # minor axis would be laid out transposed on a TPU anyway
             cache["mla_lat"] = jnp.zeros((L, batch, s_max, m.kv_lora_rank), dtype)
-            cache["mla_rope"] = jnp.zeros((L, batch, s_max, m.qk_rope_dim), dtype)
+            cache["mla_rope"] = jnp.zeros((L, batch, m.qk_rope_dim, s_max),
+                                          dtype)
         else:
             hkv, hd = cfg.n_kv, cfg.head_dim
             kv_dtype = jnp.int8 if quantized else dtype
@@ -628,7 +665,7 @@ def init_cache(cfg: ModelConfig, batch: int, s_max: int, *,
 #: cache leaves with a sequence axis, and which axis it is — the single
 #: source for growing / scattering caches (serve pool, prefill prealloc).
 CACHE_SEQ_AXES = {"k": 3, "v": 3, "k_scale": 3, "v_scale": 3,
-                  "mla_lat": 2, "mla_rope": 2}
+                  "mla_lat": 2, "mla_rope": 3}
 
 
 def grow_cache(cache: dict, s_max: int) -> dict:
@@ -677,18 +714,27 @@ def decode_step(params, cfg: ModelConfig, cache: dict, tokens_t, *,
     softmax never normalizes over an empty row (their logits are garbage
     by contract and never read).  Occupancy is pure data — joining or
     retiring a request never changes a traced shape, hence no recompile.
+    An MLA stack decodes per slot through ``_decode_slots_mla``, which
+    writes and reads the stacked latent cache in place.
     """
     params = policy.cast_to_compute(params)
     pos = cache["pos"]
     per_slot = getattr(pos, "ndim", 0) == 1
-    if per_slot and (cfg.mixer != "attn" or cfg.mla is not None):
+    if per_slot and cfg.mixer != "attn":
         raise NotImplementedError(
             "per-slot decode (vector cache['pos']) is only supported for "
-            "GQA attention caches (the kvq layout); MLA/SSM/hybrid archs "
-            "serve through the scalar-pos paths")
+            "attention caches (the kvq layout or MLA latents); SSM/hybrid "
+            "archs serve through the scalar-pos paths")
     if active is not None and not per_slot:
         raise ValueError("decode_step: active mask requires a per-slot "
                          "(vector) cache['pos']")
+    if per_slot and cfg.mla is not None:
+        return _decode_slots_mla(params, cfg, cache, tokens_t, policy=policy,
+                                 backend=kvq_backend, active=active,
+                                 scan_unroll=scan_unroll)
+    if cfg.dense_layers:
+        raise NotImplementedError("decode_step: leading dense layers are "
+                                  "served by the per-slot MLA path only")
     # per-slot pos is >= 0 by construction (pool zeros / scatter lengths),
     # so lengths = pos+1 >= 1 and every row's softmax normalizer is
     # non-empty on every backend — free slots never produce NaNs
@@ -762,3 +808,56 @@ def decode_step(params, cfg: ModelConfig, cache: dict, tokens_t, *,
     else:
         new_caches["pos"] = pos + 1
     return logits, new_caches
+
+
+def _decode_slots_mla(params, cfg: ModelConfig, cache: dict, tokens_t, *,
+                      policy: Policy, backend: str, active, scan_unroll: int):
+    """Per-slot decode of an MLA stack over the slot pool's latent cache.
+
+    The stacked ``mla_lat`` / ``mla_rope`` leaves ride the layer scan's
+    carry, and each layer writes its token and reads its layer of them in
+    place (``attention.mla_decode_slots``): no layer's slice of the cache
+    is scanned in or out, so the round makes no copy of the cache.
+    Leading dense layers (``cfg.dense_layers``) scan first, over the first
+    layers of the same cache.  Rows that ``active`` marks free read one
+    position and keep their position."""
+    pos = cache["pos"]
+    lengths = pos + 1 if active is None else jnp.where(active, pos + 1, 1)
+    x = params["embed"][tokens_t]                           # (B, D)
+
+    def make_body(lcfg):
+        def body(carry, xs):
+            x, lat, rope = carry
+            p_layer, layer = xs
+            h = rms_norm(x[:, None], p_layer["ln1"], cfg.norm_eps,
+                         bf16_grad=cfg.norm_bf16_grad)[:, 0]
+            mix, lat, rope = attn.mla_decode_slots(
+                p_layer["attn"], h, cfg, lat, rope, layer, pos, lengths,
+                backend=backend)
+            x = x + mix
+            h2 = rms_norm(x[:, None], p_layer["ln2"], cfg.norm_eps,
+                          bf16_grad=cfg.norm_bf16_grad)
+            ffn_out, _ = _ffn_apply(p_layer["ffn"], h2, lcfg)
+            return (x + ffn_out[:, 0], lat, rope), None
+        return body
+
+    carry = (x, cache["mla_lat"], cache["mla_rope"])
+    n_dense = cfg.dense_layers
+    with jax.named_scope("layers"):
+        if n_dense:
+            carry, _ = jax.lax.scan(
+                make_body(dataclass_no_moe(cfg)), carry,
+                (params["dense_blocks"], jnp.arange(n_dense)))
+        carry, _ = jax.lax.scan(
+            make_body(cfg), carry,
+            (params["blocks"], jnp.arange(n_dense, cfg.n_layers)),
+            unroll=scan_unroll)
+    x, lat, rope = carry
+    with jax.named_scope("lm_head"):
+        x = rms_norm(x[:, None], params["final_norm"], cfg.norm_eps,
+                     bf16_grad=cfg.norm_bf16_grad)[:, 0]
+        head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
+        logits = _mask_padded_vocab((x @ head).astype(policy.output_dtype),
+                                    cfg)
+    step = 1 if active is None else active.astype(jnp.int32)
+    return logits, {"pos": pos + step, "mla_lat": lat, "mla_rope": rope}

@@ -81,10 +81,11 @@ def default_buckets(max_len: int, lo: int = 16) -> tuple[int, ...]:
 
 
 def supports(cfg: ModelConfig) -> bool:
-    """Engine eligibility: the slot-pooled per-row decode path needs the
-    GQA kvq cache layout and a uniform window schedule."""
-    return (cfg.mixer == "attn" and cfg.mla is None
-            and cfg.encoder is None and not cfg.global_layers)
+    """Engine eligibility: the slot-pooled per-row decode path needs an
+    attention cache the pool holds (the GQA kvq layout or MLA latents)
+    and a uniform window schedule."""
+    return (cfg.mixer == "attn" and cfg.encoder is None
+            and not cfg.global_layers)
 
 
 class ServeEngine:
@@ -104,10 +105,14 @@ class ServeEngine:
                  sampler_keys: str = "step", sink=None):
         if not supports(cfg):
             raise NotImplementedError(
-                "ServeEngine needs a GQA attention arch with a uniform "
-                "window schedule (no MLA latents, SSM state, encoder "
+                "ServeEngine needs an attention arch (GQA or MLA) with a "
+                "uniform window schedule (no SSM state, encoder "
                 "cross-attention, or per-layer global overrides) — those "
                 "serve through the lockstep driver")
+        if mesh is not None and cfg.mla is not None:
+            raise NotImplementedError(
+                "ServeEngine: MLA latents are served on one device (no "
+                "mesh)")
         if max_retries < 0 or retry_backoff_steps < 0:
             raise ValueError("ServeEngine: max_retries and "
                              "retry_backoff_steps must be >= 0")
@@ -245,13 +250,24 @@ class ServeEngine:
             # mesh: _kv_entry pins each cache entry's sharding as it is
             # built, so the prefill scan carries the pool's layout from the
             # start instead of XLA re-sharding the finished cache
-            logits, aux = transformer.forward(
-                params, cfg, {"tokens": tokens}, policy=policy,
-                build_cache=True, cache_quantized=quantized, mesh=mesh)
-            # last VALID position, not bucket-1: padded suffix logits are
-            # garbage by contract
-            last = jax.lax.dynamic_index_in_dim(logits, true_len - 1, axis=1,
-                                                keepdims=False)
+            if cfg.mla is not None:
+                # the LM head on the last valid row alone: at a 16k bucket
+                # the whole bucket's logits would take 5 GB
+                hidden, aux = transformer.forward(
+                    params, cfg, {"tokens": tokens}, policy=policy,
+                    build_cache=True, return_hidden=True)
+                last = transformer.head_logits(
+                    params, cfg, jax.lax.dynamic_index_in_dim(
+                        hidden, true_len - 1, axis=1, keepdims=False),
+                    policy=policy)
+            else:
+                logits, aux = transformer.forward(
+                    params, cfg, {"tokens": tokens}, policy=policy,
+                    build_cache=True, cache_quantized=quantized, mesh=mesh)
+                # last VALID position, not bucket-1: padded suffix logits
+                # are garbage by contract
+                last = jax.lax.dynamic_index_in_dim(logits, true_len - 1,
+                                                    axis=1, keepdims=False)
             cache = transformer.grow_cache(aux["cache"], self.max_len)
             return last, cache
 
@@ -558,7 +574,10 @@ class ServeEngine:
         """Drop all request state; keep the compiled programs."""
         assert self.scheduler.resident == 0 and not self.scheduler.has_work(), \
             "reset with in-flight requests"
-        self.pool = SlotPool(self.cfg, self.pool.max_slots, self.max_len,
+        max_slots, self.pool = self.pool.max_slots, None
+        # the old pool's cache is released before the new one is made: a
+        # 5.4 GB latent cache cannot sit on the chip twice
+        self.pool = SlotPool(self.cfg, max_slots, self.max_len,
                              quantized=self.quantized, mesh=self.mesh)
         self.scheduler = Scheduler(
             self.pool.max_slots,
@@ -702,7 +721,8 @@ class ServeEngine:
         Traced, the ``step`` span holds consecutive phase spans: ``admit``,
         then per admission ``dispatch`` (prefill, scatter), ``sync`` (its
         first token) and ``emit``, then ``dispatch`` (decode), ``sync``
-        (decode) and ``emit``."""
+        (decode) and ``emit``.  With MLA the ``step`` span also ends with
+        ``latent_positions``: the cached positions the round reads."""
         hook = self.hooks.get("pre_step")
         if hook is not None:
             hook(self)
@@ -719,6 +739,7 @@ class ServeEngine:
         slots = [self.pool.alloc() for _ in admitted]
         assert None not in slots          # pop_admissible checked free_slots
         prefill_tokens = prefill_padded = 0
+        latent_positions = None
         scatter_ok = self.hooks.get("scatter_filter")
         for req, slot in zip(admitted, slots):
             if tr is not None:
@@ -785,6 +806,12 @@ class ServeEngine:
             if hook is not None:
                 hook(self)
             live = np.nonzero(self._active_buf)[0]      # snapshot pre-emit
+            if tr is not None and self.cfg.mla is not None:
+                # the cached positions this round's attention reads: each
+                # live slot's prompt and tokens, its newest token included
+                latent_positions = sum(
+                    self._slot_req[int(slot)].prompt_len
+                    + len(self._slot_req[int(slot)].tokens) for slot in live)
             if self.sampler_keys == "request":
                 (self._tokens_dev, self.pool.cache,
                  self._draws_dev) = self._decode_fn(
@@ -815,10 +842,13 @@ class ServeEngine:
                              self.pool.occupancy)
         if tr is not None:
             tr.end(phase)
+            counters = {}
+            if latent_positions is not None:
+                counters["latent_positions"] = latent_positions
             tr.end(step_sid, admitted=len(admitted),
                    occupancy=self.pool.occupancy,
                    prefill_tokens=prefill_tokens,
-                   prefill_padded=prefill_padded)
+                   prefill_padded=prefill_padded, **counters)
         self._step_no += 1
 
     def request_states(self) -> dict:

@@ -261,3 +261,102 @@ def test_train_step_names_its_phases(one_chip):
     assert _scoped(text, "lm_head_loss") and _scoped(text, "optimizer")
     assert re.search(r'op_name="[^"]*transpose\(jvp\(lm_head_loss\)\)',
                      text)
+
+
+# GLM-4.7-Flash latent attention: 20 heads, a 512-wide latent and 64 rope
+# dims per position; prefill attends at head dim 192 + 64 = 256
+MLA_H, MLA_C, MLA_R, MLA_D = 20, 512, 64, 256
+
+
+def test_mla_decode_compiles_at_cell_size(one_chip):
+    """The latent decode kernel at the long-context cell's pool: 24 slots
+    of 16384 positions, 12 layers, reading layer 3 where it lies."""
+    from repro.kernels.mla import kernel as mla_kernel
+    from repro.kernels.mla import ops as mla_ops
+
+    b, n_layers, s = 24, 12, 16384
+    bf16 = jnp.bfloat16
+    text = _compile(
+        lambda qa, qr, lat, rope, lens: mla_ops.mla_decode_attention(
+            qa, qr, lat, rope, lens, 3, sm_scale=MLA_D ** -0.5,
+            backend="pallas"),
+        one_chip, ((b, MLA_H, MLA_C), bf16), ((b, MLA_H, MLA_R), bf16),
+        ((n_layers, b, s, MLA_C), bf16), ((n_layers, b, MLA_R, s), bf16),
+        ((b,), jnp.int32))
+    assert _named_kernels(text) == {mla_kernel.KERNEL_NAME}
+    assert not re.search(r"= bf16\[12,24,16384,\d+\][^=]*copy\(", text)
+
+
+def test_flash_fwd_compiles_at_mla_prefill(one_chip):
+    """Latent attention's prefill as flash sees it: 20 heads of 256 over
+    the cell's largest bucket."""
+    shape = ((1, MLA_H, 16384, MLA_D), jnp.bfloat16)
+    text = _compile(lambda q, k, v: flash_ops.flash_attention(
+        q, k, v, causal=True, sm_scale=MLA_D ** -0.5, backend="pallas"),
+        one_chip, shape, shape, shape)
+    assert _named_kernels(text) == {flash_kernel.KERNEL_NAMES["fwd"]}
+
+
+def _mla_cfg(**kw):
+    from repro.models.config import MLAConfig, MoEConfig
+    base = configs.get_config("glm47-flash")
+    return dataclasses.replace(
+        base, mla=MLAConfig(q_lora_rank=768, kv_lora_rank=MLA_C,
+                            qk_nope_dim=192, qk_rope_dim=MLA_R,
+                            v_head_dim=256),
+        moe=dataclasses.replace(base.moe, held=(8, 8)), **kw)
+
+
+def test_mla_decode_program_names_its_phases(one_chip):
+    """The engine's decode round for latent attention with a dense
+    prologue and held experts: the named latent kernel, and the
+    ``layers``, ``mla_decode``, ``moe``, ``lm_head`` and ``sentinel``
+    scopes."""
+    from repro.kernels.mla import kernel as mla_kernel
+    from repro.models import transformer
+    from repro.serve import ServeEngine
+
+    cfg = _mla_cfg(n_layers=3, d_model=256, d_ff=512, vocab=1024)
+    params = jax.eval_shape(
+        lambda: transformer.init_params(cfg, jax.random.PRNGKey(0)))
+    params = jax.tree_util.tree_map(
+        lambda x: jnp.zeros(x.shape, jnp.bfloat16), params)
+    eng = ServeEngine(params, cfg, max_slots=8, max_len=1024,
+                      prompt_buckets=(128,), kv_backend="pallas")
+    text = eng._decode_fn.lower(*_on_chip(
+        (eng.params, eng.pool.cache, eng._tokens_dev, eng._active_dev,
+         eng._key), one_chip)).compile().as_text()
+    assert _named_kernels(text) == {mla_kernel.KERNEL_NAME}
+    for scope in ("layers", "mla_decode", "moe", "lm_head", "sentinel"):
+        assert _scoped(text, scope), scope
+
+
+def test_mla_decode_round_copies_no_cache(one_chip):
+    """The long-context cell's decode round at its size (12 layers, 24
+    slots of 16384): the 5.4 GB latent cache is donated, written and read
+    in place; the round's temporaries stay under 0.1 GB."""
+    from repro.core.mixed_precision import get_policy
+    from repro.models import transformer
+
+    cfg = _mla_cfg(n_layers=12)
+    b, s = 24, 16384
+    params = jax.eval_shape(lambda: jax.tree_util.tree_map(
+        lambda x: x.astype(jnp.bfloat16),
+        transformer.init_params(cfg, jax.random.PRNGKey(0))))
+    cache = jax.eval_shape(lambda: transformer.init_cache(cfg, b, s))
+    cache["pos"] = jax.ShapeDtypeStruct((b,), jnp.int32)
+
+    def decode(params, cache, tokens, active):
+        return transformer.decode_step(
+            params, cfg, cache, tokens, policy=get_policy("bf16"),
+            kvq_backend="pallas", active=active)
+
+    compiled = jax.jit(decode, donate_argnums=(1,)).lower(*_on_chip(
+        (params, cache, jax.ShapeDtypeStruct((b,), jnp.int32),
+         jax.ShapeDtypeStruct((b,), jnp.bool_)), one_chip)).compile()
+    text = compiled.as_text()
+    assert not re.search(r"= bf16\[12,24,(16384,512|64,16384)\]"
+                         r"[^=]*copy(-start)?\(", text)
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= 12 * b * s * (MLA_C + MLA_R) * 2
+    assert mem.temp_size_in_bytes < 1e8
