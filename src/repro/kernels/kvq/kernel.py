@@ -14,8 +14,17 @@ The int8->f32 dequant happens *after* the chunk is resident in VMEM, so
 HBM sees only 1 byte/elem + 4 B/token scales — the paper's store-encoded /
 decode-on-read trade applied to the decode-latency-dominant stream.
 
-Length-aware tile skipping: per-batch ``lengths`` arrive as a
-scalar-prefetch operand (``pltpu.PrefetchScalarGridSpec``), so
+The cache is the stacked one, ``(L, B, Hkv, S, D)`` with ``(L, B, Hkv,
+S)`` scales, and the layer to read rides the scalar-prefetch lane
+(``pltpu.PrefetchScalarGridSpec``): the K/V index maps lead with it, so
+the kernel reads that layer where it lies and no slice of the cache is
+copied out for it.  A per-layer cache is passed with a leading unit axis
+at layer 0.  The scales are read a ``(Hkv, bs)`` block at a time, whole
+along the head axis as Mosaic's layout rules allow, and the kernel takes
+its head's row.
+
+Length-aware tile skipping: per-batch ``lengths`` arrive as a second
+scalar-prefetch operand, so
 
   * the kernel body ``pl.when``-early-outs every KV tile whose start lies
     beyond ``lengths[b]`` (plus the last split's structural padding tiles
@@ -35,8 +44,9 @@ counting the KV tile-steps whose matmuls executed — the measured twin of
 :func:`repro.kernels.tiling.decode_tile_step_counts`, asserted
 tile-for-tile in tests and benchmarks, same contract as the flash grids.
 
-VMEM per step (BS=512, D<=128, G<=32):
+VMEM per step (BS=512, D<=128, G<=32, Hkv<=8):
   K,V chunks int8: 2*BS*D      = 128 KiB
+  scales f32:      2*Hkv*BS*4  <= 32 KiB
   dequant f32:     2*BS*D*4    = 512 KiB
   scratch acc:     G*D*4       <= 16 KiB         (fits VMEM with headroom)
 
@@ -60,12 +70,14 @@ DEFAULT_BS = tiling.DEFAULT_DECODE_BS
 KERNEL_NAME = "flash_decode_pallas"
 
 
-def _flash_decode_kernel(*refs, sm_scale, bs, ns, spt, has_bias,
-                         has_lengths, fused, count):
-    # arg order: [lengths (scalar prefetch)] q, k_q, k_s, v_q, v_s, [bias],
-    #            o[, m, l][, counts], scratch (m, l, acc, [count acc]).
+def _flash_decode_kernel(layer_ref, *refs, sm_scale, bs, ns, spt,
+                         has_bias, has_lengths, fused, count):
+    # arg order: layer, [lengths] (scalar prefetch), q, k_q, k_s, v_q, v_s,
+    #            [bias], o[, m, l][, counts], scratch (m, l, acc,
+    #            [count acc]).
     # ``fused`` (single split): normalize in-kernel and write the final
     # output — no partial (o, m, l) HBM round-trip, no jnp combine.
+    del layer_ref                       # used by the index maps only
     if has_lengths:
         lengths_ref, *refs = refs
     q_ref, kq_ref, ks_ref, vq_ref, vs_ref, *refs = refs
@@ -81,6 +93,7 @@ def _flash_decode_kernel(*refs, sm_scale, bs, ns, spt, has_bias,
         m_ref, l_ref, acc_ref = refs
 
     i = pl.program_id(0)
+    j = pl.program_id(1)
     split = pl.program_id(2)
     step = pl.program_id(3)
     t = split * spt + step                     # global KV tile this step
@@ -101,8 +114,8 @@ def _flash_decode_kernel(*refs, sm_scale, bs, ns, spt, has_bias,
 
     def _step():
         q = q_ref[...][0, 0].astype(jnp.float32)                 # (G, D)
-        k = kq_ref[...][0, 0].astype(jnp.float32) * ks_ref[...][0, 0, 0][:, None]
-        v = vq_ref[...][0, 0].astype(jnp.float32) * vs_ref[...][0, 0, 0][:, None]
+        k = kq_ref[...][0, 0, 0].astype(jnp.float32) * _head_row(ks_ref, j)
+        v = vq_ref[...][0, 0, 0].astype(jnp.float32) * _head_row(vs_ref, j)
         logits = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * sm_scale
         if has_bias:
             logits = logits + bias_ref[...][0]                    # (G, BS)
@@ -145,6 +158,14 @@ def _flash_decode_kernel(*refs, sm_scale, bs, ns, spt, has_bias,
             cnt_ref[...] = jnp.full(cnt_ref.shape, cnt_acc[0], jnp.int32)
 
 
+def _head_row(scale_ref, j):
+    """Head ``j``'s scales as a (bs, 1) column, from a (1, 1, Hkv, bs)
+    block of the stacked scales."""
+    blk = scale_ref[...][0, 0]                                 # (Hkv, bs)
+    rows = jax.lax.broadcasted_iota(jnp.int32, blk.shape, 0)
+    return jnp.sum(jnp.where(rows == j, blk, 0.0), axis=0)[:, None]
+
+
 def combine_splits(o_p, m_p, l_p, dtype):
     """Online-softmax merge of split-K partials (the lax-reduction half).
 
@@ -162,22 +183,24 @@ def combine_splits(o_p, m_p, l_p, dtype):
 
 @functools.partial(jax.jit, static_argnames=(
     "sm_scale", "block_s", "splits", "interpret", "debug_counts"))
-def flash_decode_pallas(q, k_q, k_s, v_q, v_s, bias=None, lengths=None, *,
-                        sm_scale: float, block_s: int = DEFAULT_BS,
-                        splits: int = 1, interpret: bool = False,
-                        debug_counts: bool = False):
-    """Shapes as in ref.decode_attention_ref; block size shrinks to divide S.
+def flash_decode_pallas(q, k_q, k_s, v_q, v_s, bias=None, lengths=None,
+                        layer=0, *, sm_scale: float,
+                        block_s: int = DEFAULT_BS, splits: int = 1,
+                        interpret: bool = False, debug_counts: bool = False):
+    """q: (B, Hkv, G, D); k_q/v_q: (L, B, Hkv, S, D) int8 and k_s/v_s:
+    (L, B, Hkv, S) f32, the stacked cache; ``layer`` the one to read.
+    Block size shrinks to divide S.
 
-    ``lengths`` (B,) int32 rides the scalar-prefetch lane and drives the
-    tile early-outs + in-tile iota mask; ``bias`` (B, S) f32 is the dense
-    fallback for masks lengths can't express (mutually exclusive).  With
-    neither, the unmasked kernel variant runs (no mask operand at all).
-    With ``debug_counts`` also returns (B, Hkv, splits) executed-step
-    counters.
+    ``layer`` and ``lengths`` (B,) int32 ride the scalar-prefetch lane;
+    ``lengths`` drives the tile early-outs + in-tile iota mask; ``bias``
+    (B, S) f32 is the dense fallback for masks lengths can't express
+    (mutually exclusive).  With neither, the unmasked kernel variant runs
+    (no mask operand at all).  With ``debug_counts`` also returns
+    (B, Hkv, splits) executed-step counters.
     """
     assert bias is None or lengths is None, "bias and lengths are exclusive"
     b, hkv, g, d = q.shape
-    s = k_q.shape[2]
+    s = k_q.shape[3]
     bs, ns, n_sp, spt = tiling.resolve_decode_grid(s, block_s=block_s,
                                                    splits=splits)
     grid = (b, hkv, n_sp, spt)
@@ -190,27 +213,25 @@ def flash_decode_pallas(q, k_q, k_s, v_q, v_s, bias=None, lengths=None, *,
             len_ref[i], bs=bs, ns=ns)
         return _imin(t, hi)
 
-    if has_lengths:
-        q_map = lambda i, j, k, st, lr: (i, j, 0, 0)
-        kv_map = lambda i, j, k, st, lr: (i, j, _tile(i, k, st, lr), 0)
-        sc_map = lambda i, j, k, st, lr: (i, j, 0, _tile(i, k, st, lr))
-        o_map = lambda i, j, k, st, lr: (i, j, k, 0, 0)
-    else:
-        q_map = lambda i, j, k, st: (i, j, 0, 0)
-        kv_map = lambda i, j, k, st: (i, j, _tile(i, k, st), 0)
-        sc_map = lambda i, j, k, st: (i, j, 0, _tile(i, k, st))
-        o_map = lambda i, j, k, st: (i, j, k, 0, 0)
+    # index maps get (grid ids, layer ref[, lengths ref])
+    q_map = lambda i, j, k, st, *_: (i, j, 0, 0)
+    kv_map = lambda i, j, k, st, ly, *lr: (ly[0], i, j,
+                                           _tile(i, k, st, *lr), 0)
+    sc_map = lambda i, j, k, st, ly, *lr: (ly[0], i, 0,
+                                           _tile(i, k, st, *lr))
+    o_map = lambda i, j, k, st, *_: (i, j, k, 0, 0)
 
-    # Per-token scales (and the bias) get a unit axis so the block's last
-    # two dims are (1, bs) against an array dim of 1, a layout Mosaic
-    # accepts; likewise the (1, g) stat rows and (1, 1) counters below.
-    kv_spec = pl.BlockSpec((1, 1, bs, d), kv_map)
-    sc_spec = pl.BlockSpec((1, 1, 1, bs), sc_map)
+    # The scales' block spans every kv head, (Hkv, bs) against the array's
+    # (Hkv, S), a layout Mosaic accepts with no copy of the stacked leaf;
+    # the bias gets a unit axis for the same reason, as do the (1, g) stat
+    # rows and (1, 1) counters below.
+    kv_spec = pl.BlockSpec((1, 1, 1, bs, d), kv_map)
+    sc_spec = pl.BlockSpec((1, 1, hkv, bs), sc_map)
     in_specs = [pl.BlockSpec((1, 1, g, d), q_map),
                 kv_spec, sc_spec, kv_spec, sc_spec]
-    args = [q, k_q, k_s.reshape(b, hkv, 1, s), v_q, v_s.reshape(b, hkv, 1, s)]
+    args = [q, k_q, k_s, v_q, v_s]
     if has_bias:
-        bias_map = (lambda i, j, k, st: (i, 0, _tile(i, k, st)))
+        bias_map = lambda i, j, k, st, *_: (i, 0, _tile(i, k, st))
         in_specs.append(pl.BlockSpec((1, 1, bs), bias_map))
         args.append(bias.reshape(b, 1, s))
 
@@ -250,20 +271,15 @@ def flash_decode_pallas(q, k_q, k_s, v_q, v_s, bias=None, lengths=None, *,
     params = pltpu.CompilerParams(
         dimension_semantics=("parallel", "parallel", "parallel",
                              "arbitrary"))
+    scalars = [jnp.reshape(jnp.asarray(layer, jnp.int32), (1,))]
     if has_lengths:
-        grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1, grid=grid, in_specs=in_specs,
-            out_specs=out_specs, scratch_shapes=scratch_shapes)
-        out = pl.pallas_call(kern, grid_spec=grid_spec, out_shape=out_shape,
-                             compiler_params=params, interpret=interpret,
-                             name=KERNEL_NAME)(
-            jnp.asarray(lengths, jnp.int32), *args)
-    else:
-        out = pl.pallas_call(kern, grid=grid, in_specs=in_specs,
-                             out_specs=out_specs, out_shape=out_shape,
-                             scratch_shapes=scratch_shapes,
-                             compiler_params=params,
-                             interpret=interpret, name=KERNEL_NAME)(*args)
+        scalars.append(jnp.asarray(lengths, jnp.int32))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=len(scalars), grid=grid, in_specs=in_specs,
+        out_specs=out_specs, scratch_shapes=scratch_shapes)
+    out = pl.pallas_call(kern, grid_spec=grid_spec, out_shape=out_shape,
+                         compiler_params=params, interpret=interpret,
+                         name=KERNEL_NAME)(*scalars, *args)
 
     if fused:
         o = out[0]

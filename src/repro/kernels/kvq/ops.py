@@ -6,7 +6,9 @@ dispatches pallas / interpret / ref.  ``splits`` selects the split-K
 decode grid (``kernel.flash_decode_pallas``); ``lengths`` rides the
 scalar-prefetch lane and skips fully-padded KV tiles instead of paying a
 dense (B, S) bias add — on EVERY backend, ref included, the lengths path
-never materializes a bias tensor.
+never materializes a bias tensor.  ``layer`` reads one layer of a stacked
+``(L, B, Hkv, S, D)`` cache where it lies (the serve pool's decode round);
+without it the cache is one layer's.
 """
 from __future__ import annotations
 
@@ -27,11 +29,13 @@ def resolve_splits(s: int, splits: int,
     return tiling.resolve_decode_grid(s, block_s=block_s, splits=splits)[2]
 
 
-def decode_attention(q, k_q, k_s, v_q, v_s, *, lengths=None, bias=None,
-                     sm_scale: float | None = None, backend: str = "ref",
-                     splits: int = 1, block_s: int | None = None,
-                     debug_counts: bool = False):
-    """q: (B, H, D); cache: (B, Hkv, S, D) int8 (+ (B, Hkv, S) scales).
+def decode_attention(q, k_q, k_s, v_q, v_s, *, layer=None, lengths=None,
+                     bias=None, sm_scale: float | None = None,
+                     backend: str = "ref", splits: int = 1,
+                     block_s: int | None = None, debug_counts: bool = False):
+    """q: (B, H, D); cache: (B, Hkv, S, D) int8 (+ (B, Hkv, S) scales), or
+    with ``layer`` (int or traced scalar) the stacked (L, B, Hkv, S, D)
+    cache (+ (L, B, Hkv, S) scales) and the layer to read.
 
     lengths: (B,) valid cache lengths — compared against a per-tile iota
     inside the kernel/ref body (never a broadcast bias tensor) and, on the
@@ -53,7 +57,7 @@ def decode_attention(q, k_q, k_s, v_q, v_s, *, lengths=None, bias=None,
     if lengths is not None and bias is not None:
         raise ValueError("decode_attention: lengths and bias are exclusive")
     b, h, d = q.shape
-    _, hkv, s, _ = k_q.shape
+    hkv = k_q.shape[-3]
     assert h % hkv == 0, (h, hkv)
     g = h // hkv
     sm = sm_scale if sm_scale is not None else d ** -0.5
@@ -65,15 +69,18 @@ def decode_attention(q, k_q, k_s, v_q, v_s, *, lengths=None, bias=None,
             raise ValueError("decode_attention: debug_counts needs a kernel "
                              "backend (interpret/pallas); ref runs no grid")
         out = ref.decode_attention_ref(qg, k_q, k_s, v_q, v_s, bias, sm,
-                                       lengths=lengths)
+                                       lengths=lengths, layer=layer)
     else:
         kw = dict(sm_scale=sm, splits=splits,
                   interpret=(backend == "interpret"),
                   debug_counts=debug_counts)
         if block_s is not None:
             kw["block_s"] = block_s
+        if layer is None:                  # one layer: a unit layer axis
+            k_q, k_s, v_q, v_s = (c[None] for c in (k_q, k_s, v_q, v_s))
+            layer = 0
         out = kernel.flash_decode_pallas(qg, k_q, k_s, v_q, v_s, bias,
-                                         lengths, **kw)
+                                         lengths, layer, **kw)
         if debug_counts:
             out, counts = out
             return out.reshape(b, h, d), counts

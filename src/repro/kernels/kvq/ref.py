@@ -54,7 +54,7 @@ def masked_decode_logits(q, k, sm_scale, bias, lengths):
 
 
 def decode_attention_ref(q, k_q, k_s, v_q, v_s, bias, sm_scale: float,
-                         lengths=None):
+                         lengths=None, layer=None):
     """Exact reference.
 
     q:   (B, Hkv, G, D) f32      — G = query heads per KV head (GQA group)
@@ -63,9 +63,13 @@ def decode_attention_ref(q, k_q, k_s, v_q, v_s, bias, sm_scale: float,
     bias:(B, S) f32 additive mask (0 valid / -inf padded), or None
     lengths: (B,) int32 valid prefix lengths — masked with an in-body iota
          compare, never a broadcast bias tensor (exclusive with ``bias``)
+    layer: with it, the cache leaves are stacked, (L, ...) ahead of the
+         shapes above, and this layer of them is read
     ->   (B, Hkv, G, D) f32
     """
     assert bias is None or lengths is None
+    if layer is not None:
+        k_q, k_s, v_q, v_s = (c[layer] for c in (k_q, k_s, v_q, v_s))
     k = dequantize_kv(k_q, k_s)
     v = dequantize_kv(v_q, v_s)
     logits = masked_decode_logits(q, k, sm_scale, bias, lengths)

@@ -315,37 +315,112 @@ def mla_decode_slots(p, x_t, cfg, lat, rope, layer, pos, lengths, *,
 # Cached single-token decode.
 # ---------------------------------------------------------------------------
 def _write_token(cache, new, at):
-    """Write one token into the S axis of a per-layer cache leaf.
+    """Write one token at scalar position ``at`` of the S axis of a
+    per-layer cache leaf (B, Hkv, S, hd) or (B, Hkv, S); new: (B, Hkv, hd)
+    / (B, Hkv) — every row writes the same position (lockstep batch)."""
+    return jax.lax.dynamic_update_slice(
+        cache, new[:, :, None], (0, 0, at, 0)[:cache.ndim])
 
-    cache: (B, Hkv, S, hd) or (B, Hkv, S); new: (B, Hkv, hd) / (B, Hkv);
-    at: scalar int32 (lockstep batch — every row writes the same slot) or
-    (B,) int32 (slot-pooled serving — each row writes at its own length).
-    The vector case lowers to a per-row dynamic_update_slice under vmap
-    (a scatter), keeping the write O(1) in S instead of a full-cache
-    ``where`` rewrite.
-    """
-    if at.ndim == 0:
-        return jax.lax.dynamic_update_slice(
-            cache, new[:, :, None], (0, 0, at, 0)[:cache.ndim])
-    if cache.ndim == 4:
-        return jax.vmap(lambda c, n, a: jax.lax.dynamic_update_slice(
-            c, n[:, None], (0, a, 0)))(cache, new, at)
-    return jax.vmap(lambda c, n, a: jax.lax.dynamic_update_slice(
-        c, n[:, None], (0, a)))(cache, new, at)
+
+def _decode_qkv(p, x_t, cfg, pos):
+    """One token's query (B, H, hd) and new key and value (B, Hkv, hd),
+    rotated at ``pos``: a scalar, or one position per row (B,)."""
+    b, _ = x_t.shape
+    h, hkv, hd = cfg.n_heads, cfg.n_kv, cfg.head_dim
+    q = (x_t @ p["wq"]).reshape(b, 1, h, hd)
+    k_t = (x_t @ p["wk"]).reshape(b, 1, hkv, hd)
+    v_t = (x_t @ p["wv"]).reshape(b, 1, hkv, hd)
+    pos_arr = jnp.broadcast_to(pos[:, None] if pos.ndim == 1 else pos,
+                               (b, 1))
+    if cfg.mrope_sections is not None:
+        pos3 = jnp.broadcast_to(pos_arr[None], (3, b, 1))
+        q = apply_rope(q, pos3, cfg.rope_theta, cfg.rope_fraction,
+                       cfg.mrope_sections)
+        k_t = apply_rope(k_t, pos3, cfg.rope_theta, cfg.rope_fraction,
+                         cfg.mrope_sections)
+    else:
+        q = apply_rope(q, pos_arr, cfg.rope_theta, cfg.rope_fraction)
+        k_t = apply_rope(k_t, pos_arr, cfg.rope_theta, cfg.rope_fraction)
+    return q[:, 0], k_t[:, 0], v_t[:, 0]
+
+
+def _decode_mask(pos, b, s_max, window, rolling=False):
+    """(write position, lengths, bias) of a decode token at ``pos``.
+
+    Rolling buffers and full-causal (static window <= 0) schedules give
+    per-row ``lengths`` (bias None); a window band over a non-rolling
+    cache, or a traced per-layer window, gives the dense (B, S) ``bias``
+    (lengths None)."""
+    if rolling:
+        # slot j is filled iff j <= pos (pre-wrap) or always (post-wrap);
+        # all filled slots are within the window by construction
+        return (pos % s_max,
+                jnp.broadcast_to(jnp.minimum(pos + 1, s_max), (b,)), None)
+    if isinstance(window, int) and window <= 0:
+        return pos, jnp.broadcast_to(pos + 1, (b,)), None  # incl. current
+    kv_pos = jnp.arange(s_max)
+    pos_col = pos[:, None] if pos.ndim == 1 else pos   # vs (·, S)
+    valid = kv_pos[None, :] <= pos_col                   # includes current
+    if isinstance(window, int):
+        valid &= kv_pos[None, :] > pos_col - window
+    else:
+        valid &= jnp.where(window > 0, kv_pos[None, :] > pos_col - window,
+                           True)
+    bias = jnp.where(valid, 0.0, NEG_INF).astype(jnp.float32)
+    return pos, None, jnp.broadcast_to(bias, (b, s_max))
+
+
+def _kvq_attend(q, ck, csk, cv, csv, layer, lengths, bias, *, backend,
+                splits, mesh, kv_shard):
+    """int8 decode attention over ``layer`` of a stacked (L, B, Hkv, S, hd)
+    cache, masked by ``lengths`` or ``bias``; (B, H, hd) f32."""
+    mask_kw = "lengths" if lengths is not None else "bias"
+
+    def decode(q, ck, csk, cv, csv, layer, mask):
+        return kvq_ops.decode_attention(
+            q, ck, csk, cv, csv, layer=layer, backend=backend,
+            splits=splits, **{mask_kw: mask})
+
+    if kv_shard == "heads" and backend != "ref":
+        # XLA cannot partition a Mosaic kernel: each device runs it on its
+        # own kv heads (whole GQA groups, never split)
+        heads = P(None, None, "model")
+        decode = jax.shard_map(
+            decode, mesh=mesh,
+            in_specs=(P(None, "model"),) + (heads,) * 4 + (P(), P()),
+            out_specs=P(None, "model"), check_vma=False)
+    return decode(q, ck, csk, cv, csv, jnp.asarray(layer, jnp.int32),
+                  lengths if lengths is not None else bias)
+
+
+def _plain_attend(q, ck, cv, lengths, bias, cfg):
+    """Decode attention over one layer's unquantized (B, Hkv, S, hd)
+    cache; (B, H, hd) f32."""
+    b = q.shape[0]
+    h, hkv, hd = cfg.n_heads, cfg.n_kv, cfg.head_dim
+    qg = q.reshape(b, hkv, h // hkv, hd).astype(jnp.float32)
+    # one arithmetic source for the decode mask (lengths iota compare /
+    # bias add): shared with the kvq ref oracle so paths can't drift
+    from repro.kernels.kvq.ref import masked_decode_logits
+    logits = masked_decode_logits(qg, ck.astype(jnp.float32), hd ** -0.5,
+                                  bias, lengths)
+    pr = jax.nn.softmax(logits, -1)
+    return jnp.einsum("bhgs,bhsd->bhgd", pr, cv.astype(jnp.float32)
+                      ).reshape(b, h, hd)
 
 
 def attn_decode(p, x_t, cfg, cache_k, cache_s_k, cache_v, cache_s_v, pos,
                 *, window: int = 0, quantized: bool = True, backend: str = "ref",
                 splits: int = 1, rolling: bool = False, mesh=None,
                 kv_shard: str = "none"):
-    """One-token GQA decode against a (possibly int8) cache.
+    """One-token GQA decode against one layer of a (possibly int8) cache.
 
     x_t: (B, D_model); cache_k/v: (B, Hkv, S, hd) int8 (or bf16 when not
-    quantized, scales ignored); pos: scalar int32 current position, or a
-    per-row (B,) int32 vector for slot-pooled continuous batching
-    (``repro.serve``) — each batch row then RoPE-rotates, writes, and
-    masks at its OWN position, so one jitted step serves a ragged pool of
-    in-flight requests with static shapes.
+    quantized, scales ignored); pos: scalar int32 current position
+    (lockstep batch), or a per-row (B,) int32 vector on the mesh's
+    sequence-sharded serve pool (``kv_shard == "seq"``) — each batch row
+    then RoPE-rotates, writes, and masks at its OWN position.  The serve
+    pool's other per-row rounds decode through :func:`attn_decode_slots`.
     ``rolling``: the cache is a circular window buffer of size S — writes
     land at ``pos % S`` and every filled slot is in-window by construction
     (two-tier cache for windowed layers; EXPERIMENTS §Perf).
@@ -369,52 +444,14 @@ def attn_decode(p, x_t, cfg, cache_k, cache_s_k, cache_v, cache_s_v, pos,
     re-sharding the cache around a dynamic_update_slice on its sharded
     sequence axis.  "seq" requires a quantized cache (the serve pool's
     only layout).
-    Returns (attn_out (B, D_model), new k/v token (B, Hkv, hd)).
+    Returns (attn_out (B, D_model), the layer's updated k, k_scale, v,
+    v_scale).
     """
     b, _ = x_t.shape
-    h, hkv, hd = cfg.n_heads, cfg.n_kv, cfg.head_dim
-    s_max = cache_k.shape[2]
     pos = jnp.asarray(pos, jnp.int32)
-    per_row = pos.ndim == 1                       # slot-pooled ragged batch
-    q = (x_t @ p["wq"]).reshape(b, 1, h, hd)
-    k_t = (x_t @ p["wk"]).reshape(b, 1, hkv, hd)
-    v_t = (x_t @ p["wv"]).reshape(b, 1, hkv, hd)
-    pos_arr = jnp.broadcast_to(pos[:, None] if per_row else pos, (b, 1))
-    if cfg.mrope_sections is not None:
-        pos3 = jnp.broadcast_to(pos_arr[None], (3, b, 1))
-        q = apply_rope(q, pos3, cfg.rope_theta, cfg.rope_fraction,
-                       cfg.mrope_sections)
-        k_t = apply_rope(k_t, pos3, cfg.rope_theta, cfg.rope_fraction,
-                         cfg.mrope_sections)
-    else:
-        q = apply_rope(q, pos_arr, cfg.rope_theta, cfg.rope_fraction)
-        k_t = apply_rope(k_t, pos_arr, cfg.rope_theta, cfg.rope_fraction)
-    q = q[:, 0]                                            # (B, H, hd)
-    k_new = k_t[:, 0]
-    v_new = v_t[:, 0]
-
-    kv_pos = jnp.arange(s_max)
-    pos_col = pos[:, None] if per_row else pos    # broadcasts vs (·, S)
-    lengths = bias = None
-    if rolling:
-        write_at = pos % s_max
-        # slot j is filled iff j <= pos (pre-wrap) or always (post-wrap);
-        # all filled slots are within the window by construction
-        lengths = jnp.broadcast_to(jnp.minimum(pos + 1, s_max), (b,))
-    else:
-        write_at = pos
-        if isinstance(window, int) and window <= 0:
-            lengths = jnp.broadcast_to(pos + 1, (b,))      # includes current
-        else:
-            valid = kv_pos[None, :] <= pos_col             # includes current
-            if isinstance(window, int):
-                valid &= kv_pos[None, :] > pos_col - window
-            else:
-                valid &= jnp.where(window > 0,
-                                   kv_pos[None, :] > pos_col - window, True)
-            bias = jnp.where(valid, 0.0, NEG_INF).astype(jnp.float32)
-            bias = jnp.broadcast_to(bias, (b, s_max))
-
+    q, k_new, v_new = _decode_qkv(p, x_t, cfg, pos)
+    write_at, lengths, bias = _decode_mask(pos, b, cache_k.shape[2], window,
+                                           rolling)
     if quantized:
         kq_new, ks_new = kvq_ops.quantize_kv(k_new)
         vq_new, vs_new = kvq_ops.quantize_kv(v_new)
@@ -424,41 +461,72 @@ def attn_decode(p, x_t, cfg, cache_k, cache_s_k, cache_v, cache_s_v, pos,
                 q, cache_k, cache_s_k, cache_v, cache_s_v,
                 (kq_new, ks_new, vq_new, vs_new),
                 jnp.broadcast_to(write_at, (b,)), mesh,
-                sm_scale=hd ** -0.5, lengths=lengths, bias=bias)
+                sm_scale=cfg.head_dim ** -0.5, lengths=lengths, bias=bias)
         else:
             ck = _write_token(cache_k, kq_new, write_at)
             cv = _write_token(cache_v, vq_new, write_at)
             csk = _write_token(cache_s_k, ks_new, write_at)
             csv = _write_token(cache_s_v, vs_new, write_at)
-            mask_kw = "lengths" if lengths is not None else "bias"
-
-            def decode(q, ck, csk, cv, csv, mask):
-                return kvq_ops.decode_attention(
-                    q, ck, csk, cv, csv, backend=backend, splits=splits,
-                    **{mask_kw: mask})
-
-            if kv_shard == "heads" and backend != "ref":
-                # XLA cannot partition a Mosaic kernel: each device runs it
-                # on its own kv heads (whole GQA groups, never split)
-                heads = P(None, "model")
-                decode = jax.shard_map(
-                    decode, mesh=mesh, in_specs=(heads,) * 5 + (P(),),
-                    out_specs=heads, check_vma=False)
-            out = decode(q, ck, csk, cv, csv,
-                         lengths if lengths is not None else bias)
+            out = _kvq_attend(q, ck[None], csk[None], cv[None], csv[None], 0,
+                              lengths, bias, backend=backend, splits=splits,
+                              mesh=mesh, kv_shard=kv_shard)
     else:
         ck = _write_token(cache_k, k_new.astype(cache_k.dtype), write_at)
         cv = _write_token(cache_v, v_new.astype(cache_v.dtype), write_at)
         csk, csv = cache_s_k, cache_s_v
-        g = h // hkv
-        qg = q.reshape(b, hkv, g, hd).astype(jnp.float32)
-        # one arithmetic source for the decode mask (lengths iota compare /
-        # bias add): shared with the kvq ref oracle so paths can't drift
-        from repro.kernels.kvq.ref import masked_decode_logits
-        logits = masked_decode_logits(qg, ck.astype(jnp.float32),
-                                      hd ** -0.5, bias, lengths)
-        pr = jax.nn.softmax(logits, -1)
-        out = jnp.einsum("bhgs,bhsd->bhgd", pr, cv.astype(jnp.float32)
-                         ).reshape(b, h, hd)
-    out = out.reshape(b, h * hd).astype(x_t.dtype)
+        out = _plain_attend(q, ck, cv, lengths, bias, cfg)
+    out = out.reshape(b, -1).astype(x_t.dtype)
     return out @ p["wo"], (ck, csk, cv, csv)
+
+
+def attn_decode_slots(p, x_t, cfg, k, k_scale, v, v_scale, layer, pos, *,
+                      window=0, quantized: bool = True, backend: str = "ref",
+                      splits: int = 1, mesh=None, kv_shard: str = "none"):
+    """One-token GQA decode of a slot pool, each row at its own position.
+
+    ``k``/``v`` (L, B, Hkv, S, hd) and ``k_scale``/``v_scale`` (L, B, Hkv,
+    S) are the whole stacked cache (int8 and f32 scales; bf16 with unused
+    scales when not ``quantized``).  Each row's new token is written at
+    ``(layer, b, :, pos[b])`` with one scatter a leaf, every row at once;
+    a position past the cache clamps to its last slot, as a
+    ``dynamic_update_slice`` would.  The int8 attention reads ``layer``
+    of the cache where it lies (``kernels.kvq`` with the layer on its
+    scalar-prefetch lane), so no layer of the cache is copied; masks,
+    ``window``, ``backend``, ``splits`` and ``kv_shard`` as in
+    :func:`attn_decode`.  Returns (out (B, D_model), (k, k_scale, v,
+    v_scale))."""
+    b, _ = x_t.shape
+    s_max = k.shape[3]
+    q, k_new, v_new = _decode_qkv(p, x_t, cfg, pos)
+    _, lengths, bias = _decode_mask(pos, b, s_max, window)
+    # Each scatter's window is the leaf's minor dim, so that the leaf
+    # keeps the layout the kernel reads and XLA copies none of it: the
+    # K/V leaves take one index per (row, kv head) and a head-dim window,
+    # the scales one index per row and a window over the heads.
+    hkv = cfg.n_kv
+    at = jnp.clip(pos, 0, s_max - 1)
+    rows = jnp.arange(b)
+    idx_kv = (layer, jnp.repeat(rows, hkv), jnp.tile(jnp.arange(hkv), b),
+              jnp.repeat(at, hkv))
+
+    def put(leaf, new):
+        if leaf.ndim == 5:
+            idx, new = idx_kv, new.reshape(b * hkv, -1)
+        else:
+            idx = (layer, rows, slice(None), at)
+        return leaf.at[idx].set(new.astype(leaf.dtype),
+                                mode="promise_in_bounds")
+
+    if quantized:
+        kq_new, ks_new = kvq_ops.quantize_kv(k_new)
+        vq_new, vs_new = kvq_ops.quantize_kv(v_new)
+        k, k_scale = put(k, kq_new), put(k_scale, ks_new)
+        v, v_scale = put(v, vq_new), put(v_scale, vs_new)
+        out = _kvq_attend(q, k, k_scale, v, v_scale, layer, lengths, bias,
+                          backend=backend, splits=splits, mesh=mesh,
+                          kv_shard=kv_shard)
+    else:
+        k, v = put(k, k_new), put(v, v_new)
+        out = _plain_attend(q, k[layer], v[layer], lengths, bias, cfg)
+    out = out.reshape(b, -1).astype(x_t.dtype)
+    return out @ p["wo"], (k, k_scale, v, v_scale)

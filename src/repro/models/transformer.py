@@ -714,8 +714,12 @@ def decode_step(params, cfg: ModelConfig, cache: dict, tokens_t, *,
     softmax never normalizes over an empty row (their logits are garbage
     by contract and never read).  Occupancy is pure data — joining or
     retiring a request never changes a traced shape, hence no recompile.
-    An MLA stack decodes per slot through ``_decode_slots_mla``, which
-    writes and reads the stacked latent cache in place.
+    Per slot, the stacked cache rides the layer scan's carry and each
+    layer writes its token and reads its layer of the cache in place:
+    ``_decode_slots_gqa`` (int8 K/V) and ``_decode_slots_mla`` (latents).
+    Of the per-slot rounds, only the mesh's sequence-sharded int8 pool
+    keeps the per-layer scan below (the lockstep one), for its
+    write+flash-combine collective.
     """
     params = policy.cast_to_compute(params)
     pos = cache["pos"]
@@ -738,17 +742,28 @@ def decode_step(params, cfg: ModelConfig, cache: dict, tokens_t, *,
     # per-slot pos is >= 0 by construction (pool zeros / scatter lengths),
     # so lengths = pos+1 >= 1 and every row's softmax normalizer is
     # non-empty on every backend — free slots never produce NaNs
-    x = params["embed"][tokens_t]                           # (B, D)
     static_window = int(cfg.window) if not cfg.global_layers else None
     windows = None if static_window is not None else layer_windows(cfg)
 
-    # mesh-aware cache layout (serve pool): "heads" needs no special
-    # handling (XLA keeps per-kv-head work local), "seq" switches
-    # attn_decode to the write+flash-combine collective
+    # mesh-aware cache layout (serve pool): "heads" runs the kernel per
+    # device on its kv heads, "seq" switches attn_decode to the
+    # write+flash-combine collective
     kv_shard = "none"
     if mesh is not None and "k" in cache and cfg.mla is None:
         from repro.distributed import sharding as shd
         kv_shard = shd.serve_kv_shard(mesh, cfg.n_kv, cache["k"].shape[3])
+    if per_slot and not (kv_shard == "seq" and quantized):
+        if cfg.encoder is not None:
+            raise NotImplementedError(
+                "per-slot decode (vector cache['pos']) serves decoder-only "
+                "stacks; encoder-decoder archs serve through the "
+                "scalar-pos path")
+        return _decode_slots_gqa(
+            params, cfg, cache, tokens_t, policy=policy,
+            window=static_window, windows=windows, quantized=quantized,
+            backend=kvq_backend, splits=kvq_splits, active=active,
+            scan_unroll=scan_unroll, mesh=mesh, kv_shard=kv_shard)
+    x = params["embed"][tokens_t]                           # (B, D)
 
     layer_caches = {k: v for k, v in cache.items() if k != "pos"}
 
@@ -810,38 +825,38 @@ def decode_step(params, cfg: ModelConfig, cache: dict, tokens_t, *,
     return logits, new_caches
 
 
-def _decode_slots_mla(params, cfg: ModelConfig, cache: dict, tokens_t, *,
-                      policy: Policy, backend: str, active, scan_unroll: int):
-    """Per-slot decode of an MLA stack over the slot pool's latent cache.
+def _decode_slots(params, cfg: ModelConfig, cache: dict, tokens_t, names,
+                  attend, *, policy: Policy, active, scan_unroll: int,
+                  mesh=None):
+    """Per-slot decode over the slot pool's stacked cache.
 
-    The stacked ``mla_lat`` / ``mla_rope`` leaves ride the layer scan's
-    carry, and each layer writes its token and reads its layer of them in
-    place (``attention.mla_decode_slots``): no layer's slice of the cache
-    is scanned in or out, so the round makes no copy of the cache.
-    Leading dense layers (``cfg.dense_layers``) scan first, over the first
-    layers of the same cache.  Rows that ``active`` marks free read one
-    position and keep their position."""
+    The cache leaves ``names`` ride the layer scan's carry whole, and each
+    layer's ``attend(p_attn, h, leaves, layer) -> (mix, leaves)`` writes
+    its token and reads its layer of them in place: no layer's slice of
+    the cache is scanned in or out, so the round makes no copy of the
+    cache.  Leading dense layers (``cfg.dense_layers``) scan first, over
+    the first layers of the same cache.  Rows that ``active`` marks free
+    keep their position."""
     pos = cache["pos"]
-    lengths = pos + 1 if active is None else jnp.where(active, pos + 1, 1)
     x = params["embed"][tokens_t]                           # (B, D)
 
     def make_body(lcfg):
         def body(carry, xs):
-            x, lat, rope = carry
+            x, leaves = carry[0], carry[1:]
             p_layer, layer = xs
             h = rms_norm(x[:, None], p_layer["ln1"], cfg.norm_eps,
                          bf16_grad=cfg.norm_bf16_grad)[:, 0]
-            mix, lat, rope = attn.mla_decode_slots(
-                p_layer["attn"], h, cfg, lat, rope, layer, pos, lengths,
-                backend=backend)
+            mix, leaves = attend(p_layer["attn"], h, leaves, layer)
             x = x + mix
-            h2 = rms_norm(x[:, None], p_layer["ln2"], cfg.norm_eps,
-                          bf16_grad=cfg.norm_bf16_grad)
-            ffn_out, _ = _ffn_apply(p_layer["ffn"], h2, lcfg)
-            return (x + ffn_out[:, 0], lat, rope), None
+            if "ffn" in p_layer:
+                h2 = rms_norm(x[:, None], p_layer["ln2"], cfg.norm_eps,
+                              bf16_grad=cfg.norm_bf16_grad)
+                ffn_out, _ = _ffn_apply(p_layer["ffn"], h2, lcfg, mesh=mesh)
+                x = x + ffn_out[:, 0]
+            return (x, *leaves), None
         return body
 
-    carry = (x, cache["mla_lat"], cache["mla_rope"])
+    carry = (x, *(cache[n] for n in names))
     n_dense = cfg.dense_layers
     with jax.named_scope("layers"):
         if n_dense:
@@ -852,7 +867,7 @@ def _decode_slots_mla(params, cfg: ModelConfig, cache: dict, tokens_t, *,
             make_body(cfg), carry,
             (params["blocks"], jnp.arange(n_dense, cfg.n_layers)),
             unroll=scan_unroll)
-    x, lat, rope = carry
+    x = carry[0]
     with jax.named_scope("lm_head"):
         x = rms_norm(x[:, None], params["final_norm"], cfg.norm_eps,
                      bf16_grad=cfg.norm_bf16_grad)[:, 0]
@@ -860,4 +875,46 @@ def _decode_slots_mla(params, cfg: ModelConfig, cache: dict, tokens_t, *,
         logits = _mask_padded_vocab((x @ head).astype(policy.output_dtype),
                                     cfg)
     step = 1 if active is None else active.astype(jnp.int32)
-    return logits, {"pos": pos + step, "mla_lat": lat, "mla_rope": rope}
+    return logits, {"pos": pos + step, **dict(zip(names, carry[1:]))}
+
+
+def _decode_slots_gqa(params, cfg: ModelConfig, cache: dict, tokens_t, *,
+                      policy: Policy, window, windows, quantized: bool,
+                      backend: str, splits: int, active, scan_unroll: int,
+                      mesh, kv_shard: str):
+    """Per-slot decode of a GQA stack over the slot pool's K/V cache
+    (``attention.attn_decode_slots``).  ``window`` is the static window,
+    or None with ``windows`` the per-layer ones.  Every row, free ones
+    too, writes its token at its position and attends over its first
+    ``pos + 1`` positions."""
+    pos = cache["pos"]
+
+    def attend(p_attn, h, leaves, layer):
+        win = window if window is not None else windows[layer]
+        return attn.attn_decode_slots(
+            p_attn, h, cfg, *leaves, layer, pos, window=win,
+            quantized=quantized, backend=backend, splits=splits, mesh=mesh,
+            kv_shard=kv_shard)
+
+    return _decode_slots(params, cfg, cache, tokens_t,
+                         ("k", "k_scale", "v", "v_scale"), attend,
+                         policy=policy, active=active,
+                         scan_unroll=scan_unroll, mesh=mesh)
+
+
+def _decode_slots_mla(params, cfg: ModelConfig, cache: dict, tokens_t, *,
+                      policy: Policy, backend: str, active, scan_unroll: int):
+    """Per-slot decode of an MLA stack over the slot pool's latent cache
+    (``attention.mla_decode_slots``).  Rows that ``active`` marks free
+    read one position."""
+    pos = cache["pos"]
+    lengths = pos + 1 if active is None else jnp.where(active, pos + 1, 1)
+
+    def attend(p_attn, h, leaves, layer):
+        mix, lat, rope = attn.mla_decode_slots(
+            p_attn, h, cfg, *leaves, layer, pos, lengths, backend=backend)
+        return mix, (lat, rope)
+
+    return _decode_slots(params, cfg, cache, tokens_t,
+                         ("mla_lat", "mla_rope"), attend, policy=policy,
+                         active=active, scan_unroll=scan_unroll)

@@ -113,6 +113,78 @@ class TestSplitKParity:
                                 debug_counts=True)
 
 
+def _stacked_cache(n_layers, b, hkv, s, d):
+    """An int8 cache of ``n_layers`` layers, each with data of its own."""
+    leaves = [_cache(b, hkv, s, d) for _ in range(n_layers)]
+    return tuple(jnp.stack(xs) for xs in zip(*leaves))
+
+
+class TestStackedCache:
+    """The kernel reads one layer of a stacked (L, B, Hkv, S, D) cache
+    where it lies: the layer it is given, and no other."""
+
+    @pytest.mark.parametrize("layer", [0, 2])
+    @pytest.mark.parametrize("splits", [1, 4])
+    def test_layer_matches_ref(self, layer, splits):
+        b, h, hkv, s, d, bs = 3, 8, 2, 1024, 64, 128
+        q = _q(b, h, d)
+        kq, ks, vq, vs = _stacked_cache(3, b, hkv, s, d)
+        lengths = jnp.asarray([1, s, int(RNG.integers(2, s))], jnp.int32)
+        o_layer = DO.decode_attention(
+            q, kq[layer], ks[layer], vq[layer], vs[layer], lengths=lengths,
+            backend="ref")
+        o_ref = DO.decode_attention(q, kq, ks, vq, vs, layer=layer,
+                                    lengths=lengths, backend="ref")
+        np.testing.assert_array_equal(np.asarray(o_ref), np.asarray(o_layer))
+        o_int = DO.decode_attention(q, kq, ks, vq, vs, layer=layer,
+                                    lengths=lengths, backend="interpret",
+                                    splits=splits, block_s=bs)
+        np.testing.assert_allclose(np.asarray(o_int), np.asarray(o_layer),
+                                   atol=1e-3)
+        other = DO.decode_attention(
+            q, kq[1], ks[1], vq[1], vs[1], lengths=lengths, backend="ref")
+        assert np.abs(np.asarray(o_int) - np.asarray(other)).max() > 1e-2
+
+    @pytest.mark.parametrize("layer", [0, 2])
+    @pytest.mark.parametrize("splits", [1, 4])
+    def test_counters_match_analytic(self, layer, splits):
+        s, bs, lengths = 1024, 128, [1, 1024, 300]
+        b, hkv, d = len(lengths), 2, 32
+        kq, ks, vq, vs = _stacked_cache(3, b, hkv, s, d)
+        _, cnt = DO.decode_attention(
+            _q(b, 2 * hkv, d), kq, ks, vq, vs, layer=layer,
+            lengths=jnp.asarray(lengths), backend="interpret",
+            splits=splits, block_s=bs, debug_counts=True)
+        c = tiling.decode_tile_step_counts(s, lengths, block_s=bs,
+                                           splits=splits)
+        for j in range(hkv):
+            np.testing.assert_array_equal(np.asarray(cnt)[:, j],
+                                          np.asarray(c["counts"]))
+
+    @pytest.mark.parametrize("mask", ["lengths", "bias", "none"])
+    def test_unit_layer_axis_is_the_per_layer_form(self, mask):
+        """A per-layer cache reads the same through ``decode_attention``
+        as its ``cache[None]`` at layer 0 does through the kernel."""
+        b, h, hkv, s, d = 2, 4, 2, 512, 64
+        q = _q(b, h, d)
+        cache = _cache(b, hkv, s, d)
+        kw = {}
+        if mask == "lengths":
+            kw["lengths"] = jnp.asarray([7, s], jnp.int32)
+        elif mask == "bias":
+            kw["bias"] = jnp.broadcast_to(jnp.where(
+                jnp.arange(s) % 3 != 0, 0.0, -1e30).astype(jnp.float32),
+                (b, s))
+        o_layer = DO.decode_attention(q, *cache, backend="interpret",
+                                      splits=2, block_s=128, **kw)
+        qg = q.reshape(b, hkv, h // hkv, d)
+        o_unit = DK.flash_decode_pallas(
+            qg, *(c[None] for c in cache), kw.get("bias"), kw.get("lengths"),
+            0, sm_scale=d ** -0.5, splits=2, block_s=128, interpret=True)
+        np.testing.assert_array_equal(np.asarray(o_layer),
+                                      np.asarray(o_unit).reshape(b, h, d))
+
+
 class TestDecodeCounters:
     """Measured ``debug_counts`` == ``tiling.decode_tile_step_counts``,
     tile-for-tile per (batch row, split), identical across KV heads."""

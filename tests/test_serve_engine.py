@@ -190,6 +190,27 @@ class TestPerSlotDecode:
                                    atol=1e-5)
         np.testing.assert_array_equal(np.asarray(nc_v["pos"]), [5, 5])
 
+    def test_vector_pos_at_cache_end_matches_scalar(self, llama):
+        """A row at a position past the cache writes its last slot, as
+        the lockstep path's ``dynamic_update_slice`` does."""
+        cfg, params = llama
+        tok = jnp.asarray([3, 5], jnp.int32)
+        s = 16
+        c_s = transformer.init_cache(cfg, 2, s, quantized=True)
+        c_s["pos"] = jnp.int32(s)
+        c_v = transformer.init_cache(cfg, 2, s, quantized=True)
+        c_v["pos"] = jnp.asarray([s, s], jnp.int32)
+        lg_s, nc_s = transformer.decode_step(params, cfg, c_s, tok,
+                                             quantized=True)
+        lg_v, nc_v = transformer.decode_step(params, cfg, c_v, tok,
+                                             quantized=True)
+        np.testing.assert_allclose(np.asarray(lg_s), np.asarray(lg_v),
+                                   atol=1e-5)
+        for name in ("k", "k_scale", "v", "v_scale"):
+            np.testing.assert_array_equal(np.asarray(nc_s[name]),
+                                          np.asarray(nc_v[name]))
+        assert np.abs(np.asarray(nc_v["k"])[:, :, :, s - 1]).max() > 0
+
     def test_active_mask_freezes_slots(self, llama):
         cfg, params = llama
         cache = transformer.init_cache(cfg, 3, 16, quantized=True)

@@ -360,3 +360,38 @@ def test_mla_decode_round_copies_no_cache(one_chip):
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes >= 12 * b * s * (MLA_C + MLA_R) * 2
     assert mem.temp_size_in_bytes < 1e8
+
+
+def test_gqa_decode_round_copies_no_cache(one_chip):
+    """The chat cell's decode round at its size (GLM-4-9B, 16 layers, 64
+    slots of 2560, 2 int8 KV heads of 128): the 0.67 GB K and V leaves and
+    their scales are donated, written and read in place; the round's
+    temporaries stay under 0.1 GB."""
+    from repro.core.mixed_precision import get_policy
+    from repro.models import transformer
+
+    cfg = dataclasses.replace(configs.get_config("glm4-9b"), n_layers=16,
+                              vocab=151552)
+    b, s = 64, 2560
+    assert (cfg.n_kv, cfg.head_dim) == (2, D)
+    params = jax.eval_shape(lambda: jax.tree_util.tree_map(
+        lambda x: x.astype(jnp.bfloat16),
+        transformer.init_params(cfg, jax.random.PRNGKey(0))))
+    cache = jax.eval_shape(lambda: transformer.init_cache(cfg, b, s))
+    cache["pos"] = jax.ShapeDtypeStruct((b,), jnp.int32)
+
+    def decode(params, cache, tokens, active):
+        return transformer.decode_step(
+            params, cfg, cache, tokens, policy=get_policy("bf16"),
+            kvq_backend="pallas", active=active)
+
+    compiled = jax.jit(decode, donate_argnums=(1,)).lower(*_on_chip(
+        (params, cache, jax.ShapeDtypeStruct((b,), jnp.int32),
+         jax.ShapeDtypeStruct((b,), jnp.bool_)), one_chip)).compile()
+    text = compiled.as_text()
+    assert _named_kernels(text) == {kvq_kernel.KERNEL_NAME}
+    assert not re.search(r"= (s8\[16,64,2,2560,128\]|f32\[16,64,2,2560\])"
+                         r"[^=]*copy(-start)?\(", text)
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= 16 * b * 2 * s * (2 * D + 2 * 4)
+    assert mem.temp_size_in_bytes < 1e8
