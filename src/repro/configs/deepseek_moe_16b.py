@@ -1,6 +1,6 @@
 """DeepSeek-MoE-16B [arXiv:2401.06066]: fine-grained MoE, 2 shared + 64
 routed experts, top-6.  (HF layer 0 is dense-MLP; we keep a uniform MoE
-stack for scan homogeneity — see DESIGN.md §5.)"""
+stack for scan homogeneity.)"""
 from repro.models.config import ModelConfig, MoEConfig
 
 

@@ -1,6 +1,6 @@
 """Hymba-1.5B [arXiv:2411.13676]: hybrid parallel attention+mamba heads,
-sliding-window attention except 3 global layers (meta-tokens omitted —
-DESIGN.md §5).  Sub-quadratic -> eligible for long_500k."""
+sliding-window attention except 3 global layers (meta-tokens omitted).
+Sub-quadratic -> eligible for long_500k."""
 from repro.models.config import ModelConfig, SSMConfig
 
 

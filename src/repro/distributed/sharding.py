@@ -7,8 +7,7 @@ over the param tree:
   * last-dim "model"      : wq wk wv w_gate w_up q_b kv_b w1 b1 shared_* lm_head
   * penultimate "model"   : wo w_down w2 shared_down embed
   * MoE EP mode           : experts sharded on the expert axis instead
-  * SSM params            : replicated (small; heads rarely divide 16 —
-                            DESIGN.md §5 records this choice)
+  * SSM params            : replicated (small; heads rarely divide 16)
 
 Caches shard batch over DP when divisible, KV-heads over model when
 divisible, otherwise the *sequence* dim over model (long-context serving).

@@ -266,8 +266,7 @@ def decode_tile_step_counts(s: int, lengths=None, *,
     below ``lengths[b]`` — exactly the kernel's ``pl.when`` predicate.
     ``dense`` is the B * ns tile-steps a length-blind sequential sweep
     pays per kv head.  The planner's decode report
-    (``repro.plan.decode_tile_report``) and BENCH_decode.json both build
-    on these counts.
+    (``repro.plan.decode_tile_report``) builds on these counts.
     """
     bs, ns, splits_eff, spt = resolve_decode_grid(s, block_s=block_s,
                                                   splits=splits)

@@ -108,7 +108,7 @@ def attn_block(p, x, cfg, *, positions, window: int = 0, layer_window=None,
     q = (x @ p["wq"]).reshape(b, s, h, hd)
     k = (x @ p["wk"]).reshape(b, s, hkv, hd)
     v = (x @ p["wv"]).reshape(b, s, hkv, hd)
-    # NOTE (tried & refuted, EXPERIMENTS §Perf): forcing MQA-style TP here
+    # NOTE (tried & refuted): forcing MQA-style TP here
     # (q head-sharded, k/v replicated) when kv-heads don't divide the model
     # axis made llama3/glm4 15% MORE collective-bound — XLA's own hybrid
     # layout beats forced replication.  The deployed fix for mismatched
@@ -344,18 +344,12 @@ def _decode_qkv(p, x_t, cfg, pos):
     return q[:, 0], k_t[:, 0], v_t[:, 0]
 
 
-def _decode_mask(pos, b, s_max, window, rolling=False):
+def _decode_mask(pos, b, s_max, window):
     """(write position, lengths, bias) of a decode token at ``pos``.
 
-    Rolling buffers and full-causal (static window <= 0) schedules give
-    per-row ``lengths`` (bias None); a window band over a non-rolling
-    cache, or a traced per-layer window, gives the dense (B, S) ``bias``
-    (lengths None)."""
-    if rolling:
-        # slot j is filled iff j <= pos (pre-wrap) or always (post-wrap);
-        # all filled slots are within the window by construction
-        return (pos % s_max,
-                jnp.broadcast_to(jnp.minimum(pos + 1, s_max), (b,)), None)
+    Full-causal (static window <= 0) schedules give per-row ``lengths``
+    (bias None); a window band, static or a traced per-layer window,
+    gives the dense (B, S) ``bias`` (lengths None)."""
     if isinstance(window, int) and window <= 0:
         return pos, jnp.broadcast_to(pos + 1, (b,)), None  # incl. current
     kv_pos = jnp.arange(s_max)
@@ -411,8 +405,7 @@ def _plain_attend(q, ck, cv, lengths, bias, cfg):
 
 def attn_decode(p, x_t, cfg, cache_k, cache_s_k, cache_v, cache_s_v, pos,
                 *, window: int = 0, quantized: bool = True, backend: str = "ref",
-                splits: int = 1, rolling: bool = False, mesh=None,
-                kv_shard: str = "none"):
+                splits: int = 1, mesh=None, kv_shard: str = "none"):
     """One-token GQA decode against one layer of a (possibly int8) cache.
 
     x_t: (B, D_model); cache_k/v: (B, Hkv, S, hd) int8 (or bf16 when not
@@ -421,18 +414,14 @@ def attn_decode(p, x_t, cfg, cache_k, cache_s_k, cache_v, cache_s_v, pos,
     sequence-sharded serve pool (``kv_shard == "seq"``) — each batch row
     then RoPE-rotates, writes, and masks at its OWN position.  The serve
     pool's other per-row rounds decode through :func:`attn_decode_slots`.
-    ``rolling``: the cache is a circular window buffer of size S — writes
-    land at ``pos % S`` and every filled slot is in-window by construction
-    (two-tier cache for windowed layers; EXPERIMENTS §Perf).
 
-    Masking is length-first: rolling buffers and full-causal (static
-    window <= 0) schedules pass per-batch ``lengths`` through to
-    ``decode_attention`` — the split-K kernel skips fully-padded KV tiles
-    and masks the straddling tile with an in-kernel iota compare, and no
-    (B, S) f32 bias tensor is built on ANY backend.  Only schedules
-    lengths can't express (a window band over a non-rolling cache, or a
-    traced per-layer window) fall back to the dense bias.  ``splits``
-    selects the kernel's split-K fan-out.
+    Masking is length-first: full-causal (static window <= 0) schedules
+    pass per-batch ``lengths`` through to ``decode_attention`` — the
+    split-K kernel skips fully-padded KV tiles and masks the straddling
+    tile with an in-kernel iota compare, and no (B, S) f32 bias tensor is
+    built on ANY backend.  Only schedules lengths can't express (a window
+    band, static or a traced per-layer window) fall back to the dense
+    bias.  ``splits`` selects the kernel's split-K fan-out.
 
     ``kv_shard`` (from ``sharding.serve_kv_shard``) names how the cache is
     laid out under ``mesh``: under "heads" XLA keeps the per-kv-head
@@ -450,12 +439,11 @@ def attn_decode(p, x_t, cfg, cache_k, cache_s_k, cache_v, cache_s_v, pos,
     b, _ = x_t.shape
     pos = jnp.asarray(pos, jnp.int32)
     q, k_new, v_new = _decode_qkv(p, x_t, cfg, pos)
-    write_at, lengths, bias = _decode_mask(pos, b, cache_k.shape[2], window,
-                                           rolling)
+    write_at, lengths, bias = _decode_mask(pos, b, cache_k.shape[2], window)
     if quantized:
         kq_new, ks_new = kvq_ops.quantize_kv(k_new)
         vq_new, vs_new = kvq_ops.quantize_kv(v_new)
-        if kv_shard == "seq" and mesh is not None and not rolling:
+        if kv_shard == "seq" and mesh is not None:
             from repro.distributed import collectives
             out, ck, csk, cv, csv = collectives.sp_decode_attention_int8(
                 q, cache_k, cache_s_k, cache_v, cache_s_v,

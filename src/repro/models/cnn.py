@@ -3,8 +3,7 @@
 Built as an explicit *layer list* so OpTorch's ``checkpoint_sequential``
 applies exactly as in the paper: segments of the sequential stack are
 rematted, only segment inputs are stored.  GroupNorm replaces BatchNorm
-(stateless — no running stats to thread through pjit; accuracy-neutral at
-paper scale, noted in DESIGN.md).
+(stateless — no running stats to thread through pjit).
 
 The first layer is the E-D *decode layer* when the input is a packed
 uint32 batch (paper II.A.2: "a custom deep learning layer to decode").

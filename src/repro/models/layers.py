@@ -10,8 +10,8 @@ def rms_norm(x, w, eps: float = 1e-5, *, bf16_grad: bool = False):
 
     ``bf16_grad`` swaps in a custom-vjp variant whose input cotangent is
     emitted in ``x.dtype`` instead of f32: under tensor parallelism the
-    backward all-reduce of dx then moves half the bytes (perf iteration;
-    see EXPERIMENTS.md §Perf).  Forward values are bit-identical.
+    backward all-reduce of dx then moves half the bytes.  Forward values
+    are bit-identical.
     """
     if bf16_grad:
         return _rms_norm_bf16g(x, w, eps)

@@ -141,7 +141,7 @@ def _kv_entry(k, v, cfg, mesh, *, quantized: bool = True):
     quarters the bytes that must move when XLA reshards the (head-sharded)
     attention K/V into the (sequence-sharded) cache layout; the sharding
     constraint makes that reshard happen on the small per-layer slice
-    instead of the full (L, ...) stack (perf iteration, EXPERIMENTS §Perf).
+    instead of the full (L, ...) stack.
     k, v: (B, S, Hkv, hd) -> int8 entries in cache axis order (B, Hkv, S, hd).
     """
     k = jnp.moveaxis(k, 2, 1)                        # (B, Hkv, S, hd)
@@ -505,133 +505,6 @@ def loss_fn(params, cfg: ModelConfig, batch: dict, *,
 
 
 # ---------------------------------------------------------------------------
-# Two-tier cache (windowed archs): global layers keep the full context,
-# window layers keep a rolling buffer of `window` slots.  For hymba @ 500k
-# this shrinks the attention cache 29/32 layers x 512 = ~10x (EXPERIMENTS
-# §Perf, cell C).
-# ---------------------------------------------------------------------------
-def layer_runs(cfg: ModelConfig):
-    """Contiguous layer runs [(lo, hi, is_global)] preserving order."""
-    glob = set(cfg.global_layers)
-    runs: list[tuple[int, int, bool]] = []
-    for i in range(cfg.n_layers):
-        is_g = i in glob
-        if runs and runs[-1][2] == is_g:
-            runs[-1] = (runs[-1][0], i + 1, is_g)
-        else:
-            runs.append((i, i + 1, is_g))
-    return runs
-
-
-def init_cache_two_tier(cfg: ModelConfig, batch: int, s_max: int, *,
-                        quantized: bool = True, dtype=jnp.bfloat16) -> dict:
-    assert cfg.window > 0 and cfg.global_layers and cfg.mixer in (
-        "attn", "hybrid"), "two-tier cache needs a windowed attention arch"
-    L = cfg.n_layers
-    n_g = len([g for g in cfg.global_layers if g < L])
-    n_w = L - n_g
-    hkv, hd = cfg.n_kv, cfg.head_dim
-    kv_dtype = jnp.int8 if quantized else dtype
-    w = min(cfg.window, s_max)
-    cache: dict[str, Any] = {"pos": jnp.zeros((), jnp.int32)}
-    for tier, n_t, s_t in (("g", n_g, s_max), ("w", n_w, w)):
-        cache[f"{tier}k"] = jnp.zeros((n_t, batch, hkv, s_t, hd), kv_dtype)
-        cache[f"{tier}v"] = jnp.zeros((n_t, batch, hkv, s_t, hd), kv_dtype)
-        cache[f"{tier}k_scale"] = jnp.zeros((n_t, batch, hkv, s_t), jnp.float32)
-        cache[f"{tier}v_scale"] = jnp.zeros((n_t, batch, hkv, s_t), jnp.float32)
-    if cfg.mixer == "hybrid":
-        s = cfg.ssm
-        conv_dim = s.d_inner + 2 * s.d_state
-        cache["conv"] = jnp.zeros((L, batch, s.conv_kernel - 1, conv_dim), dtype)
-        cache["ssm"] = jnp.zeros((L, batch, s.heads, s.d_state, s.head_p),
-                                 jnp.float32)
-    return cache
-
-
-def decode_step_two_tier(params, cfg: ModelConfig, cache: dict, tokens_t, *,
-                         policy: Policy = Policy.full(), quantized: bool = True,
-                         kvq_backend: str = "ref", kvq_splits: int = 1,
-                         mesh=None):
-    """Single-token decode over a two-tier cache (see init_cache_two_tier).
-
-    Every layer takes the lengths-aware decode path: window layers roll a
-    W-slot buffer (their split-K axis statically shrinks to ~W/BS tiles),
-    global layers pass ``lengths = pos + 1`` — no bias tensors anywhere.
-    """
-    params = policy.cast_to_compute(params)
-    pos = cache["pos"]
-    x = params["embed"][tokens_t]
-
-    def make_body(rolling: bool):
-        def body(carry, xs):
-            p_layer, lc = xs["p"], xs["c"]
-            x = carry
-            h = rms_norm(x[:, None], p_layer["ln1"], cfg.norm_eps,
-                         bf16_grad=cfg.norm_bf16_grad)[:, 0]
-            new_lc = dict(lc)
-            mix, (ck, csk, cv, csv) = attn.attn_decode(
-                p_layer["attn"], h, cfg, lc["k"], lc["k_scale"], lc["v"],
-                lc["v_scale"], pos, window=0, quantized=quantized,
-                backend=kvq_backend, splits=kvq_splits, rolling=rolling)
-            new_lc.update(k=ck, k_scale=csk, v=cv, v_scale=csv)
-            if cfg.mixer == "hybrid":
-                s_mix, nconv, nssm = ssm_mod.ssm_decode_step(
-                    p_layer["ssm"], h, cfg, lc["conv"], lc["ssm"])
-                new_lc.update(conv=nconv, ssm=nssm)
-                mix = 0.5 * (
-                    rms_norm(mix[:, None], p_layer["mix_norm_attn"],
-                             cfg.norm_eps)[:, 0]
-                    + rms_norm(s_mix[:, None], p_layer["mix_norm_ssm"],
-                               cfg.norm_eps)[:, 0])
-            x = x + mix
-            if "ffn" in p_layer:
-                h2 = rms_norm(x[:, None], p_layer["ln2"], cfg.norm_eps,
-                              bf16_grad=cfg.norm_bf16_grad)
-                ffn_out, _ = _ffn_apply(p_layer["ffn"], h2, cfg, mesh=mesh)
-                x = x + ffn_out[:, 0]
-            return x, new_lc
-        return body
-
-    new_cache = dict(cache)
-    g_off = w_off = 0
-    sl = jax.tree_util.tree_map
-    for lo, hi, is_global in layer_runs(cfg):
-        n = hi - lo
-        tier = "g" if is_global else "w"
-        off = g_off if is_global else w_off
-        p_run = sl(lambda a: a[lo:hi], params["blocks"])
-        lc_run = {"k": cache[f"{tier}k"][off:off + n],
-                  "k_scale": cache[f"{tier}k_scale"][off:off + n],
-                  "v": cache[f"{tier}v"][off:off + n],
-                  "v_scale": cache[f"{tier}v_scale"][off:off + n]}
-        if cfg.mixer == "hybrid":
-            lc_run["conv"] = cache["conv"][lo:hi]
-            lc_run["ssm"] = cache["ssm"][lo:hi]
-        x, updated = jax.lax.scan(make_body(rolling=not is_global), x,
-                                  {"p": p_run, "c": lc_run})
-        for key_src, key_dst in (("k", f"{tier}k"), ("k_scale", f"{tier}k_scale"),
-                                 ("v", f"{tier}v"), ("v_scale", f"{tier}v_scale")):
-            new_cache[key_dst] = jax.lax.dynamic_update_slice_in_dim(
-                new_cache[key_dst], updated[key_src], off, axis=0)
-        if cfg.mixer == "hybrid":
-            new_cache["conv"] = jax.lax.dynamic_update_slice_in_dim(
-                new_cache["conv"], updated["conv"], lo, axis=0)
-            new_cache["ssm"] = jax.lax.dynamic_update_slice_in_dim(
-                new_cache["ssm"], updated["ssm"], lo, axis=0)
-        if is_global:
-            g_off += n
-        else:
-            w_off += n
-
-    x = rms_norm(x[:, None], params["final_norm"], cfg.norm_eps,
-                 bf16_grad=cfg.norm_bf16_grad)[:, 0]
-    head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    logits = _mask_padded_vocab((x @ head).astype(policy.output_dtype), cfg)
-    new_cache["pos"] = pos + 1
-    return logits, new_cache
-
-
-# ---------------------------------------------------------------------------
 # KV / state cache and single-token decode.
 # ---------------------------------------------------------------------------
 def init_cache(cfg: ModelConfig, batch: int, s_max: int, *,
@@ -701,9 +574,8 @@ def decode_step(params, cfg: ModelConfig, cache: dict, tokens_t, *,
     Uniform window schedules pass the window as a STATIC python int (same
     gate as ``forward``), so ``attn_decode`` can take the lengths-aware
     kvq path — per-batch lengths + split-K tile skipping instead of a
-    dense (B, S) bias; per-layer overrides (``cfg.global_layers``) scan a
-    traced window and keep the documented bias fallback (hybrid archs
-    serve through ``decode_step_two_tier`` to avoid it entirely).
+    dense (B, S) bias; per-layer overrides (``cfg.global_layers``, as in
+    hymba) scan a traced window and take the dense (B, S) bias fallback.
 
     Slot-pooled serving (``repro.serve``): when ``cache['pos']`` is a
     per-row (B,) vector, every row decodes at its OWN position — RoPE,

@@ -249,8 +249,8 @@ def flash_attn_flop_report(cfg, b: int, s: int, *, tiles=None) -> dict:
     (QK^T, PV: 4·BQ·BK·D flops), dQ (score recompute, dP, dS·K: 6), dKV
     (score recompute, P^T·dO, dP, dS^T·Q: 8) — against the same matmuls
     on the dense nQ x nK rectangle a mask-blind grid executes, each grid
-    at the tiles it runs.  This is what dryrun train cells, the trainer
-    banner and BENCH_flash.json report as the sparse-grid FLOP claw-back.
+    at the tiles it runs.  This is what dryrun train cells and the trainer
+    banner report as the sparse-grid FLOP claw-back.
     ``tiles`` counts every grid at one (bq, bk) instead, e.g. (128, 128)
     for the claw-back on fine grids.
     """
@@ -505,7 +505,7 @@ def plan_for_budget(profile: ChainProfile, budget_bytes: float,
 
     An unsatisfiable budget yields the peak-minimal best-effort plan,
     tagged ``:infeasible`` in ``source`` AND warned about — every consumer
-    (trainer --remat auto, TrainConfig.mem_budget_mb, hillclimb budget<N>)
+    (trainer --remat auto, TrainConfig.mem_budget_mb)
     funnels through here, so the violated constraint is never silent.
     """
     import warnings

@@ -1,9 +1,9 @@
 """Split-K flash decode over the int8 KV cache (ISSUE 4): parity vs the
-ref oracle across ragged lengths / GQA / MQA / windowed-tier caches and
+ref oracle across ragged lengths / GQA / MQA / window-sized caches and
 split counts, the split-K merge oracle, measured-vs-analytic tile-step
 counters, the >=70% ragged skip-ratio acceptance, the no-bias jaxpr
-contract on every backend, decode_step / two-tier integration, the
-planner's decode report vs measured counts, and the serve CLI flags."""
+contract on every backend, decode_step integration, the planner's decode
+report vs measured counts, and the serve CLI flags."""
 import os
 import subprocess
 import sys
@@ -35,7 +35,7 @@ def _q(b, h, d):
 
 # (b, h, hkv, s, d, splits, block_s) — MHA/GQA/MQA, ragged tile counts,
 # split counts that don't divide the tile count, splits > tiles (clamped),
-# and a window-tier-sized cache (s == W, the two-tier rolling geometry)
+# and a window-sized cache (s == W, the planner's windowed geometry)
 CASES = [
     (1, 4, 4, 512, 64, 1, 512),       # MHA, sequential baseline
     (2, 8, 2, 1024, 64, 4, 256),      # GQA 4:1, even split
@@ -43,7 +43,7 @@ CASES = [
     (3, 6, 2, 768, 32, 3, 256),       # odd batch, ns == splits
     (2, 8, 2, 768, 64, 2, 256),       # splits don't divide ns (3 tiles)
     (2, 4, 2, 256, 16, 8, 64),        # splits > ns -> clamped
-    (2, 4, 2, 256, 64, 2, 128),       # windowed tier: W-slot rolling cache
+    (2, 4, 2, 256, 64, 2, 128),       # a cache as long as the window
     (1, 4, 2, 2048, 64, 3, 512),      # ns=4, splits=3 -> empty last shard
 ]
 
@@ -288,9 +288,10 @@ class TestNoBiasMaterialization:
 
 
 class TestDecodeStepIntegration:
-    """The serve path end-to-end: decode_step (uniform schedule -> static
-    window -> lengths path) and decode_step_two_tier on interpret split-K
-    match the ref backend."""
+    """The serve path end-to-end: decode_step on interpret split-K matches
+    the ref backend, for a uniform schedule (static window -> lengths
+    path) and a hybrid stack with per-layer windows (traced window ->
+    bias path)."""
 
     def _run(self, cfg, step_fn, cache, steps=3, **kw):
         toks = jnp.asarray(
@@ -302,8 +303,9 @@ class TestDecodeStepIntegration:
             outs.append(np.asarray(logits))
         return outs
 
-    def test_decode_step_splitk_matches_ref(self):
-        cfg = configs.smoke_config("llama3-8b")
+    @pytest.mark.parametrize("arch", ["llama3-8b", "hymba-1.5b"])
+    def test_decode_step_splitk_matches_ref(self, arch):
+        cfg = configs.smoke_config(arch)
         params = transformer.init_params(cfg, jax.random.PRNGKey(0))
         outs = {}
         for backend, splits in (("ref", 1), ("interpret", 2)):
@@ -311,20 +313,6 @@ class TestDecodeStepIntegration:
             step = lambda c, t, _b=backend, _s=splits: transformer.decode_step(
                 params, cfg, c, t, quantized=True, kvq_backend=_b,
                 kvq_splits=_s)
-            outs[backend] = self._run(cfg, step, cache)
-        for a, b_ in zip(outs["ref"], outs["interpret"]):
-            np.testing.assert_allclose(a, b_, atol=1e-3)
-
-    def test_two_tier_splitk_matches_ref(self):
-        cfg = configs.smoke_config("hymba-1.5b")
-        params = transformer.init_params(cfg, jax.random.PRNGKey(1))
-        outs = {}
-        for backend in ("ref", "interpret"):
-            cache = transformer.init_cache_two_tier(cfg, 2, 32,
-                                                    quantized=True)
-            step = lambda c, t, _b=backend: transformer.decode_step_two_tier(
-                params, cfg, c, t, quantized=True, kvq_backend=_b,
-                kvq_splits=2)
             outs[backend] = self._run(cfg, step, cache)
         for a, b_ in zip(outs["ref"], outs["interpret"]):
             np.testing.assert_allclose(a, b_, atol=1e-3)
@@ -363,7 +351,7 @@ class TestPlannerDecodeHonesty:
         win_layers = [l for l in rep["per_layer"] if l["window"] > 0]
         assert win_layers and all(
             l["cache_len"] == min(l["window"], 32768) for l in win_layers)
-        # the two-tier claw-back: most layers pay ~W/S of the dense sweep
+        # windowed layers sized at min(W, S): most pay ~W/S of the sweep
         assert rep["skip_frac"] > 0.8
         assert rep["visited_flops"] < rep["dense_flops"]
 
@@ -382,7 +370,7 @@ class TestPlannerDecodeHonesty:
         rep = plan.kv_cache_report(cfg, 4, 32768)
         assert rep["eligible"] and rep["int8_bytes"] < rep["f32_bytes"]
         assert rep["ratio"] > 3.0                     # ~3.76x at head_dim 128
-        # two-tier shrinks the windowed share on top of quantization
+        # min(W, S) windowed layers shrink it on top of quantization
         hy = plan.kv_cache_report(configs.get_config("hymba-1.5b"), 4, 32768)
         full = 4 * configs.get_config("hymba-1.5b").n_layers
         assert hy["int8_bytes"] < hy["f32_bytes"]

@@ -93,19 +93,6 @@ def test_make_mesh_for_elastic_shapes():
     assert m.devices.size == 1
 
 
-def test_layer_runs_partition():
-    import dataclasses as dc
-    from repro import configs
-    from repro.models.transformer import layer_runs
-    cfg = configs.get_config("hymba-1.5b")
-    runs = layer_runs(cfg)
-    assert runs[0] == (0, 1, True)
-    assert sum(hi - lo for lo, hi, _ in runs) == cfg.n_layers
-    # order-preserving and alternating
-    for (a, b, g1), (c, d, g2) in zip(runs, runs[1:]):
-        assert b == c and g1 != g2
-
-
 def test_train_attn_backend_flag(tmp_path):
     """--attn-backend plumbs through to the config and trains (ISSUE 2:
     the flash custom_vjp backward is exercised by real optimizer steps)."""

@@ -91,14 +91,19 @@ def test_smoke_remat_equals_standard(arch):
                                    atol=1e-5, rtol=1e-4)
 
 
-@pytest.mark.parametrize("arch", ["llama3-8b", "glm4-9b", "qwen2-vl-2b",
-                                  "mamba2-130m", "hymba-1.5b", "minicpm3-4b",
-                                  "whisper-base"])
-def test_decode_matches_forward(arch):
+@pytest.mark.parametrize("arch,s", [
+    *[pytest.param(a, 12, id=a)
+      for a in ("llama3-8b", "glm4-9b", "qwen2-vl-2b", "mamba2-130m",
+                "hymba-1.5b", "minicpm3-4b", "whisper-base")],
+    # 24 tokens against the smoke window of 16: the band must hold once
+    # the window has filled (windowed layers decode on the bias path)
+    pytest.param("hymba-1.5b", 24, id="hymba-1.5b-past-window"),
+])
+def test_decode_matches_forward(arch, s):
     """Greedy decode logits must match teacher-forced forward logits."""
     cfg = configs.smoke_config(arch)
     params = transformer.init_params(cfg, KEY)
-    b, s = 2, 12
+    b = 2
     rng = np.random.default_rng(3)
     toks = jnp.asarray(rng.integers(0, cfg.vocab, (b, s)), jnp.int32)
     batch = {"tokens": toks, "labels": toks}
@@ -166,22 +171,3 @@ def test_prefill_cache_matches_incremental():
     nxt = jnp.asarray(logits[:, -1].argmax(-1), jnp.int32)
     # pad prefill cache to the incremental cache length for the next step
     assert int(cache_pf["pos"]) == s
-
-
-def test_two_tier_cache_matches_uniform():
-    """Rolling window buffers must reproduce the uniform-cache decode,
-    including after wraparound (hymba two-tier serving path)."""
-    cfg = configs.smoke_config("hymba-1.5b")  # window=16, global=(0,)
-    params = transformer.init_params(cfg, KEY)
-    b, steps = 2, 24  # beyond the window to exercise wraparound
-    rng = np.random.default_rng(4)
-    toks = jnp.asarray(rng.integers(0, cfg.vocab, (b, steps)), jnp.int32)
-    c_uni = transformer.init_cache(cfg, b, steps, quantized=True)
-    c_tt = transformer.init_cache_two_tier(cfg, b, steps, quantized=True)
-    for t in range(steps):
-        l_uni, c_uni = transformer.decode_step(params, cfg, c_uni, toks[:, t])
-        l_tt, c_tt = transformer.decode_step_two_tier(params, cfg, c_tt,
-                                                      toks[:, t])
-    assert (np.asarray(l_uni).argmax(-1) == np.asarray(l_tt).argmax(-1)).all()
-    rel = float(jnp.abs(l_uni - l_tt).max()) / (float(jnp.abs(l_uni).max()) + 1e-9)
-    assert rel < 0.05

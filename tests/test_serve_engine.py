@@ -353,6 +353,23 @@ class TestEngineInvariants:
             len(r.tokens) for r in eng._requests_done)
         assert 0 < summary["occupancy_mean"] <= 3
 
+    def test_slot_step_efficiency(self, llama):
+        """Continuous batching packs a ragged trace: on this seeded trace,
+        arrival gaps included, the useful tokens over the slot-steps the
+        engine ran (steps x slots) hold at 0.75 or above."""
+        cfg, _ = llama
+        slots = 4
+        eng = self._engine(llama, max_slots=slots, max_len=96,
+                           prompt_buckets=(16,), seed=0)
+        trace = synthetic_trace(12, seed=7, vocab=cfg.vocab, mean_prompt=10,
+                                max_prompt=16, mean_gen=16, max_gen=48,
+                                arrival_rate=1.0)
+        useful = sum(r.max_new_tokens for r in trace)
+        summary = eng.run(trace)
+        assert summary["n_done"] == len(trace)
+        assert summary["total_tokens"] == useful
+        assert useful / (summary["n_steps"] * slots) >= 0.75
+
     def test_eos_retires_early(self, llama):
         cfg, params = llama
         prompt = np.random.default_rng(3).integers(0, cfg.vocab, (9,),
